@@ -2,7 +2,7 @@
 
 Equivalent surface to the reference's ``FieldSpec.DataType`` enum
 (pinot-spi/.../data/FieldSpec.java:383-398) and the dimension/metric/datetime
-field taxonomy, re-expressed with numpy/JAX storage mappings instead of Java
+field classification, re-expressed with numpy/JAX storage mappings instead of Java
 stored types.
 """
 

@@ -350,7 +350,7 @@ class LaunchCoalescer:
         # leader: hold the micro-batch window open — but a cohort that
         # fills to max_cohort early dispatches immediately (the remaining
         # window would be pure added latency for everyone in it). A window
-        # that finds NO partner costs window_s against a ~100ms link RTT;
+        # that finds NO partner costs window_s on top of the link round trip;
         # the pressure gate keeps that bounded to genuinely-concurrent load.
         #
         # STREAM window (double-buffered launch/fetch): when the previous

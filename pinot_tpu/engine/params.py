@@ -147,8 +147,18 @@ class BatchContext:
 
     MAX_MV_K = 16  # (S, L, K) id blocks cost K x an SV column of HBM
 
-    def __init__(self, segments: list, pad_multiple: int = 1024):
+    def __init__(self, segments: list, pad_multiple: int = 1024, mesh=None):
+        """``mesh``: the executor's segment-axis Mesh, if it has one — the
+        (S, ...) blocks are then placed sharded over it at upload, each
+        device holding only its own segments. Placed whole on the default
+        device they would put the entire batch on the first chip and have
+        every launch re-shard it."""
         self.segments = list(segments)
+        # a segment count the mesh does not divide is padded per launch by
+        # parallel/mesh.py pad_to_multiple; those batches stay on the
+        # default device as before
+        self._mesh = mesh if mesh is not None \
+            and len(self.segments) % mesh.devices.size == 0 else None
         # pad to a whole number of zone-map blocks so the block-skip path
         # (ops/blockskip.py) can reshape (S, L) -> (S * n_blocks, R) without
         # a second padding pass; worst case +3072 pad rows per segment
@@ -156,7 +166,7 @@ class BatchContext:
         self.pad_to = max(padded_len(s.n_docs, pad_multiple) for s in self.segments)
         self.S = len(self.segments)
         self.n_docs = np.array([s.n_docs for s in self.segments], dtype=np.int32)
-        self.n_docs_dev = jnp.asarray(self.n_docs)
+        self.n_docs_dev = self._put(self.n_docs)
         self._columns: dict[str, object] = {}       # name -> (S, L) device array
         self._encodings: dict[str, str] = {}
         self._global_dicts: dict[str, Dictionary] = {}
@@ -186,6 +196,19 @@ class BatchContext:
         self._subbyte = _env_flag("PINOT_TPU_SUBBYTE")
         self._plans: dict[str, ColPlan] = {}
         self._narrow_saved_bytes = 0
+
+    def _put(self, blocks):
+        """Host (S, ...) block -> device array, sharded over the segment
+        axis when the batch has a mesh."""
+        if self._mesh is None:
+            return jnp.asarray(blocks)
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from pinot_tpu.parallel.mesh import SEG_AXIS
+
+        return jax.device_put(blocks, NamedSharding(
+            self._mesh, PartitionSpec(SEG_AXIS, *[None] * (blocks.ndim - 1))))
 
     # ---- column access ---------------------------------------------------
     def column_meta(self, name: str):
@@ -254,7 +277,7 @@ class BatchContext:
                 )
                 rank = np.arange(len(fwd), dtype=np.int64) - np.repeat(off[:-1], lens)
                 blocks[i, doc_of_entry, rank] = remap[fwd]
-            self._mv_columns[name] = jnp.asarray(blocks)
+            self._mv_columns[name] = self._put(blocks)
             self._note_resident(self._mv_columns[name])
         return self._mv_columns[name]
 
@@ -429,7 +452,7 @@ class BatchContext:
                         z = build_zone_map(blocks[i, : s.n_docs])
                     zlo[i, : z.shape[1]] = z[0]
                     zhi[i, : z.shape[1]] = z[1]
-            self._columns[name] = jnp.asarray(blocks)
+            self._columns[name] = self._put(blocks)
             self._note_resident(self._columns[name])
             self._store_zone_map(name, zlo, zhi)
             # legacy wide layout: int32 id plane / base-dtype raw plane,
@@ -481,7 +504,7 @@ class BatchContext:
         return zm
 
     def _store_zone_map(self, key: str, zlo, zhi) -> None:
-        self._zone_maps[key] = (jnp.asarray(zlo), jnp.asarray(zhi))
+        self._zone_maps[key] = (self._put(zlo), self._put(zhi))
         for a in self._zone_maps[key]:
             self._note_resident(a)
 
@@ -562,7 +585,7 @@ class BatchContext:
                     else build_zone_map(blocks[i, : len(fwd)])
                 zlo[i, : z.shape[1]] = z[0]
                 zhi[i, : z.shape[1]] = z[1]
-            self._decoded[name] = jnp.asarray(blocks)
+            self._decoded[name] = self._put(blocks)
             self._note_resident(self._decoded[name])
             self._store_zone_map("dv::" + name, zlo, zhi)
             nb = self.pad_to // ZONE_BLOCK_ROWS
@@ -585,7 +608,7 @@ class BatchContext:
                 h = hash32_np(np.asarray(s.dictionary(name).values))
                 fwd = np.asarray(s.forward(name))
                 blocks[i, : len(fwd)] = h[fwd]
-            self._prehashed[name] = jnp.asarray(blocks)
+            self._prehashed[name] = self._put(blocks)
             self._note_resident(self._prehashed[name])
         return self._prehashed[name]
 
@@ -623,7 +646,7 @@ class BatchContext:
                 planes = vals.view(np.uint8).reshape(len(vals), W)
                 fwd = np.asarray(s.forward(name))
                 blocks[i, : len(fwd)] = planes[fwd]
-            self._decoded[key] = jnp.asarray(blocks)
+            self._decoded[key] = self._put(blocks)
             self._note_resident(self._decoded[key])
         return self._decoded[key]
 

@@ -5,13 +5,16 @@ the reference does (here: the bit-packing codec backing
 ``<col>.fwdpacked.bin``, the FixedBitSVForwardIndexWriter/PinotDataBitSet
 analog). The shared library is compiled once per checkout with the system
 ``g++`` (no pip/pybind11 — plain ``extern "C"`` + ctypes) and cached next
-to the source; when no toolchain is available the vectorized numpy
-fallback serves the same format, so segments stay portable either way.
+to the source under a name that carries a hash of ``packer.cpp``'s bytes,
+so a library is only ever loaded if it was built from the source beside
+it; when no toolchain is available the vectorized numpy fallback serves
+the same format, so segments stay portable either way.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,17 +26,26 @@ log = logging.getLogger("pinot_tpu.native")
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "packer.cpp")
-_LIB = os.path.join(_HERE, "_libpinot_packer.so")
+
+
+def _lib_path() -> str:
+    """The library file for the CURRENT source bytes. A file mtime says
+    nothing once a tree has been copied to another machine; the content
+    hash does, and a stale build simply never matches the name."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_libpinot_packer.{digest}.so")
+
 
 _lock = threading.Lock()
 _lib = None
 _lib_tried = False
 
 
-def _compile() -> bool:
+def _compile(lib_path: str) -> bool:
     # compile to a pid-suffixed temp then os.replace: concurrent processes
     # racing through a fresh checkout must never dlopen a half-written .so
-    tmp = f"{_LIB}.{os.getpid()}"
+    tmp = f"{lib_path}.{os.getpid()}"
     base = ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC]
     # degrade codec by codec: a host missing one dev header/library must
     # not cost the others their native path (python fallbacks read the
@@ -85,7 +97,7 @@ def _compile() -> bool:
     try:
         subprocess.run(base + extra, check=True, capture_output=True,
                        timeout=120)
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
         return True
     except Exception as e:  # noqa: BLE001 — numpy/python fallbacks serve
         log.warning("native packer build failed (%s) with %s", e, extra)
@@ -103,7 +115,7 @@ def _load():
     """ctypes handle on the packer library, or None (numpy fallback).
 
     Every failure mode — no toolchain, a failed compile, a corrupt or
-    unloadable ``_libpinot_packer.so`` — degrades to the pure-numpy codec
+    unloadable library file — degrades to the pure-numpy codec
     (`_pack_np`/`_unpack_np`, same byte format), so ``<col>.fwdpacked.bin``
     segments stay readable on any host. ``PINOT_TPU_NO_NATIVE=1`` forces
     the numpy path outright (checked per call, ahead of the cached
@@ -117,11 +129,10 @@ def _load():
             return _lib
         _lib_tried = True
         try:
-            if (not os.path.exists(_LIB)
-                    or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-                if not _compile():
-                    return None
-            lib = ctypes.CDLL(_LIB)
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path) and not _compile(lib_path):
+                return None
+            lib = ctypes.CDLL(lib_path)
             lib.pack_bits.argtypes = [
                 ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
                 ctypes.c_int, ctypes.POINTER(ctypes.c_uint8),

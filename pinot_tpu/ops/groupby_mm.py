@@ -54,12 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax <= 0.4.x spells the Mosaic params class TPUCompilerParams; newer
-# releases renamed it. Resolve once so the kernel runs (interpret mode
-# included) on both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 BLK = 8192              # rows per grid step (64 lane-rows of 128); larger
                         # blocks amortize per-step overhead — measured 35.8
                         # -> 30.3ms for the 4-channel q1 shape at 100M rows
@@ -237,7 +231,7 @@ def _launch(ids_lane, ch_operand, ch_spec_kind, *, a_real, hpad, lo, nsuper,
         ),
         out_shape=jax.ShapeDtypeStruct((nsuper, a_real, hpad, lo), jnp.float32),
         scratch_shapes=[pltpu.VMEM((a_real, hpad, lo), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(vmem_limit_bytes=vmem_limit),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(ids_lane, ch_operand)
     return jnp.sum(out, axis=0, dtype=jnp.float64)

@@ -44,7 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from pinot_tpu.parallel.mesh import SEG_AXIS, _SM_KW, _shard_map
+from pinot_tpu.parallel.mesh import SEG_AXIS
 from jax.sharding import PartitionSpec as P
 
 
@@ -115,8 +115,8 @@ def expand_pairs(lo, counts, bound: int):
 
 
 def _mesh_call(mesh, fn, in_specs, out_specs, *args):
-    sm = _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **_SM_KW)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(sm)(*args)
 
 

@@ -66,13 +66,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ONE copy of the MXU-kernel tuning machinery: the jax-version shim, the
-# VMEM/transient budgets, and the block-size planner live in
-# ops/groupby_mm.py (already re-measured and retuned there once) — a
-# retune must reach both kernel tiers, so this module imports rather
-# than restating them
+# ONE copy of the MXU-kernel tuning machinery: the VMEM/transient budgets
+# and the block-size planner live in ops/groupby_mm.py (already
+# re-measured and retuned there once) — a retune must reach both kernel
+# tiers, so this module imports rather than restating them
 from pinot_tpu.ops.groupby_mm import (  # noqa: F401 — re-exported budgets
-    _COMPILER_PARAMS,
     _plan_blk as _mm_plan_blk,
     BLK,
     MAX_ACC_CELLS,
@@ -151,10 +149,14 @@ def _rel_onehots(ids_r, p, gp: int, hpad: int, blk: int):
     map to the sentinel gp, whose hi row (== hpad) matches no iota row —
     out-of-partition rows contribute nothing, which is what makes the
     partition sweep a disjoint cover of the group space."""
+    # every scalar entering the body is typed int32: the package runs with
+    # x64 on, where a bare Python int becomes a weak 64-bit value that
+    # Mosaic cannot convert
+    gp = _i32(gp)
     rel = ids_r - p * gp
-    rel = jnp.where((rel >= 0) & (rel < gp), rel, gp)
-    lo_r = rel & (LO - 1)
-    hi_r = rel >> 7  # LO = 128
+    rel = jnp.where((rel >= _i32(0)) & (rel < gp), rel, gp)
+    lo_r = rel & _i32(LO - 1)
+    hi_r = rel >> _i32(7)  # LO = 128
     jsub = jax.lax.broadcasted_iota(jnp.int32, (LO, blk), 0)
     oh_loT = jnp.where(lo_r == jsub, jnp.float32(1), jnp.float32(0)) \
         .astype(jnp.bfloat16)
@@ -259,7 +261,7 @@ def plane_group_sums(gid, channels, num_groups: int, *,
         out_shape=jax.ShapeDtypeStruct(
             (npart * nsuper, a_real, hp, LO), jnp.float32),
         scratch_shapes=[pltpu.VMEM((a_real, hp, LO), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(a_real, hp, blk, stacked)),
         interpret=interpret,
     )(ids_lane, ch)
@@ -298,16 +300,17 @@ def _minmax_kernel(ids_ref, v_ref, *refs, ops, span, blk, nsteps, fills):
     @pl.when(s == 0)
     def _():
         for a, fill in zip(acc_refs, fills):
-            a[:] = jnp.full_like(a, fill)
+            a[:] = jnp.full(a.shape, fill, a.dtype)
 
     ids_r = ids_ref[:].reshape(1, blk)
-    rel = ids_r - p * span
-    rel = jnp.where((rel >= 0) & (rel < span), rel, span)
+    span_ = _i32(span)  # typed: see _rel_onehots
+    rel = ids_r - p * span_
+    rel = jnp.where((rel >= _i32(0)) & (rel < span_), rel, span_)
     gsub = jax.lax.broadcasted_iota(jnp.int32, (span, blk), 0)
     onehot = rel == gsub  # rel == span matches no group row
     v = v_ref[:].reshape(1, blk)
     for op, acc, fill in zip(ops, acc_refs, fills):
-        vm = jnp.where(onehot, v, fill)
+        vm = jnp.where(onehot, v, jnp.asarray(fill, v.dtype))
         red = vm.min(axis=1, keepdims=True) if op == "min" \
             else vm.max(axis=1, keepdims=True)
         folded = jnp.broadcast_to(red, (span, 128))
@@ -363,7 +366,7 @@ def group_minmax(gid, values, num_groups: int, ops: tuple, *,
         out_shape=[jax.ShapeDtypeStruct((npart, MINMAX_SPAN, 128), kdt)
                    for _ in ops],
         scratch_shapes=[pltpu.VMEM((MINMAX_SPAN, 128), kdt) for _ in ops],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(
                 16 << 20, (len(ops) + 3) * MINMAX_SPAN * blk * 4)),
         interpret=interpret,
@@ -404,8 +407,8 @@ def _hll_kernel(ids_ref, rho_ref, out_ref, acc_ref, *,
     rho_r = rho_ref[:].reshape(1, blk)
 
     def chh(r):
-        ch = jnp.where(rho_r == r + 1, jnp.float32(1), jnp.float32(0)) \
-            .astype(jnp.bfloat16)
+        ch = jnp.where(rho_r == _i32(r + 1), jnp.float32(1),
+                       jnp.float32(0)).astype(jnp.bfloat16)
         return oh_hi * ch
 
     # presence accumulates as f32 counts: nonneg adds never take a touched
@@ -423,10 +426,10 @@ def _hll_kernel(ids_ref, rho_ref, out_ref, acc_ref, *,
 
     @pl.when(s == nsteps - 1)
     def _():
-        pres = acc_ref[:] > 0.5
+        pres = acc_ref[:] > jnp.float32(0.5)
         rvals = jax.lax.broadcasted_iota(
-            jnp.int32, (nrho, hpad, LO), 0) + 1
-        out_ref[0] = jnp.max(jnp.where(pres, rvals, 0), axis=0)
+            jnp.int32, (nrho, hpad, LO), 0) + _i32(1)
+        out_ref[0] = jnp.max(jnp.where(pres, rvals, _i32(0)), axis=0)
 
 
 def hll_register_max(slot, rho, nslots: int, nrho: int, *,
@@ -466,7 +469,7 @@ def hll_register_max(slot, rho, nslots: int, nrho: int, *,
             memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((npart, hp, LO), jnp.int32),
         scratch_shapes=[pltpu.VMEM((nrho, hp, LO), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(nrho, hp, blk, stacked)),
         interpret=interpret,
     )(ids_lane, rho_lane)
@@ -680,7 +683,7 @@ def _fused_eval(tpl, colv, parv, shape):
         v = colv[key]
         p = parv[tpl[2]]
         m = v == p[0]
-        for k in range(1, p.shape[0]):
+        for k in range(1, len(p)):
             m |= v == p[k]
         return m
     if kind == "range_dict":
@@ -710,36 +713,54 @@ def _fused_kernel(cand_ref, rows_ref, *refs, plan: FusedPlan, sub, pshapes):
             colv[key] = blk
         else:
             colv[key] = blk.astype(jnp.int32)
-    parv = {key: refs[ncols + j][:]
+    # predicate params live in SMEM, which loads scalars only: one scalar
+    # read per literal (IN lists are bounded by FUSED_MAX_IN)
+    parv = {key: [refs[ncols + j][k] for k in range(pshapes[key][0])]
             for j, key in enumerate(sorted(pshapes))}
     out_i = refs[ncols + len(pshapes)]
     out_f = None if plan.n_flt == 0 else refs[ncols + len(pshapes) + 1]
 
     shape = (sub, 128)
     mask = _fused_eval(plan.filter_tpl, colv, parv, shape)
-    rowid = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128 \
+    rowid = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * _i32(128) \
         + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     mask &= rowid < rows_ref[i]
 
-    ints = [jnp.sum(mask, dtype=jnp.int32)]  # slot 0: matched rows
+    # every partial reduces to a (1, 1) tile, not a scalar: Mosaic's
+    # reduce-to-scalar proxy re-enters jnp.sum without a dtype, which
+    # under x64 promotes int32 to an int64 it cannot lower
+    def red(fn, x, **kw):
+        return fn(fn(x, axis=0, keepdims=True, **kw),
+                  axis=1, keepdims=True, **kw)
+
+    # slot 0: matched rows
+    ints = [red(jnp.sum, mask.astype(jnp.int32), dtype=jnp.int32)]
     flts = []
     for (_i, op, ck, buf, _slot, fill) in plan.aggs:
         v = colv[ck]
         if op == "sum":
-            ints.append(jnp.sum(jnp.where(mask, v, 0), dtype=jnp.int32))
+            ints.append(red(jnp.sum, jnp.where(mask, v, _i32(0)),
+                            dtype=jnp.int32))
         elif buf == "int":
-            vm = jnp.where(mask, v, jnp.int32(fill))
-            ints.append(vm.min() if op == "min" else vm.max())
+            vm = jnp.where(mask, v, _i32(fill))
+            ints.append(red(jnp.min if op == "min" else jnp.max, vm))
         else:
             vm = jnp.where(mask, v, jnp.float32(fill))
-            flts.append(vm.min() if op == "min" else vm.max())
-    ki = out_i.shape[1]
-    vec_i = jnp.stack(ints + [jnp.int32(0)] * (ki - len(ints)))
-    out_i[0] = jnp.broadcast_to(vec_i[:, None], (ki, 128))
+            flts.append(red(jnp.min if op == "min" else jnp.max, vm))
+
+    def slots(vals, ref, zero):
+        # slot k of the (K, 128) output row-block = k-th partial, lane-
+        # broadcast (a select per slot: scalars don't stack in VMEM)
+        k = ref.shape[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (k, 128), 0)
+        out = jnp.full((k, 128), zero)
+        for n, val in enumerate(vals):
+            out = jnp.where(row == _i32(n), val, out)
+        ref[0] = out
+
+    slots(ints, out_i, _i32(0))
     if out_f is not None:
-        kf = out_f.shape[1]
-        vec_f = jnp.stack(flts + [jnp.float32(0)] * (kf - len(flts)))
-        out_f[0] = jnp.broadcast_to(vec_f[:, None], (kf, 128))
+        slots(flts, out_f, jnp.float32(0))
 
 
 def fused_filter_agg(cand, rows_in_block, col_arrays: dict,
@@ -769,18 +790,24 @@ def fused_filter_agg(cand, rows_in_block, col_arrays: dict,
     kern = functools.partial(_fused_kernel, plan=plan, sub=sub,
                              pshapes=pshapes)
     in_specs = [
-        pl.BlockSpec((1, sub, 128), lambda i, c, r: (c[i], 0, 0),
+        pl.BlockSpec((1, sub, 128),
+                     lambda i, c, r: (c[i], _i32(0), _i32(0)),
                      memory_space=pltpu.VMEM)
         for _ in plan.cols
     ] + [
-        pl.BlockSpec(memory_space=pltpu.SMEM) for _ in pkeys
+        # whole-array SMEM blocks with an explicit int32 index map (the
+        # default map's Python zeros are 64-bit under x64)
+        pl.BlockSpec(pshapes[k], lambda i, c, r: (_i32(0),),
+                     memory_space=pltpu.SMEM) for k in pkeys
     ]
-    out_specs = [pl.BlockSpec((1, ki, 128), lambda i, c, r: (i, 0, 0),
+    out_specs = [pl.BlockSpec((1, ki, 128),
+                              lambda i, c, r: (i, _i32(0), _i32(0)),
                               memory_space=pltpu.VMEM)]
     out_shape = [jax.ShapeDtypeStruct((B, ki, 128), jnp.int32)]
     if kf:
         out_specs.append(
-            pl.BlockSpec((1, kf, 128), lambda i, c, r: (i, 0, 0),
+            pl.BlockSpec((1, kf, 128),
+                         lambda i, c, r: (i, _i32(0), _i32(0)),
                          memory_space=pltpu.VMEM))
         out_shape.append(jax.ShapeDtypeStruct((B, kf, 128), jnp.float32))
     gs = pltpu.PrefetchScalarGridSpec(

@@ -31,16 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# newer jax exposes shard_map at top level (replication checking spelled
-# check_vma); jax <= 0.4.x ships it in experimental as check_rep. Resolve
-# once so the combine layer runs on both.
-if hasattr(jax, "shard_map"):
-    _shard_map, _SM_KW = jax.shard_map, {"check_vma": False}
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SM_KW = {"check_rep": False}
-
 SEG_AXIS = "segments"
 
 
@@ -219,9 +209,9 @@ def shard_pipeline(pipeline_fn, mesh: Mesh, cohort: bool = False, post=None):
             return P(None, SEG_AXIS) if cohort else P(SEG_AXIS)
 
         out_specs = {k: out_spec(k) for k in outs_shape}
-        fn = _shard_map(
+        fn = jax.shard_map(
             sharded, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **_SM_KW,
+            check_vma=False,
         )
         return fn(cols, n_docs, params)
 

@@ -179,9 +179,16 @@ def build_star_trees(segment, star_tree_configs) -> None:
                     dtype=f"S{width}")
                 metric_specs.append((name, DataType.BYTES))
             else:
-                v = np.asarray(segment.values(col), dtype=np.float64)
+                v = np.asarray(segment.values(col))
+                int_sum = fn == "sum" and v.dtype.kind in "iu"
+                v = v.astype(np.int64 if int_sum else np.float64)
                 if fn == "sum":
-                    acc = np.zeros(n_groups)
+                    # integer sums stay integers: the device stores DOUBLE
+                    # planes as f32 (storage/device.py), which would round
+                    # a pre-aggregated sum past 2^24 — an int64 plane rides
+                    # the exact byte-plane sum path instead, so the cube
+                    # answers SUM(int) bit-for-bit like the scan does
+                    acc = np.zeros(n_groups, dtype=v.dtype)
                     np.add.at(acc, ginv, v)
                 elif fn == "min":
                     acc = np.full(n_groups, np.inf)
@@ -189,7 +196,8 @@ def build_star_trees(segment, star_tree_configs) -> None:
                 else:
                     acc = np.full(n_groups, -np.inf)
                     np.maximum.at(acc, ginv, v)
-                metric_specs.append((name, DataType.DOUBLE))
+                metric_specs.append(
+                    (name, DataType.LONG if int_sum else DataType.DOUBLE))
             out_cols[name] = acc
 
         st_schema = Schema.build(

@@ -25,6 +25,32 @@ class TestCodec:
         # numpy fallback, but HERE the native path must be exercised
         assert native.native_available()
 
+    def test_library_of_other_source_bytes_is_not_loaded(self, tmp_path,
+                                                         monkeypatch):
+        """The library's file name carries a hash of packer.cpp's bytes: a
+        build left behind by different source (here: garbage that would
+        fail to dlopen) is never picked up — the current source compiles
+        under its own name instead. An mtime comparison could not tell
+        after the tree was copied to another machine."""
+        with open(native._SRC, "rb") as f:
+            src_bytes = f.read()
+        src = tmp_path / "packer.cpp"
+        src.write_bytes(src_bytes)
+        monkeypatch.setattr(native, "_HERE", str(tmp_path))
+        monkeypatch.setattr(native, "_SRC", str(src))
+        stale = native._lib_path()
+        with open(stale, "wb") as f:
+            f.write(b"built from the bytes above")
+        src.write_bytes(src_bytes + b"\n// edited\n")
+        fresh = native._lib_path()
+        assert fresh != stale and not os.path.exists(fresh)
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_lib_tried", False)
+        assert native._load() is not None  # dlopen of `stale` would fail
+        assert os.path.exists(fresh)
+        with open(stale, "rb") as f:
+            assert f.read() == b"built from the bytes above"
+
     @pytest.mark.parametrize("bits", [1, 2, 3, 5, 7, 8, 11, 13, 16])
     def test_roundtrip(self, bits):
         rng = np.random.default_rng(bits)

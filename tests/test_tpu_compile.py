@@ -1,0 +1,200 @@
+"""Compile the chip's kernels for a DESCRIBED TPU v5e — no chip attached.
+
+Interpret-mode tests cannot see what Mosaic and the TPU compiler refuse:
+a weak 64-bit scalar in a kernel body (the package runs with x64 on), a
+layout that pads a column 128x, a reshape that relays out 800 MB. The
+TPU compiler is installed with jax and compiles for a topology that is
+only described, so these cases guard every later PR at no chip time:
+each kernel of ops/groupby_mm.py and ops/pallas_scatter.py at the SSB
+batch's real widths, and whole pipelines at the (8, 12_500_992) batch
+shape chip_smoke.py serves.
+
+Nothing runs, so nothing here says a result is right or fast — the
+differential suites (test_pallas_scatter.py, test_groupby_mm.py) and
+chip_smoke.py do that.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), and all cases live in this ONE file so
+the worker that loads the library runs them all.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import pinot_tpu  # noqa: F401 — x64 on, as the package runs
+from pinot_tpu.engine import device as dev
+from pinot_tpu.ops import groupby_mm as mm
+from pinot_tpu.ops import pallas_scatter as ps
+
+SEG_ROWS = 12_500_000          # one SSB segment (pinot_tpu/tools/ssb.py)
+ALL_ROWS = 100_000_000         # the table
+BATCH = (8, 12_500_992)        # the padded (S, L) batch the executor builds
+NB = BATCH[1] // 4096          # zone blocks per segment
+HBM = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """shape/dtype -> ShapeDtypeStruct placed on the described chip 0."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    return make
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, mem
+    return compiled, mem
+
+
+def _compile_kernel(fn, *args):
+    compiled, mem = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    return mem
+
+
+# ---- ops/groupby_mm.py: the one-hot matmul kernels, whole table -----------
+
+
+def test_group_sums_100m(spec):
+    _compile_kernel(
+        lambda g, c: mm.group_sums(g, c, 6240, first_channel_ones=True),
+        spec((ALL_ROWS,), "int32"), spec((4, ALL_ROWS), "bfloat16"))
+
+
+def test_hll_registers_100m(spec):
+    _compile_kernel(
+        lambda s, r: mm.hll_registers(s, r, 8, 10),
+        spec((ALL_ROWS,), "int32"), spec((ALL_ROWS,), "int32"))
+
+
+# ---- ops/pallas_scatter.py: the scatter tier, one segment -----------------
+
+
+@pytest.mark.parametrize("num_groups", [2_000, 100_000])
+def test_plane_group_sums(spec, num_groups):
+    _compile_kernel(
+        lambda g, c: ps.plane_group_sums(g, c, num_groups,
+                                         first_channel_ones=True),
+        spec((SEG_ROWS,), "int32"), spec((4, SEG_ROWS), "bfloat16"))
+
+
+@pytest.mark.parametrize("dtype", ["int32", "float32"])
+def test_group_minmax(spec, dtype):
+    _compile_kernel(
+        lambda g, v: ps.group_minmax(g, v, 2_000, ("min", "max")),
+        spec((SEG_ROWS,), "int32"), spec((SEG_ROWS,), dtype))
+
+
+def test_hll_register_max(spec):
+    _compile_kernel(
+        lambda s, r: ps.hll_register_max(s, r, 4096, 22),
+        spec((SEG_ROWS,), "int32"), spec((SEG_ROWS,), "int32"))
+
+
+# q2_range_sum as the executor plans it on the SSB batch (captured from a
+# served run): filter template, column width plan, param shapes
+Q2_FILTER = (
+    "and",
+    ("range_dict", "lo_orderdate", "pr0", "pr1"),
+    ("range_dict", "lo_discount", "pr2", "pr3"),
+    ("range_raw", ("raw", "lo_quantity"), "pr4", "pr5",
+     False, True, True, False),
+)
+Q2_WIDTHS = {
+    "lo_discount": ("|u1", 0, False, ""),
+    "lo_orderdate": ("<u2", 0, False, ""),
+    "lo_quantity": ("|u1", 0, False, "<i4"),
+    "lo_revenue": ("<i4", 0, False, ""),
+}
+Q2_SUM_REVENUE = ("sum", ("raw", "lo_revenue"), (3, 256))
+
+
+def test_fused_filter_agg_q2_plan(spec):
+    """The fused filter+gather+aggregate kernel on q2's filter. q2's own
+    SUM(lo_revenue) is declined by the plan (a 4096-row block of values up
+    to 6M overflows the kernel's int32 partial), so the statement itself
+    never reaches the kernel; the aggregates below are the ones it takes."""
+    assert ps.plan_fused(Q2_FILTER, (Q2_SUM_REVENUE,), Q2_WIDTHS) is None
+    aggs = (("count", None, None),
+            ("sum", ("raw", "lo_quantity"), (1, 1 << 20)),
+            ("min", ("raw", "lo_revenue"), None),
+            ("max", ("raw", "lo_revenue"), None))
+    plan = ps.plan_fused(Q2_FILTER, aggs, Q2_WIDTHS)
+    assert plan is not None
+    n_cand = -(-NB // 16)  # ops/blockskip.py CAND_FRACTION
+    cols = {k: spec((NB, 4096 // 128, 128), Q2_WIDTHS[k][0])
+            for k in plan.cols}
+    pars = {k: spec((1,), "int32") for k in plan.pred_params}
+    _compile_kernel(
+        lambda cand, rows, c, p: ps.fused_filter_agg(cand, rows, c, p, plan),
+        spec((n_cand,), "int32"), spec((n_cand,), "int32"), cols, pars)
+
+
+# ---- whole pipelines at the served batch shape -----------------------------
+
+
+def test_pipeline_q1_groupby_real_batch(spec):
+    """q1_scan_agg as DeviceExecutor() builds it on a TPU (mm_mode and
+    pallas_mode both "tpu"): SUM(lo_revenue) GROUP BY lo_suppkey."""
+    template = ("groupby", ("true",), ("lo_suppkey",), (2000,),
+                (Q2_SUM_REVENUE,), 0, False)
+    widths = {"lo_revenue": ("<i4", 0, False, ""),
+              "lo_suppkey": ("<u2", 0, False, "")}
+    fn = dev.build_pipeline(template, mm_mode="tpu", sorted_hll_ok=True,
+                            widths=widths, pallas_mode="tpu")
+    cols = {"lo_revenue": spec(BATCH, "int32"),
+            "lo_suppkey": spec(BATCH, "uint16")}
+    params = {"off0": spec((), "int64"), "ps_alive": spec((8,), "bool")}
+    compiled, _ = _compile(fn, cols, spec((8,), "int32"), params)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pipeline_q2_blockskip_real_batch(spec):
+    """q2_range_sum's block-skip pipeline. The candidate gather must slice
+    blocks out of the (S, L) columns: a (S*NB, 4096) reshape is a relayout
+    on the TPU (segments sit on sublanes) — it cost 221 s of compile and a
+    400 MB copy of lo_revenue per query before ops/blockskip.py stopped
+    doing it. The temp bound is what catches its return."""
+    template = ("agg", Q2_FILTER, (), (), (Q2_SUM_REVENUE,), 0, False)
+    fn = dev.build_pipeline(template, mm_mode="tpu", sorted_hll_ok=True,
+                            blockskip=True, widths=Q2_WIDTHS,
+                            pallas_mode="tpu")
+    cols = {k: spec(BATCH, w[0]) for k, w in Q2_WIDTHS.items()}
+    for k in ("lo_discount", "lo_orderdate", "lo_quantity"):
+        cols["zlo::" + k] = spec((8, NB), Q2_WIDTHS[k][0])
+        cols["zhi::" + k] = spec((8, NB), Q2_WIDTHS[k][0])
+    params = {"off0": spec((), "int64"), "ps_alive": spec((8,), "bool"),
+              **{f"pr{i}": spec((), "int32") for i in range(4)},
+              "pr4": spec((), "int64"), "pr5": spec((), "int64")}
+    _, mem = _compile(fn, cols, spec((8,), "int32"), params)
+    assert mem.temp_size_in_bytes < 64 << 20, mem
