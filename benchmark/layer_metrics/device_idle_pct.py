@@ -1,0 +1,13 @@
+"""Share of the traced slice in which no operation ran on the device:
+1 - union of the ``XLA Ops`` intervals / slice."""
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "queries_per_s"
+
+
+def read(run):
+    trace = run["trace"]
+    if not trace:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
