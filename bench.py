@@ -4158,7 +4158,7 @@ def bench_observability(n_queries: int = 24):
     from pinot_tpu.common.datatypes import DataType
     from pinot_tpu.common.schema import Schema
     from pinot_tpu.common.table_config import TableConfig
-    from pinot_tpu.common.trace import span, top_level_spans
+    from pinot_tpu.common.trace import span
     from pinot_tpu.controller.controller import Controller
     from pinot_tpu.server.server import ServerInstance
     from pinot_tpu.storage.creator import build_segment
@@ -4244,12 +4244,14 @@ def bench_observability(n_queries: int = 24):
             for inst, spans in info.items():
                 if inst == "broker":
                     continue
-                total = next((s["durationMs"] for s in spans
+                total = next((s for s in spans
                               if s["phase"].endswith(".total")), None)
-                if not total:
+                if not total or not total["durationMs"]:
                     continue
-                cov = sum(s["durationMs"]
-                          for s in top_level_spans(spans)) / total
+                # the top-level phases are the total's children
+                cov = sum(s["durationMs"] for s in spans
+                          if s["parentId"] == total["spanId"]) \
+                    / total["durationMs"]
                 coverages.append(cov)
             for k, v in phase_breakdown({"traceInfo": info}).items():
                 phase_samples.setdefault(k, []).append(v)

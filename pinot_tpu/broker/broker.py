@@ -1021,15 +1021,63 @@ class Broker:
         }, t0)
 
     # ---- request handling ------------------------------------------------
-    def execute(self, sql: str, principal: str = None) -> dict:
+    def execute(self, sql: str, principal: str = None, entry=None) -> dict:
         """HTTP POST /query/sql equivalent (PinotClientRequest →
         BaseBrokerRequestHandler.handleRequest). ``principal``: the
         authenticated identity (broker HTTP basic auth) — the tenant key
         for priority admission when enabled (ISSUE 14); queries may also
-        self-identify via ``SET workloadName``."""
+        self-identify via ``SET workloadName``. ``entry``
+        (common/trace.py Entry): the HTTP door's clock reads; under
+        ``SET trace=true`` the tracer is left there and the door closes
+        the root.
+
+        A thin tracing bracket around ``_execute``: the tracer is only
+        minted after the parse, and every way out of the request has to
+        close ``broker.total``."""
+        from pinot_tpu.common import trace
+
+        traced: list = []  # [tracer, its broker.total span] once minted
+        try:
+            return self._execute(sql, principal, entry, traced)
+        finally:
+            if traced:
+                trace.end_trace()
+                # with no HTTP door this is the root: the tracer is kept
+                traced[1].close()
+
+    def _begin_trace(self, entry, t_in: float, c_in: float,
+                     t_parsed: float, c_parsed: float, traced: list):
+        """Mint the request's tracer, now that the parse has found ``SET
+        trace=true``, and back-fill what ran before it from the clock
+        reads every request takes: the door's spans and
+        ``broker.parse``. Returns the request id its trace id is made
+        of."""
+        from pinot_tpu.common import trace
+
+        request_id = next(self._request_id)
+        tracer = trace.start_trace(
+            f"{self.broker_id}-{request_id}",
+            t0=t_in if entry is None else entry.t0)
+        if entry is not None:
+            tracer.open("http.request", entry.t0, entry.c0)
+            tracer.record("http.read", entry.t0, entry.t1,
+                          (entry.c1 - entry.c0) * 1000,
+                          {"bytesIn": entry.bytes_in})
+            entry.tracer = tracer
+        traced += [tracer, tracer.open("broker.total", t_in, c_in)]
+        tracer.record("broker.parse", t_in, t_parsed,
+                      (c_parsed - c_in) * 1000)
+        return request_id
+
+    def _execute(self, sql: str, principal, entry, traced: list) -> dict:
         from pinot_tpu.common import trace
 
         t0 = time.time()
+        # the span clock, read for every request: the trace option is
+        # only known after the parse (back-filled as broker.parse)
+        t_in = time.perf_counter()
+        # the door read the CPU clock a moment ago: a system call saved
+        c_in = time.thread_time() if entry is None else entry.c1
         self.metrics.count("queries")
         if self.draining:
             # fleet drain (ISSUE 18): typed refusal, never a hang or a
@@ -1079,6 +1127,7 @@ class Broker:
             # and the result cache all share it
             gen = self._routing_gen()
             q = self._resolve_table_case(q, gen)
+            t_parsed, c_parsed = time.perf_counter(), time.thread_time()
             if q.explain and getattr(q, "analyze", False):
                 # EXPLAIN ANALYZE (ISSUE 11): execute the underlying
                 # query through the FULL scatter path (traced, so the
@@ -1100,76 +1149,83 @@ class Broker:
                     plan["resultTable"]["rows"] = [
                         [ln, i, i - 1] for i, ln in enumerate(lines)]
                 return plan
-            # tenant + priority resolution (ISSUE 14): the authenticated
-            # principal wins, then SET workloadName, then the shared
-            # 'default' bucket; ``adm_key`` is the literal digest the
-            # sub-RTT queue-jump memo and the bounded-staleness shed
-            # path key on (computed regardless of the fresh cache's
-            # trace/chaos gating — shedding must find entries even when
-            # the FRESH path is opted out)
-            tenant = pclass = None
-            adm_key = None
-            if self.admission is not None:
-                from pinot_tpu.broker.querylog import template_key
-
-                tenant, pclass = self.admission.resolve(q, principal)
-                adm_key = self.result_cache.key_for(q, template_key(q))
-            cache_key = self._result_cache_key(q, precomputed=adm_key)
-            cache_gen = None
-            cache_view = None
-            if cache_key is not None:
-                # the generation and epoch view are captured BEFORE the
-                # scatter: a cluster change mid-flight stores an entry
-                # that can never validate (conservative), not one that
-                # serves stale
-                cache_gen = gen
-                cache_view = self._epoch_view(q.table_name)
-                cached = self.result_cache.get(
-                    cache_key, cache_view, cache_gen)
-                if cached is not None:
-                    # queue jumping (ISSUE 14): a fresh result-cache hit
-                    # costs no server work, so it bypasses BOTH tenant
-                    # admission and the table quota — sub-RTT serving
-                    # never waits behind (or is starved by) cold scans —
-                    # and marks this literal digest sub-RTT so its
-                    # repeats admit at a fraction of a token
-                    self.metrics.count("resultCacheHits")
-                    resp = dict(cached)
-                    resp["resultCacheHit"] = True
-                    if self.admission is not None:
-                        self.admission.note_sub_rtt(adm_key)
-                        resp["tenant"] = tenant
-                        resp["priorityClass"] = pclass
-                    resp["requestId"] = next(self._request_id)
-                    resp["timeUsedMs"] = round((time.time() - t0) * 1000, 3)
-                    self.metrics.time_ms("query", resp["timeUsedMs"])
-                    return self._log_query(sql, q, resp, t0)
-                self.metrics.count("resultCacheMisses")
-            if self.admission is not None:
-                decision = self.admission.try_admit(
-                    tenant, pclass, load_score=self._max_load_score(),
-                    sub_rtt=self.admission.is_sub_rtt(adm_key))
-                if not decision.admitted:
-                    # degrade before rejecting: bounded-staleness cache
-                    # read (SET maxStalenessMs), else a typed 429 whose
-                    # Retry-After is THIS tenant's actual refill time
-                    return self._shed_response(sql, q, decision,
-                                               adm_key, t0)
-            if not self.quota.acquire(q.table_name, gen):
-                # quota rejection before any fan-out
-                # (BaseBrokerRequestHandler's quota check placement)
-                self.metrics.count("queriesQuotaExceeded")
-                return self._log_query(sql, q, {"exceptions": [{
-                    "errorCode": 429,
-                    "message": f"query quota exceeded for table "
-                               f"{q.table_name!r}"}],
-                    # pacing hint for clients (Retry-After analog): the
-                    # token bucket refills within about a second
-                    "retryAfterSeconds": 0.5}, t0)
+            request_id = None
             if q.options_ci().get("trace"):
-                tracer = trace.start_trace()
+                # born before admission, so that every later span of
+                # the request has a tracer
+                request_id = self._begin_trace(
+                    entry, t_in, c_in, t_parsed, c_parsed, traced)
+                tracer = traced[0]
+            with trace.span("broker.admit", tracer):
+                # tenant + priority resolution (ISSUE 14): the authenticated
+                # principal wins, then SET workloadName, then the shared
+                # 'default' bucket; ``adm_key`` is the literal digest the
+                # sub-RTT queue-jump memo and the bounded-staleness shed
+                # path key on (computed regardless of the fresh cache's
+                # trace/chaos gating — shedding must find entries even when
+                # the FRESH path is opted out)
+                tenant = pclass = None
+                adm_key = None
+                if self.admission is not None:
+                    from pinot_tpu.broker.querylog import template_key
+
+                    tenant, pclass = self.admission.resolve(q, principal)
+                    adm_key = self.result_cache.key_for(q, template_key(q))
+                cache_key = self._result_cache_key(q, precomputed=adm_key)
+                cache_gen = None
+                cache_view = None
+                if cache_key is not None:
+                    # the generation and epoch view are captured BEFORE the
+                    # scatter: a cluster change mid-flight stores an entry
+                    # that can never validate (conservative), not one that
+                    # serves stale
+                    cache_gen = gen
+                    cache_view = self._epoch_view(q.table_name)
+                    cached = self.result_cache.get(
+                        cache_key, cache_view, cache_gen)
+                    if cached is not None:
+                        # queue jumping (ISSUE 14): a fresh result-cache hit
+                        # costs no server work, so it bypasses BOTH tenant
+                        # admission and the table quota — sub-RTT serving
+                        # never waits behind (or is starved by) cold scans —
+                        # and marks this literal digest sub-RTT so its
+                        # repeats admit at a fraction of a token
+                        self.metrics.count("resultCacheHits")
+                        resp = dict(cached)
+                        resp["resultCacheHit"] = True
+                        if self.admission is not None:
+                            self.admission.note_sub_rtt(adm_key)
+                            resp["tenant"] = tenant
+                            resp["priorityClass"] = pclass
+                        resp["requestId"] = next(self._request_id)
+                        resp["timeUsedMs"] = round((time.time() - t0) * 1000, 3)
+                        self.metrics.time_ms("query", resp["timeUsedMs"])
+                        return self._log_query(sql, q, resp, t0)
+                    self.metrics.count("resultCacheMisses")
+                if self.admission is not None:
+                    decision = self.admission.try_admit(
+                        tenant, pclass, load_score=self._max_load_score(),
+                        sub_rtt=self.admission.is_sub_rtt(adm_key))
+                    if not decision.admitted:
+                        # degrade before rejecting: bounded-staleness cache
+                        # read (SET maxStalenessMs), else a typed 429 whose
+                        # Retry-After is THIS tenant's actual refill time
+                        return self._shed_response(sql, q, decision,
+                                                   adm_key, t0)
+                if not self.quota.acquire(q.table_name, gen):
+                    # quota rejection before any fan-out
+                    # (BaseBrokerRequestHandler's quota check placement)
+                    self.metrics.count("queriesQuotaExceeded")
+                    return self._log_query(sql, q, {"exceptions": [{
+                        "errorCode": 429,
+                        "message": f"query quota exceeded for table "
+                                   f"{q.table_name!r}"}],
+                        # pacing hint for clients (Retry-After analog): the
+                        # token bucket refills within about a second
+                        "retryAfterSeconds": 0.5}, t0)
             resp = self._scatter_gather(q, sql, gen, tenant=tenant,
-                                        priority=pclass)
+                                        priority=pclass,
+                                        request_id=request_id)
             if tracer is not None:
                 resp.setdefault("traceInfo", {})["broker"] = tracer.to_json()
                 if tracer.trace_id:
@@ -1179,9 +1235,6 @@ class Broker:
             return self._log_query(sql, q, {"exceptions": [{
                 "errorCode": 450,
                 "message": f"{type(e).__name__}: {e}"}]}, t0)
-        finally:
-            if tracer is not None:
-                trace.end_trace()
         own_epochs = resp.pop("__epochView__", None)
         resp["timeUsedMs"] = round((time.time() - t0) * 1000, 3)
         self.metrics.time_ms("query", resp["timeUsedMs"])
@@ -2148,7 +2201,8 @@ class Broker:
                     "brokerId": self.broker_id,
                     "timeoutMs": stage_ms,
                     "traceEnabled": trace_on,
-                    "traceId": f"{request_id}:{attempt}",
+                    "traceId": request_id,
+                    "attempt": attempt,
                     "partitions": assign["partitions"],
                     "partitionOwners": assign["owners"],
                     "endpoints": assign["endpoints"],
@@ -2457,35 +2511,38 @@ class Broker:
         return out
 
     def _scatter_gather(self, q: QueryContext, sql: str, gen=None,
-                        tenant: str = None, priority: str = None) -> dict:
+                        tenant: str = None, priority: str = None,
+                        request_id: int = None) -> dict:
         """Thin reservation bracket around the scatter body: routing
         reserves the picked instances' outstanding counts atomically with
         the pick (concurrent queries balance instead of herding), and the
         release is guaranteed here however the query settles.
         ``tenant``/``priority`` (ISSUE 14) stamp every instance request
-        so the servers' weighted-fair schedulers isolate tenants."""
+        so the servers' weighted-fair schedulers isolate tenants.
+        ``request_id``: already drawn by a traced request, whose trace
+        id is made of it."""
         reserved: list = []
         try:
             return self._scatter_gather_inner(q, sql, reserved, gen,
-                                              tenant, priority)
+                                              tenant, priority, request_id)
         finally:
             self.routing.release(reserved)
 
     def _scatter_gather_inner(self, q: QueryContext, sql: str,
                               reserved: list, gen=None,
                               tenant: str = None,
-                              priority: str = None) -> dict:
+                              priority: str = None,
+                              request_id: int = None) -> dict:
         from pinot_tpu.common.trace import active, span
 
         q = self._expand_star(q)
-        request_id = next(self._request_id)
+        if request_id is None:
+            request_id = next(self._request_id)
         # trace id: minted per request, stamped into EVERY scatter
         # request (primary + retries + hedges, each tagged with its
         # attempt kind) so per-server spans join back to one query
         tracer = active()
         trace_id = f"{self.broker_id}-{request_id}"
-        if tracer is not None:
-            tracer.trace_id = trace_id
         trace_on = tracer is not None
         # per-query failure-handling counters (the query log's view; the
         # registry counters aggregate the same events process-wide)
@@ -2526,7 +2583,7 @@ class Broker:
         # never the global observation state at put time, which can hold
         # epochs newer than the data this query actually scanned
         own_epochs: dict = {}
-        with span("broker.route"):
+        with span("broker.route") as route_span:
             for physical, time_filter in self._physical_tables(q.table_name,
                                                                gen):
                 routing, reps, rinfo = \
@@ -2557,6 +2614,9 @@ class Broker:
                     else:
                         fully_pruned.append(
                             (inst, physical, segs[:1], time_filter))
+            route_span.set(
+                segmentsRouted=sum(len(e[2]) for e in scatter),
+                segmentsPrunedByBroker=num_pruned, servers=len(n_servers))
         if not scatter and fully_pruned:
             # every segment pruned: query one anyway — the server's min/max
             # pruner short-circuits it, and the reduce gets a typed empty
@@ -2610,6 +2670,7 @@ class Broker:
                 # every attempt ships the trace flag + id, tagged with its
                 # kind, so a retried/hedged query still traces end to end
                 trace=trace_on, trace_id=trace_id, attempt=attempt,
+                parent_span=scatter_span.span_id,
                 # tenant + priority class (ISSUE 14): the server's
                 # weighted-fair scheduler groups slots by tenant
                 workload=tenant, priority=priority,
@@ -2719,6 +2780,10 @@ class Broker:
             or (self.hedging_enabled
                 and bool_option(opts, "usehedging", None) is not False))
 
+        # a wait on the servers' spans, which hang under it by the id the
+        # scatter request ships: in the tree, not in the profiler
+        scatter_span = span("broker.scatter_gather", quiet=True)
+        scatter_span.__enter__()
         for inst, phys, segs, tf in scatter:
             entries.append({
                 "inst": inst, "phys": phys, "segs": segs, "tf": tf,
@@ -2941,7 +3006,7 @@ class Broker:
                 if done is not None:
                     return finish(done)
 
-        with span("broker.scatter_gather"), self.metrics.timed("scatterMs"):
+        with self.metrics.timed("scatterMs"):
             for e in entries:
                 served, errs = harvest(e)
                 attempted_all |= e["attempted"]
@@ -2978,6 +3043,7 @@ class Broker:
                         results.append(r)
                     if parts:
                         responded.add(inst)
+        scatter_span.close()
         for t in timers:
             t.cancel()
         if any(x["errorCode"] == 250 for x in exceptions):
@@ -3009,69 +3075,73 @@ class Broker:
         with span("broker.reduce"):
             merged = merge_intermediates(q, results)
             table = finalize(q, merged)
-        resp = table.to_json()
-        if server_traces:
-            resp["traceInfo"] = server_traces
-        stats = merged.stats
-        resp.update(
-            {
-                "exceptions": exceptions,
-                # a cold-tier segment answered as an in-flight partial:
-                # the rows are honest-but-incomplete, so the response is
-                # partial (which also keeps it OUT of the result cache)
-                "partialResult": bool(exceptions)
-                or stats.num_segments_cold > 0,
-                # queried counts every instance the broker dispatched to
-                # (primary fan-out + retries + hedges); responded counts
-                # the instances whose answers the reduce actually used
-                "numServersQueried": len(n_servers | attempted_all),
-                "numServersResponded": len(responded),
-                "numRetries": attempt_counts["retries"],
-                "numHedges": attempt_counts["hedges"],
-                # replica-group routing attribution (ISSUE 10): groups
-                # touched + the chosen group's load score at pick time
-                "numReplicaGroupsQueried": rg_queried,
-                "numDocsScanned": stats.num_docs_scanned,
-                "numEntriesScannedInFilter": stats.num_entries_scanned_in_filter,
-                "numEntriesScannedPostFilter": stats.num_entries_scanned_post_filter,
-                "numSegmentsQueried": stats.num_segments_queried,
-                "numSegmentsPrunedByBroker": num_pruned,
-                "numSegmentsPrunedByValue": num_pruned_value,
-                "numSegmentsPrunedByServer": stats.num_segments_pruned,
-                "numBlocksPruned": stats.num_blocks_pruned,
-                # cold-tier segments served as honest in-flight partials
-                # while their deep-store hydration proceeds (ISSUE 12) —
-                # non-zero means a repeat of this query will cover more
-                "numSegmentsCold": stats.num_segments_cold,
-                "numSegmentsProcessed": stats.num_segments_processed,
-                "numSegmentsMatched": stats.num_segments_matched,
-                "totalDocs": stats.total_docs,
-                "numGroupsLimitReached": stats.num_groups_limit_reached,
-                # any server partial answered from its device partials
-                # cache (sub-RTT serving; querylog --per-template
-                # aggregates this into per-template hit rates)
-                "partialsCacheHit": stats.partials_cache_hit,
-                # summed across servers, like the reference's V3 metadata
-                "threadCpuTimeNs": stats.thread_cpu_time_ns,
-                "schedulerWaitMs": round(stats.scheduler_wait_ms, 3),
-                # kernel roofline accounting (ISSUE 11), summed across
-                # server partials; the per-flight detail rides "roofline"
-                "deviceBytesMoved": stats.device_bytes_moved,
-                "deviceKernelMs": round(stats.device_kernel_ms, 3),
-                "deviceLinkMs": round(stats.device_link_ms, 3),
-                "requestId": request_id,
-            }
-        )
-        if server_roofline:
-            resp["roofline"] = server_roofline
-        if stats.advisor_decisions:
-            # plan-advisor stamps (ISSUE 17): the decisions the answering
-            # servers' launches ran with, deduped by the stats merge
-            resp["advisorDecisions"] = list(stats.advisor_decisions)
-        if rg_load_score is not None:
-            resp["loadScore"] = rg_load_score
-            resp["replicaGroup"] = rg_name
-        # internal side channel for the result cache's put (stripped by
-        # execute before the response leaves the broker)
-        resp["__epochView__"] = own_epochs
+        with span("broker.respond"):
+            resp = table.to_json()
+            if server_traces:
+                resp["traceInfo"] = server_traces
+            stats = merged.stats
+            resp.update(
+                {
+                    "exceptions": exceptions,
+                    # a cold-tier segment answered as an in-flight partial:
+                    # the rows are honest-but-incomplete, so the response is
+                    # partial (which also keeps it OUT of the result cache)
+                    "partialResult": bool(exceptions)
+                    or stats.num_segments_cold > 0,
+                    # queried counts every instance the broker dispatched to
+                    # (primary fan-out + retries + hedges); responded counts
+                    # the instances whose answers the reduce actually used
+                    "numServersQueried": len(n_servers | attempted_all),
+                    "numServersResponded": len(responded),
+                    "numRetries": attempt_counts["retries"],
+                    "numHedges": attempt_counts["hedges"],
+                    # replica-group routing attribution (ISSUE 10): groups
+                    # touched + the chosen group's load score at pick time
+                    "numReplicaGroupsQueried": rg_queried,
+                    "numDocsScanned": stats.num_docs_scanned,
+                    "numEntriesScannedInFilter": stats.num_entries_scanned_in_filter,
+                    "numEntriesScannedPostFilter": stats.num_entries_scanned_post_filter,
+                    "numSegmentsQueried": stats.num_segments_queried,
+                    "numSegmentsPrunedByBroker": num_pruned,
+                    "numSegmentsPrunedByValue": num_pruned_value,
+                    "numSegmentsPrunedByServer": stats.num_segments_pruned,
+                    "numBlocksPruned": stats.num_blocks_pruned,
+                    # cold-tier segments served as honest in-flight partials
+                    # while their deep-store hydration proceeds (ISSUE 12) —
+                    # non-zero means a repeat of this query will cover more
+                    "numSegmentsCold": stats.num_segments_cold,
+                    # segments the host executor answered for any reason
+                    # (host scan, fallback, a refused or failed launch)
+                    "numSegmentsOnHost": stats.num_segments_on_host,
+                    "numSegmentsProcessed": stats.num_segments_processed,
+                    "numSegmentsMatched": stats.num_segments_matched,
+                    "totalDocs": stats.total_docs,
+                    "numGroupsLimitReached": stats.num_groups_limit_reached,
+                    # any server partial answered from its device partials
+                    # cache (sub-RTT serving; querylog --per-template
+                    # aggregates this into per-template hit rates)
+                    "partialsCacheHit": stats.partials_cache_hit,
+                    # summed across servers, like the reference's V3 metadata
+                    "threadCpuTimeNs": stats.thread_cpu_time_ns,
+                    "schedulerWaitMs": round(stats.scheduler_wait_ms, 3),
+                    # kernel roofline accounting (ISSUE 11), summed across
+                    # server partials; the per-flight detail rides "roofline"
+                    "deviceBytesMoved": stats.device_bytes_moved,
+                    "deviceKernelMs": round(stats.device_kernel_ms, 3),
+                    "deviceLinkMs": round(stats.device_link_ms, 3),
+                    "requestId": request_id,
+                }
+            )
+            if server_roofline:
+                resp["roofline"] = server_roofline
+            if stats.advisor_decisions:
+                # plan-advisor stamps (ISSUE 17): the decisions the answering
+                # servers' launches ran with, deduped by the stats merge
+                resp["advisorDecisions"] = list(stats.advisor_decisions)
+            if rg_load_score is not None:
+                resp["loadScore"] = rg_load_score
+                resp["replicaGroup"] = rg_name
+            # internal side channel for the result cache's put (stripped by
+            # execute before the response leaves the broker)
+            resp["__epochView__"] = own_epochs
         return resp

@@ -22,6 +22,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from pinot_tpu.common import trace
 from pinot_tpu.common.auth import BasicAuthAccessControl
 
 
@@ -56,7 +57,8 @@ class BrokerHttpServer:
                 pass
 
             def _send(self, code: int, payload: dict,
-                      headers: dict = None) -> None:
+                      headers: dict = None) -> int:
+                """Encode and send; returns the body's bytes."""
                 body = json.dumps(payload).encode("utf-8")
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
@@ -65,6 +67,7 @@ class BrokerHttpServer:
                     self.send_header(k, v)
                 self.end_headers()
                 self.wfile.write(body)
+                return len(body)
 
             def do_GET(self):
                 if self.path == "/health":
@@ -137,9 +140,13 @@ class BrokerHttpServer:
                 if principal is None:
                     self._reject_unauthorized()
                     return
+                # the door's clock reads, taken for every request: the
+                # trace option is only known after the broker's parse
+                entry = trace.Entry()
                 try:
                     length = int(self.headers.get("Content-Length", 0))
                     payload = json.loads(self.rfile.read(length) or b"{}")
+                    entry.read_done(length)
                     sql = payload.get("sql", "")
                     if outer.broker.draining:
                         # fleet drain (ISSUE 18): a REAL 503 before any
@@ -164,7 +171,8 @@ class BrokerHttpServer:
                     # priority admission (ISSUE 14); "" (auth disabled)
                     # falls back to SET workloadName / 'default'
                     resp = outer.broker.execute(sql,
-                                                principal=principal or None)
+                                                principal=principal or None,
+                                                entry=entry)
                     excs = resp.get("exceptions") or []
                     if excs and all(x.get("errorCode") == 429 for x in excs):
                         # over-quota: a real 429 status + Retry-After so
@@ -179,13 +187,18 @@ class BrokerHttpServer:
                         self._send(429, resp,
                                    headers={"Retry-After": str(max(1, after))})
                         return
-                    self._send(200, resp)
+                    with trace.span("http.write", entry.tracer) as sp:
+                        sp.set(bytesOut=self._send(200, resp))
                 except Exception as e:  # noqa: BLE001
                     self._send(
                         200,
                         {"exceptions": [{"errorCode": 450,
                                          "message": f"{type(e).__name__}: {e}"}]},
                     )
+                finally:
+                    if entry.tracer is not None:
+                        # the root, http.request: the tracer is kept
+                        entry.tracer.end()
 
             def _stream_query(self, sql: str, principal: str) -> None:
                 """Chunked NDJSON result delivery (ISSUE 18): one JSON

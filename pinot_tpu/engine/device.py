@@ -20,6 +20,7 @@ merge: groups are already aligned across segments when the scatter lands.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import logging
 import os
 import threading
@@ -335,37 +336,44 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     channels = [jnp.ones(n_total, dtype=jnp.bfloat16)]
     specs = []  # (i, kind, slice into channel rows, offset param key)
     row = 1
-    for i, kind, nplanes, v in plans:
-        flat = v.reshape(-1)
-        if kind == "int":
-            off = params[f"off{i}"]
-            channels.extend(mm.int_planes(flat, off, nplanes))
-        else:
-            channels.extend(mm.float_planes(flat))
-        specs.append((i, kind, slice(row, row + nplanes)))
-        row += nplanes
+    with jax.named_scope("pinot.plane_split"):
+        for i, kind, nplanes, v in plans:
+            flat = v.reshape(-1)
+            if kind == "int":
+                off = params[f"off{i}"]
+                channels.extend(mm.int_planes(flat, off, nplanes))
+            else:
+                channels.extend(mm.float_planes(flat))
+            specs.append((i, kind, slice(row, row + nplanes)))
+            row += nplanes
+        stacked = jnp.stack(channels)
 
-    if use_pallas:
-        sums = ps.plane_group_sums(
-            gid.reshape(-1), jnp.stack(channels), num_groups,
-            interpret=(pallas_mode == "interpret"),
-            first_channel_ones=True,
-        )
-    else:
-        sums = mm.group_sums(
-            gid.reshape(-1), jnp.stack(channels), num_groups,
-            interpret=(mm_mode == "interpret"), first_channel_ones=True,
-        )
-    gcount = jnp.round(sums[0]).astype(jnp.int64)
-    outs["gcount"] = gcount
-    done = set()
-    for i, kind, sl in specs:
-        planes = [sums[j] for j in range(sl.start, sl.stop)]
-        if kind == "int":
-            outs[f"a{i}_sum"] = mm.recombine_int(planes, gcount, params[f"off{i}"])
+    # pad, relayout to lanes and the Pallas kernel itself
+    # (pinot_scatter_sums / pinot_groupby_mm in the device trace)
+    with jax.named_scope("pinot.groupby_kernel"):
+        if use_pallas:
+            sums = ps.plane_group_sums(
+                gid.reshape(-1), stacked, num_groups,
+                interpret=(pallas_mode == "interpret"),
+                first_channel_ones=True,
+            )
         else:
-            outs[f"a{i}_sum"] = mm.recombine_float(planes)
-        done.add(i)
+            sums = mm.group_sums(
+                gid.reshape(-1), stacked, num_groups,
+                interpret=(mm_mode == "interpret"), first_channel_ones=True,
+            )
+    with jax.named_scope("pinot.recombine"):
+        gcount = jnp.round(sums[0]).astype(jnp.int64)
+        outs["gcount"] = gcount
+        done = set()
+        for i, kind, sl in specs:
+            planes = [sums[j] for j in range(sl.start, sl.stop)]
+            if kind == "int":
+                outs[f"a{i}_sum"] = mm.recombine_int(
+                    planes, gcount, params[f"off{i}"])
+            else:
+                outs[f"a{i}_sum"] = mm.recombine_float(planes)
+            done.add(i)
     return done
 
 
@@ -611,19 +619,23 @@ def _pack_outs(outs):
     aligned for zero-copy np views on the host side."""
     names = sorted(outs, key=lambda n: (-jnp.dtype(outs[n].dtype).itemsize, n))
     bleaves, fleaves = [], []
-    for n in names:
-        x = outs[n]
-        if _is_f64(x.dtype):
-            fleaves.append(x.reshape(-1))
-            continue
-        if x.dtype == jnp.bool_:
-            x = x.astype(jnp.uint8)
-        bleaves.append(jax.lax.bitcast_convert_type(x, jnp.uint8).reshape(-1))
-    packed = {}
-    if bleaves:
-        packed["b"] = jnp.concatenate(bleaves) if len(bleaves) > 1 else bleaves[0]
-    if fleaves:
-        packed["f"] = jnp.concatenate(fleaves) if len(fleaves) > 1 else fleaves[0]
+    with jax.named_scope("pinot.pack"):
+        for n in names:
+            x = outs[n]
+            if _is_f64(x.dtype):
+                fleaves.append(x.reshape(-1))
+                continue
+            if x.dtype == jnp.bool_:
+                x = x.astype(jnp.uint8)
+            bleaves.append(
+                jax.lax.bitcast_convert_type(x, jnp.uint8).reshape(-1))
+        packed = {}
+        if bleaves:
+            packed["b"] = jnp.concatenate(bleaves) if len(bleaves) > 1 \
+                else bleaves[0]
+        if fleaves:
+            packed["f"] = jnp.concatenate(fleaves) if len(fleaves) > 1 \
+                else fleaves[0]
     return packed
 
 
@@ -824,11 +836,12 @@ def build_pipeline(template, mm_mode: str = "auto",
             }
 
         def dense(blocks_total):
-            valid = mask_ops.valid_mask(n_docs, L, batched=True) \
-                & alive_b[:, None]
-            mask = _eval_filter(filter_tpl, data_cols, params, (S, L),
-                                widths) & valid
-            seg_matched = jnp.sum(mask, axis=1, dtype=jnp.int64)
+            with jax.named_scope("pinot.mask"):
+                valid = mask_ops.valid_mask(n_docs, L, batched=True) \
+                    & alive_b[:, None]
+                mask = _eval_filter(filter_tpl, data_cols, params, (S, L),
+                                    widths) & valid
+                seg_matched = jnp.sum(mask, axis=1, dtype=jnp.int64)
             outs = _stat_outs(
                 seg_matched, jnp.sum(jnp.where(alive_b, nd64, 0)),
                 blocks_total, blocks_total)
@@ -839,19 +852,21 @@ def build_pipeline(template, mm_mode: str = "auto",
 
         # ---- zone-map block skip (ops/blockskip.py) ----------------------
         NB = L // R
-        blocks_total = jnp.sum(jnp.where(alive_b, (nd64 + R - 1) // R, 0))
-        verdict = bs_ops.zone_verdict(filter_tpl, cols, params, (S, NB),
-                                      widths)
-        block_start = jnp.arange(NB, dtype=jnp.int32) * R
-        verdict = verdict & (block_start[None, :] < n_docs[:, None]) \
-            & alive_b[:, None]
-        flat = verdict.reshape(-1)
         total = S * NB
         frac = bs_ops.CAND_FRACTION if blockskip is True \
             or int(blockskip) <= 1 else int(blockskip)
         B = min(total, max(1, -(-total // frac)))
-        n_cand = jnp.sum(flat, dtype=jnp.int32)
-        cand, cand_valid = bs_ops.compact_candidates(flat, B)
+        with jax.named_scope("pinot.zone_verdict"):
+            blocks_total = jnp.sum(
+                jnp.where(alive_b, (nd64 + R - 1) // R, 0))
+            verdict = bs_ops.zone_verdict(filter_tpl, cols, params, (S, NB),
+                                          widths)
+            block_start = jnp.arange(NB, dtype=jnp.int32) * R
+            verdict = verdict & (block_start[None, :] < n_docs[:, None]) \
+                & alive_b[:, None]
+            flat = verdict.reshape(-1)
+            n_cand = jnp.sum(flat, dtype=jnp.int32)
+            cand, cand_valid = bs_ops.compact_candidates(flat, B)
 
         def fused_skip(ps_ops):
             """Fused filter+gather+aggregate (ops/pallas_scatter.py): the
@@ -944,11 +959,14 @@ def build_pipeline(template, mm_mode: str = "auto",
             # sub-byte planes gather at their PACKED block width (R // f
             # bytes per block; R = 4096 divides by every pack factor) and
             # unpack post-gather at the access site (_ids_col)
-            g_cols = {k: bs_ops.gather_blocks(v, cand, NB, R // _kfactor(k))
-                      for k, v in data_cols.items()}
-            mask = _eval_filter(filter_tpl, g_cols, params, (B, R),
-                                widths) & rvalid
-            block_matched = jnp.sum(mask, axis=1, dtype=jnp.int64)
+            with jax.named_scope("pinot.gather_blocks"):
+                g_cols = {
+                    k: bs_ops.gather_blocks(v, cand, NB, R // _kfactor(k))
+                    for k, v in data_cols.items()}
+            with jax.named_scope("pinot.mask"):
+                mask = _eval_filter(filter_tpl, g_cols, params, (B, R),
+                                    widths) & rvalid
+                block_matched = jnp.sum(mask, axis=1, dtype=jnp.int64)
             seg_matched = jnp.zeros(S + 1, dtype=jnp.int64).at[
                 jnp.where(cand_valid, seg_of, S)].add(block_matched)[:S]
             outs = _stat_outs(
@@ -987,6 +1005,10 @@ def build_pipeline(template, mm_mode: str = "auto",
                             lambda: _pad_table(skip()))
 
     def _aggregate(cols, params, mask, outs):
+        with jax.named_scope("pinot.aggregate"):
+            return _aggregate_stages(cols, params, mask, outs)
+
+    def _aggregate_stages(cols, params, mask, outs):
         """Filter mask → aggregation outputs; shape-agnostic over the row
         layout (dense (S, L) or gathered (B, R) — every reduction lands in
         template-shaped accumulators either way)."""
@@ -1236,6 +1258,7 @@ class DeviceExecutor:
         self._lock = threading.RLock()
         self._inflight_launches: dict = {}  # batch key -> in-flight count
         self.inflight = 0            # launches between dispatch and fetch
+        self._launch_ids = itertools.count(1)  # one per device launch
         self.coalescer = LaunchCoalescer()
         # cumulative host-link observability (bench reads deltas per query)
         self.fetch_bytes_total = 0
@@ -1764,7 +1787,7 @@ class DeviceExecutor:
         bits.extend(g.name for g in (q.group_by or ()) if g.is_identifier)
         return ":".join(bits)
 
-    def _make_resolve(self, bufs_dev, layout, tracer=None, flight=None):
+    def _make_resolve(self, bufs_dev, layout, flight=None, attrs=None):
         """fetch-phase closure shared by solo and cohort launches: ONE
         blocking device_get of the dispatched packed buffer, observability
         accounting under the lock, unpack by the precomputed layout.
@@ -1773,51 +1796,59 @@ class DeviceExecutor:
         (block_until_ready — remaining device compute since dispatch) and
         a LINK wait (device_get — the host transfer): the split feeds the
         ALWAYS-ON roofline accounting (ISSUE 11 — achieved GB/s needs
-        kernel-ms without tracing armed), and ``tracer`` (the dispatching
-        query's, cohorts: the LEADER's) additionally records the pair as
-        spans — the waterfall's kernel-ms vs link-ms separation. The
+        kernel-ms without tracing armed). Whoever runs the one fetch —
+        the launching query, or the first member of a cohort to get here
+        — also records the pair, and the unpack, as spans on ITS trace
+        (the thread's active tracer: InflightLaunch._traced_resolve). The
         untraced overhead is one extra no-op call on an already-complete
         buffer.
 
         ``flight``: the launch's roofline flight dict (None = no
         accounting, e.g. the bench's profile captures); filled with the
-        per-flight record via _note_flight after the unpack."""
+        per-flight record via _note_flight after the unpack. ``attrs``:
+        what the members' traces say of this launch (``launchId``,
+        ``cohortSize``, ``cohortPadded``; ``partialsCacheHit`` where
+        nothing was launched); rides the closure as ``resolve.stamp``
+        with the wait's span, so the member that fetched can add its own
+        role to it."""
+        stamp = {"attrs": attrs or {}, "wait_span": None}
+
         def resolve():
             import time as _time
 
             if faults.ACTIVE:
                 faults.inject("device.fetch")
+            wait_span = stamp["wait_span"] = trace_span(
+                "executor.device_wait")
+            wait_span.set(**stamp["attrs"])
             _t_get = _time.perf_counter()
-            if tracer is not None:
-                with trace_span("kernel", tracer):
-                    jax.block_until_ready(bufs_dev)
-            else:
+            with wait_span:
                 jax.block_until_ready(bufs_dev)
             _t_kernel = _time.perf_counter()
-            if tracer is not None:
-                with trace_span("link", tracer):
-                    bufs = jax.device_get(bufs_dev)
-            else:
+            with trace_span("executor.link"):
                 bufs = jax.device_get(bufs_dev)
             # blocking wait = link round trip + kernel; bench subtracts it
             # from wall time for a MEASURED host_ms (floor-subtraction
             # overstated host work by the link's RTT variance)
             _t_link = _time.perf_counter()
             wait = _t_link - _t_get
-            bufs = {k: np.asarray(v) for k, v in bufs.items()}
-            fetched = sum(v.nbytes for v in bufs.values())
-            with self._lock:
-                self.last_get_wait_s = wait
-                # observability: what actually crossed the host link
-                self.fetch_bytes_total += fetched
-                self.fetch_leaves_total += len(bufs)
-            self.metrics.time_ms("deviceFetchMs", wait * 1e3)
-            outs = _unpack_outs(bufs, layout)
-            if flight is not None:
-                self._note_flight(flight, outs, fetched,
-                                  _t_kernel - _t_get, _t_link - _t_kernel)
+            with trace_span("executor.unpack"):
+                bufs = {k: np.asarray(v) for k, v in bufs.items()}
+                fetched = sum(v.nbytes for v in bufs.values())
+                with self._lock:
+                    self.last_get_wait_s = wait
+                    # observability: what actually crossed the host link
+                    self.fetch_bytes_total += fetched
+                    self.fetch_leaves_total += len(bufs)
+                self.metrics.time_ms("deviceFetchMs", wait * 1e3)
+                outs = _unpack_outs(bufs, layout)
+                if flight is not None:
+                    self._note_flight(flight, outs, fetched,
+                                      _t_kernel - _t_get,
+                                      _t_link - _t_kernel)
             return outs
 
+        resolve.stamp = stamp
         return resolve
 
     # ---- kernel roofline accounting (ISSUE 11) ---------------------------
@@ -2077,16 +2108,21 @@ class DeviceExecutor:
         for _attempt in range(3):
             ctx = self.batch_for(segments, retain=True)
             tpl_box: list = []
+            # the template build, up to the gather: closed by
+            # _launch_pinned where it ends, or here if it raises
+            tpl_span = trace_span("engine.template", tracer)
+            tpl_span.__enter__()
             try:
                 handle = self._launch_pinned(q, ctx, batch_key, segments,
                                              aggs, final, alive, tpl_box,
-                                             tracer, reduce_mode)
+                                             tracer, reduce_mode, tpl_span)
                 handle.tracer = tracer
                 self.metrics.time_ms(
                     "deviceLaunchMs",
                     (time.perf_counter() - t_launch) * 1e3)
                 return handle
             except BaseException as e:
+                tpl_span.close()
                 self._release_launch(batch_key)
                 if not _is_device_runtime_error(e):
                     raise
@@ -2131,7 +2167,8 @@ class DeviceExecutor:
 
     def _launch_pinned(self, q, ctx, batch_key, segments, aggs,
                        final, alive_hint=None, tpl_box=None,
-                       tracer=None, reduce_mode=None) -> InflightLaunch:
+                       tracer=None, reduce_mode=None,
+                       tpl_span=None) -> InflightLaunch:
         params: dict = {}
         # host-bytes side channel: engine/params.py _slot records each
         # literal's (dtype, shape, bytes) here BEFORE upload, so the
@@ -2425,6 +2462,8 @@ class DeviceExecutor:
             # and per-rung GB/s feed the template's memo at resolve time
             flight["adv_key"] = adv_key
 
+        if tpl_span is not None:
+            tpl_span.close()
         # device partials cache: a repeat execution — same pipeline, same
         # batch, same literal/ps_alive/param VALUES — skips the gather +
         # dispatch + kernel and re-fetches the cached packed buffer (one
@@ -2445,8 +2484,9 @@ class DeviceExecutor:
                 bufs_dev, clayout = hit
                 if flight is not None:
                     flight["cache_hit"] = True
-                resolve = self._make_resolve(bufs_dev, clayout, tracer,
-                                             flight)
+                resolve = self._make_resolve(
+                    bufs_dev, clayout, flight,
+                    attrs={"partialsCacheHit": True})
                 handle = InflightLaunch(self, q, ctx, template, aggs,
                                         batch_key, resolve)
                 handle.cache_hit = True
@@ -2457,7 +2497,7 @@ class DeviceExecutor:
                 handle.adv_trim_keep = adv_trim_keep
                 return handle
         cols = {}
-        with trace_span("gather", tracer):
+        with trace_span("executor.gather", tracer):
             for c in sorted(needed):
                 if c.startswith(bs_ops.ZLO):
                     cols[c] = ctx.zone_map(c[len(bs_ops.ZLO):])[0]
@@ -2522,10 +2562,9 @@ class DeviceExecutor:
             synth = _neutral_outs(layout)
             return InflightLaunch(self, q, ctx, template, aggs, batch_key,
                                   lambda: synth)
-        with trace_span("dispatch", tracer):
-            resolve = self._dispatch(
-                entry, batch_key, cols, n_docs, params, lkey, layout, tracer,
-                cache_key, flight, adv_key=adv_key, adv_notes=adv_notes)
+        resolve = self._dispatch(
+            entry, batch_key, cols, n_docs, params, lkey, layout, tracer,
+            cache_key, flight, adv_key=adv_key, adv_notes=adv_notes)
         handle = InflightLaunch(self, q, ctx, template, aggs, batch_key,
                                 resolve)
         handle.flight = flight
@@ -2615,10 +2654,12 @@ class DeviceExecutor:
                     return outs
             else:
                 inner = sharded
-            pipeline = jax.jit(
-                lambda cols, n_docs, params: _pack_outs(
-                    inner(cols, n_docs, params))
-            )
+            # named: the jitted entry points show in the profiler's
+            # trace under these names (XLA Modules: jit_pinot_pipeline)
+            def pinot_pipeline(cols, n_docs, params):
+                return _pack_outs(inner(cols, n_docs, params))
+
+            pipeline = jax.jit(pinot_pipeline)
             entry = {
                 "pipeline": pipeline, "inner": inner, "raw": raw_cohort,
                 "agg_tpls": agg_tpls, "final": final,
@@ -2636,11 +2677,11 @@ class DeviceExecutor:
         the InflightLaunch fetch phase blocks on. Coalescing is disabled
         under profile capture (the bench must see per-query launches).
 
-        ``tracer`` rides into the resolve closure: a solo launch's fetch
-        spans land on the launching query's trace; a COHORT's shared
-        fetch spans land on the leader's (whoever opened the window
-        supplies the launch_fn, hence the tracer) — member queries still
-        get their own fetch-phase span from InflightLaunch.fetch."""
+        ``tracer`` records this query's own launch-phase spans: the
+        leader's window wait (``executor.launch_wait``), its ``stack``
+        and ``dispatch``; a solo launch's ``dispatch``. A member's join
+        returns at once — it records its waits in its fetch phase
+        (InflightLaunch._traced_resolve)."""
         co = self.coalescer
         if (co is not None and not self.profile_enabled
                 and co.should_window(self.inflight)):
@@ -2664,13 +2705,23 @@ class DeviceExecutor:
                     if adv_notes is not None:
                         adv_notes.append(note)
 
+            # the leader's window: a wait for OTHER requests, so it is
+            # written to the profiler; closed when the window does
+            window = trace_span("executor.launch_wait", tracer)
+
             def _launch(members, _ak=adv_key):
+                window.close()
                 if _ak is not None and self.advisor is not None:
                     self.advisor.observe(_ak, cohort=len(members))
                 return self._cohort_launch(
                     entry, cols, n_docs, members, lkey, tracer, flight)
 
-            cohort, idx = co.join(ckey, params, _launch, window_s=window_s)
+            window.__enter__()
+            try:
+                cohort, idx = co.join(ckey, params, _launch,
+                                      window_s=window_s)
+            finally:
+                window.cancel()  # a member: the window was not its own
 
             def resolve(_c=cohort, _i=idx):
                 return _c.resolve_member(_i)
@@ -2679,6 +2730,7 @@ class DeviceExecutor:
             # all-abandoned cohort still signals fetch_done so the next
             # stream window doesn't poll out its cap
             resolve.abandon = cohort.note_abandoned
+            resolve.cohort, resolve.index = cohort, idx
             return resolve
         return self._solo_launch(entry, cols, n_docs, params, layout, tracer,
                                  cache_key, flight)
@@ -2693,14 +2745,21 @@ class DeviceExecutor:
                     sum(int(np.prod(v.shape, dtype=np.int64))
                         * v.dtype.itemsize for v in cols.values()),
                 )
-        bufs_dev = pipeline(cols, n_docs, params)  # async dispatch
+        launch_id = next(self._launch_ids)
+        dispatch = trace_span("executor.dispatch", tracer)
+        dispatch.set(launchId=launch_id)
+        with dispatch:
+            bufs_dev = pipeline(cols, n_docs, params)  # async dispatch
         if cache_key is not None:
             # cache the dispatched buffer itself (immutable): the repeat
             # query fetches it again without gather/dispatch/kernel.
             # Cohort members never insert — their buffer interleaves the
             # whole cohort's rows
             self._partials_put(cache_key, bufs_dev, layout)
-        return self._make_resolve(bufs_dev, layout, tracer, flight)
+        return self._make_resolve(
+            bufs_dev, layout, flight,
+            attrs={"launchId": launch_id, "cohortSize": 1,
+                   "cohortPadded": 1})
 
     def _cohort_launch(self, entry, cols, n_docs, members, lkey, tracer=None,
                        flight=None):
@@ -2715,7 +2774,13 @@ class DeviceExecutor:
             layout = entry["layouts"][lkey]
             base = self._solo_launch(entry, cols, n_docs, members[0], layout,
                                      tracer, flight=flight)
-            return lambda: {k: v[None] for k, v in base().items()}
+
+            def alone():
+                return {k: v[None] for k, v in base().items()}
+
+            alone.stamp = base.stamp
+            return alone
+        launch_id = next(self._launch_ids)
         pipeline_v, inner_v = self._cohort_pipeline(entry)
         # pad the cohort to the next power of two (repeating the last
         # member's params): jit re-specializes per stack size, and ragged
@@ -2725,22 +2790,30 @@ class DeviceExecutor:
         # (idx < real size) never see the padding
         n_real = len(members)
         n_pad = 1 << (n_real - 1).bit_length()
-        padded = list(members) + [members[-1]] * (n_pad - n_real)
-        pstack = {k: jnp.stack([m[k] for m in padded])
-                  for k in members[0]}
-        # literal-free templates have EMPTY params; vmap needs at least one
-        # batched leaf, so every cohort rides a synthetic member index
-        # (templates index params by name — an extra key is never read)
-        pstack["__member__"] = jnp.arange(n_pad, dtype=jnp.int32)
-        ck = (lkey, n_pad)
-        layout = entry["cohort_layouts"].get(ck)
-        if layout is None:
-            layout = _out_layout(
-                jax.eval_shape(inner_v, cols, n_docs, pstack))
-            with self._lock:
-                entry["cohort_layouts"][ck] = layout
-        bufs_dev = pipeline_v(cols, n_docs, pstack)  # async dispatch
-        return self._make_resolve(bufs_dev, layout, tracer, flight)
+        with trace_span("executor.stack", tracer):
+            padded = list(members) + [members[-1]] * (n_pad - n_real)
+            pstack = {k: jnp.stack([m[k] for m in padded])
+                      for k in members[0]}
+            # literal-free templates have EMPTY params; vmap needs at
+            # least one batched leaf, so every cohort rides a synthetic
+            # member index (templates index params by name — an extra key
+            # is never read)
+            pstack["__member__"] = jnp.arange(n_pad, dtype=jnp.int32)
+            ck = (lkey, n_pad)
+            layout = entry["cohort_layouts"].get(ck)
+            if layout is None:
+                layout = _out_layout(
+                    jax.eval_shape(inner_v, cols, n_docs, pstack))
+                with self._lock:
+                    entry["cohort_layouts"][ck] = layout
+        dispatch = trace_span("executor.dispatch", tracer)
+        dispatch.set(launchId=launch_id)
+        with dispatch:
+            bufs_dev = pipeline_v(cols, n_docs, pstack)  # async dispatch
+        return self._make_resolve(
+            bufs_dev, layout, flight,
+            attrs={"launchId": launch_id, "cohortSize": n_real,
+                   "cohortPadded": n_pad})
 
     def _cohort_pipeline(self, entry):
         """(jitted packed pipeline, inner fn) over params carrying a
@@ -2773,11 +2846,15 @@ class DeviceExecutor:
                     return _post(_raw(cols, n_docs, p), p)
 
             def inner_v(cols, n_docs, pstack, _one=one):
-                return jax.vmap(
-                    lambda p: _one(cols, n_docs, p))(pstack)
-        pipeline_v = jax.jit(
-            lambda cols, n_docs, pstack: _pack_outs(
-                inner_v(cols, n_docs, pstack)))
+                def pinot_cohort_member(p):
+                    return _one(cols, n_docs, p)
+
+                return jax.vmap(pinot_cohort_member)(pstack)
+
+        def pinot_cohort_pipeline(cols, n_docs, pstack):
+            return _pack_outs(inner_v(cols, n_docs, pstack))
+
+        pipeline_v = jax.jit(pinot_cohort_pipeline)
         with self._lock:
             if entry["cohort"] is None:
                 entry["cohort"] = (pipeline_v, inner_v)

@@ -243,6 +243,8 @@ class QueryEngine:
                 # cold-tier segments that answered as in-flight partials
                 # while their deep-store download proceeds (ISSUE 12)
                 "numSegmentsCold": stats.num_segments_cold,
+                # segments the host executor answered for any reason
+                "numSegmentsOnHost": stats.num_segments_on_host,
                 "numGroupsLimitReached": stats.num_groups_limit_reached,
                 "partialsCacheHit": stats.partials_cache_hit,
                 "totalDocs": stats.total_docs,
@@ -495,7 +497,7 @@ class QueryEngine:
             # must release the in-flight handles or their batch pins leak
             try:
                 host_results = []
-                with span("host_scan", tracer):
+                with span("engine.host_scan", tracer):
                     for s in host_segs:
                         if deadline is not None:
                             deadline.check("host scan")
@@ -509,6 +511,11 @@ class QueryEngine:
             res = list(results)
             ran = executed
             fallback_pruned = []  # stats-pruned members of fallen-back handles
+            # segments the device answered / the host executor answered
+            # for any reason (host scan, fetch-time fallback, a refused
+            # or failed launch): the second is ExecutionStats'
+            # always-on num_segments_on_host
+            on_device, on_host = 0, len(host_results)
             if device_handles:
                 # ANY failure below must drop every remaining in-flight
                 # launch's batch pin (handle.release is idempotent after
@@ -522,6 +529,8 @@ class QueryEngine:
                         handle, segs_of_handle = pending.pop(0)
                         try:
                             res.append(handle.fetch())
+                            on_device += sum(id(s) not in scan_pruned
+                                             for s in segs_of_handle)
                         except DeviceUnsupported:
                             # fetch-time fallback (sorted group-table
                             # overflow, or a device-runtime failure the
@@ -541,9 +550,11 @@ class QueryEngine:
                                 s for s in segs_of_handle
                                 if id(s) in scan_pruned)
 
+                            on_host += len(live)
+
                             def _host_rerun(_segs=live):
                                 out = []
-                                with span("host_fallback", tracer):
+                                with span("engine.host_fallback", tracer):
                                     for s in _segs:
                                         if deadline is not None:
                                             deadline.check(
@@ -581,8 +592,11 @@ class QueryEngine:
                     empty.stats.num_segments_queried = 0
                     res.append(empty)
 
-            with span("merge", tracer):
+            with span("engine.merge", tracer) as merge_span:
                 merged = merge_intermediates(q, res)
+            merge_span.set(segmentsOnDevice=on_device,
+                           segmentsOnHost=on_host)
+            merged.stats.num_segments_on_host += on_host
             # per-flight roofline records (ISSUE 11) concatenate across
             # partials (merge_intermediates builds a fresh result; the
             # single-partial shortcut passes its own list through)

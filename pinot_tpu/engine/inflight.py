@@ -26,6 +26,8 @@ from __future__ import annotations
 import threading
 import time
 
+from pinot_tpu.common.trace import span as trace_span
+
 
 class InflightLaunch:
     """A dispatched-but-not-fetched device launch.
@@ -55,8 +57,8 @@ class InflightLaunch:
         # optional explicit Tracer (common/trace.py), set by the executor
         # when the query is traced: the fetch phase may run on a different
         # thread than the launch (PR-2 split) or ride a cohort whose
-        # shared buffer another member resolves — spans recorded against
-        # the handle's tracer land on THIS query's trace regardless
+        # shared buffer another member resolves — every member records
+        # its own waits on its own trace (_traced_resolve)
         self.tracer = None
         # True when the launch was served from the device partials cache
         # (no gather/dispatch/kernel — the fetch re-reads a cached packed
@@ -92,16 +94,8 @@ class InflightLaunch:
                     self._note_abandoned()
                     raise
             try:
-                if self.tracer is not None:
-                    # the member-side fetch wait: covers the cohort-shared
-                    # resolve (whose own kernel/link sub-spans land on the
-                    # LEADER's trace) as well as the solo path
-                    from pinot_tpu.common.trace import span
-
-                    with span("device_fetch", self.tracer):
-                        outs = self._resolve()
-                else:
-                    outs = self._resolve()
+                outs = self._resolve() if self.tracer is None \
+                    else self._traced_resolve(self.tracer)
             except Exception as e:  # noqa: BLE001 — may convert to fallback
                 # device-runtime failures (XlaRuntimeError /
                 # RESOURCE_EXHAUSTED, real or injected) convert to the
@@ -117,10 +111,11 @@ class InflightLaunch:
             self._executor._note_device_success(
                 self._template, self._batch_key)
             adv_key = getattr(self, "adv_key", None)
-            result = self._executor._to_intermediate(
-                self._q, self._ctx, self._template, outs, self._aggs,
-                cache_hit=self.cache_hit, adv_key=adv_key,
-                adv_trim_keep=getattr(self, "adv_trim_keep", None))
+            with trace_span("executor.unpack", self.tracer):
+                result = self._executor._to_intermediate(
+                    self._q, self._ctx, self._template, outs, self._aggs,
+                    cache_hit=self.cache_hit, adv_key=adv_key,
+                    adv_trim_keep=getattr(self, "adv_trim_keep", None))
             result.stats.partials_cache_hit = self.cache_hit
             # plan-advisor stamps + cache-hit feedback (ISSUE 17): the
             # decisions this launch ran with ride the result's stats to
@@ -145,6 +140,47 @@ class InflightLaunch:
             return result
         finally:
             self._executor._release_launch(self._batch_key)
+
+    def _traced_resolve(self, tracer):
+        """``_resolve()`` for a traced member, on its own thread and its
+        own tracer. Whoever runs a launch's one fetch records
+        ``executor.device_wait`` / ``link`` / ``unpack`` as it goes (the
+        shared resolve spans the thread's ACTIVE tracer, so this member's
+        is made that); every other member back-fills its wait from the
+        clock here and the cohort's ``t_dispatched``. A member that is
+        not the leader waited first for the leader's dispatch
+        (``executor.launch_wait``), then for the device. Exactly one
+        span of a launched request — its ``executor.device_wait`` —
+        carries ``launchId``, ``cohortSize``, ``cohortPadded``, ``role``
+        and ``windowKind``."""
+        from pinot_tpu.common import trace
+
+        r = self._resolve
+        prev = trace.activate(tracer)
+        t_enter = time.perf_counter()
+        try:
+            outs = r()
+        finally:
+            trace.activate(prev)
+        t_exit = time.perf_counter()
+        cohort = getattr(r, "cohort", None)
+        stamp = getattr(r, "stamp", None) if cohort is None else cohort.stamp
+        if stamp is None:
+            return outs  # nothing was launched (a fully pruned batch)
+        mine = {"role": "solo", "windowKind": "none"} if cohort is None \
+            else {"role": "member" if r.index else "leader",
+                  "windowKind": cohort.window_kind}
+        t_dispatched = t_enter
+        if mine["role"] == "member":
+            t_dispatched = min(max(cohort.t_dispatched, t_enter), t_exit)
+            tracer.record("executor.launch_wait", t_enter, t_dispatched)
+        wait_span = stamp.get("wait_span")
+        if wait_span is not None and wait_span.tracer is tracer:
+            wait_span.set(**mine)  # this member ran the fetch
+        else:
+            tracer.record("executor.device_wait", t_dispatched, t_exit,
+                          attrs={**stamp["attrs"], **mine})
+        return outs
 
     def _note_abandoned(self):
         """Tell a cohort this member will never fetch (resolve closures
@@ -185,6 +221,14 @@ class _Cohort:
 
     def __init__(self, launch_fn):
         self._launch_fn = launch_fn
+        # what the members' traces say of this launch: whether the
+        # leader's window was the fixed micro-batch or held open for the
+        # predecessor's fetch ("stream"), the instant the one stacked
+        # launch was dispatched, and the shared resolve's launch stamp
+        # (DeviceExecutor._make_resolve: launchId, sizes)
+        self.window_kind = "fixed"
+        self.t_dispatched = None
+        self.stamp = None
         self.leader_thread = threading.current_thread()  # creator leads
         self.members = []          # per-member params dicts, join order
         self.open = True           # False once the window closed
@@ -207,6 +251,8 @@ class _Cohort:
         """Leader only: one stacked launch for the whole cohort."""
         try:
             self._shared_resolve = self._launch_fn(self.members)
+            self.stamp = getattr(self._shared_resolve, "stamp", None)
+            self.t_dispatched = time.perf_counter()
         except BaseException as e:  # noqa: BLE001 — members must observe it
             self.error = e
             self.fetch_done.set()  # nothing will ever fetch; unblock successor
@@ -361,6 +407,7 @@ class LaunchCoalescer:
         # can't stall the stream).
         if pred_done is not None:
             self.stream_windows += 1
+            c.window_kind = "stream"
             deadline = time.monotonic() + self.stream_cap_s
             while not c.full.is_set() and not pred_done.is_set():
                 left = deadline - time.monotonic()

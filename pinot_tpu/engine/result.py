@@ -34,6 +34,11 @@ class ExecutionStats:
     # store, the touch scheduled an async hydration, and the result is
     # an honest in-flight partial (numSegmentsCold in responses)
     num_segments_cold: int = 0
+    # segments of this execution answered by the HOST executor for any
+    # reason — host scan, fetch-time fallback, a refused or failed device
+    # launch (numSegmentsOnHost in responses): always on, so that a
+    # launch the executor refuses uncounted cannot reach the host unseen
+    num_segments_on_host: int = 0
     total_docs: int = 0
     time_used_ms: float = 0.0
     # per-query resource accounting (reference: DataTable V3 metadata
@@ -96,6 +101,7 @@ class ExecutionStats:
         self.num_segments_pruned += other.num_segments_pruned
         self.num_blocks_pruned += other.num_blocks_pruned
         self.num_segments_cold += other.num_segments_cold
+        self.num_segments_on_host += other.num_segments_on_host
         self.total_docs += other.total_docs
         self.thread_cpu_time_ns += other.thread_cpu_time_ns
         self.scheduler_wait_ms += other.scheduler_wait_ms

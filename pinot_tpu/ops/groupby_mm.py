@@ -233,6 +233,8 @@ def _launch(ids_lane, ch_operand, ch_spec_kind, *, a_real, hpad, lo, nsuper,
         scratch_shapes=[pltpu.VMEM((a_real, hpad, lo), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        # the name the profiler's trace shows the kernel under
+        name="pinot_hll_mm" if rho_mode else "pinot_groupby_mm",
     )(ids_lane, ch_operand)
     return jnp.sum(out, axis=0, dtype=jnp.float64)
 

@@ -264,6 +264,7 @@ def plane_group_sums(gid, channels, num_groups: int, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(a_real, hp, blk, stacked)),
         interpret=interpret,
+        name="pinot_scatter_sums",
     )(ids_lane, ch)
     # (npart*nsuper, A, hp, LO) → superblock partials reduce in f64, then
     # partitions concatenate along the group axis
@@ -370,6 +371,7 @@ def group_minmax(gid, values, num_groups: int, ops: tuple, *,
             vmem_limit_bytes=max(
                 16 << 20, (len(ops) + 3) * MINMAX_SPAN * blk * 4)),
         interpret=interpret,
+        name="pinot_scatter_minmax",
     )(ids_lane, v_lane)
     if not isinstance(outs, (list, tuple)):
         outs = [outs]
@@ -472,6 +474,7 @@ def hll_register_max(slot, rho, nslots: int, nrho: int, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(nrho, hp, blk, stacked)),
         interpret=interpret,
+        name="pinot_scatter_hll",
     )(ids_lane, rho_lane)
     return out.reshape(npart * gp)[:nslots]
 
@@ -820,6 +823,7 @@ def fused_filter_agg(cand, rows_in_block, col_arrays: dict,
         kern, grid_spec=gs,
         out_shape=out_shape if kf else out_shape[0],
         interpret=interpret,
+        name="pinot_fused_filter_agg",
     )(cand.astype(jnp.int32), rows_in_block.astype(jnp.int32),
       *[col_arrays[k] for k in plan.cols],
       *[param_arrays[k] for k in pkeys])

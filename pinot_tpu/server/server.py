@@ -447,7 +447,11 @@ class ServerInstance:
         (the broker re-routes them to replicas) while queries already
         counted in ``_inflight_queries`` drain inside the configured
         window."""
+        # the span clock, read for every request: whether it is traced
+        # is only known once it is decoded (back-filled as server.decode)
+        t_in, c_in = time.perf_counter(), time.thread_time()
         req = parse_instance_request(request)
+        clock = (t_in, c_in, time.perf_counter(), time.thread_time())
         with self._inflight_cond:
             if self._shutting_down:
                 self.metrics.count("queriesRejected")
@@ -457,27 +461,46 @@ class ServerInstance:
                     f"draining for shutdown")
             self._inflight_queries += 1
         try:
-            return self._submit_inner(req)
+            return self._submit_inner(req, clock)
         finally:
             with self._inflight_cond:
                 self._inflight_queries -= 1
                 self._inflight_cond.notify_all()
 
-    def _submit_inner(self, req: dict) -> bytes:
+    @staticmethod
+    def _begin_trace(req: dict, clock: tuple):
+        """The server's tracer of a traced request, under the broker's
+        trace id and hung under the broker span whose id came with the
+        request. ``clock``: (perf_counter, thread_time) at the request's
+        entry and after its decode — ``server.total`` starts at the
+        first, ``server.decode`` is back-filled from both."""
+        from pinot_tpu.common import trace
+
+        t_in, c_in, t_dec, c_dec = clock
+        tracer = trace.Tracer(req.get("traceId"),
+                              parent_id=req.get("parentSpanId"), t0=t_in)
+        tracer.open("server.total", t_in, c_in).set(
+            attempt=req.get("attempt"))
+        tracer.record("server.decode", t_in, t_dec, (c_dec - c_in) * 1000)
+        return tracer
+
+    def _submit_inner(self, req: dict, clock: tuple) -> bytes:
         from pinot_tpu.common import trace
 
         deadline = self._request_deadline(req)
         # broker-stamped tracing (traceEnabled + traceId ride the
         # instance request, retries/hedges included): the tracer exists
-        # BEFORE compile so the compile phase itself is a span. A direct
+        # BEFORE compile so the plan phase itself is a span. A direct
         # submit that only carries SET trace=true in its SQL gets its
-        # tracer after compile (no compile span) in _handle_submit_launch.
-        tracer = trace.Tracer(req.get("traceId")) \
+        # tracer after compile (no plan span).
+        tracer = self._begin_trace(req, clock) \
             if req.get("traceEnabled") else None
         try:
             self.metrics.count("queries")
-            with trace.span("server.compile", tracer):
+            with trace.span("server.plan", tracer):
                 q = self._compile_admitted(req["sql"], deadline)
+            if tracer is None and q.options_ci().get("trace"):
+                tracer = self._begin_trace(req, clock)
             if deadline is None:
                 # no broker-shipped budget: fall back to SET timeoutMs
                 # from the now-compiled options (embedded submits)
@@ -532,6 +555,10 @@ class ServerInstance:
         except Exception as e:  # noqa: BLE001 — query errors ship in-band
             self.metrics.count("queryErrors")
             return encode_error("query_error", f"{type(e).__name__}: {e}")
+        finally:
+            if tracer is not None:
+                # the root, server.total: the tracer is kept
+                tracer.end()
 
     def _handle_submit_launch(self, req: dict, q, acct: dict = None,
                               deadline: Deadline = None, tracer=None):
@@ -542,14 +569,13 @@ class ServerInstance:
         here and re-raise into the submit error path).
 
         The tracer is EXPLICIT (common/trace.py): it was minted in
-        _submit_inner from the broker-stamped traceEnabled/traceId (or
-        here, for direct submits whose SQL says SET trace=true) and rides
+        _submit_inner from the broker-stamped traceEnabled/traceId (or,
+        for direct submits, from the SQL's SET trace=true) and rides
         by reference through the engine, the device launch handles, and
         the fetch closure — the PR-2 launch/fetch thread split and
         coalesced cohorts record onto the right query's trace."""
         import time as _time
 
-        from pinot_tpu.common import trace
         from pinot_tpu.common.trace import span
 
         t_cpu = _time.thread_time_ns()
@@ -557,12 +583,13 @@ class ServerInstance:
         # before compile/admission
         timer = self.metrics.timed("query")
         timer.__enter__()
-        if tracer is None and q.options_ci().get("trace"):
-            tracer = trace.Tracer(req.get("traceId"))
         if tracer is not None and acct:
             # the scheduler published its admission wait before running
             # this fn — back-fill it as the queue phase
-            tracer.add_ms("server.queue", acct.get("scheduler_wait_ms", 0.0))
+            now = _time.perf_counter()
+            tracer.record(
+                "server.queue",
+                now - acct.get("scheduler_wait_ms", 0.0) / 1000.0, now)
         tdm, acquired = None, []
 
         def cleanup():
@@ -609,7 +636,7 @@ class ServerInstance:
             # broker result cache (conservative re-scatter), never stamp
             # pre-mutation rows with the post-mutation epoch
             epoch_at_start = freshness.epoch(q.table_name)
-            with span("server.execute", tracer):
+            with span("server.execute", tracer, quiet=True):
                 # the fetch-time host fallback (sorted-table overflow) is
                 # heavy CPU work on a slot-free thread: re-admit it
                 # through the scheduler so a fallback storm can't escape
@@ -631,7 +658,7 @@ class ServerInstance:
         def finish() -> bytes:
             try:
                 # the blocking link wait lives here, OUTSIDE the slot
-                with span("server.fetch", tracer):
+                with span("server.fetch", tracer, quiet=True):
                     merged = fetch_merged()
                 with span("server.trim", tracer):
                     merged = trim_group_by(q, merged, self.group_trim_size)
@@ -653,6 +680,9 @@ class ServerInstance:
                 merged.stats.server_inflight = self._inflight_queries
                 merged.stats.table_epoch = epoch_at_start
                 self.queries_served += 1
+                if merged.stats.num_segments_on_host:
+                    self.metrics.count("segmentsOnHost",
+                                       merged.stats.num_segments_on_host)
                 # segment-temperature telemetry (ISSUE 11): every routed
                 # segment of this query heats up — bytes are the
                 # rows x referenced-columns x 4 admission-cost proxy
@@ -666,14 +696,17 @@ class ServerInstance:
                 except Exception:  # noqa: BLE001 — telemetry never fails a query
                     log.exception("segment heat accounting failed")
                 if tracer is not None:
-                    # encode itself can't appear in the trace: the spans
-                    # are serialized INTO the payload encode produces.
-                    # server.total is the reconciliation denominator —
-                    # tracer birth (request entry) to now; the phase
-                    # ladder's top-level spans must cover >=90% of it
-                    tracer.add_ms("server.total", tracer.elapsed_ms())
+                    # the payload cannot hold its own encode time: its
+                    # spans are serialized INTO it, server.total (the
+                    # root, still open) as long as it is by now.
+                    # server.encode reaches the ring and the profiler.
+                    tracer.root.set(
+                        segments=len(segments),
+                        segmentsPrunedByServer=merged.stats
+                        .num_segments_pruned)
                     merged.trace = tracer.to_json()
-                return encode(merged)
+                with span("server.encode", tracer):
+                    return encode(merged)
             finally:
                 cleanup()
 
@@ -782,8 +815,10 @@ class ServerInstance:
         from pinot_tpu.sql.parser import parse_sql
 
         deadline = self._request_deadline(req) or Deadline(30.0)
-        tracer = trace.Tracer(req.get("traceId")) \
-            if req.get("traceEnabled") else None
+        tracer = None
+        if req.get("traceEnabled"):
+            tracer = trace.Tracer(req.get("traceId"))
+            tracer.open("server.total").set(attempt=req.get("attempt"))
         exchange_id = req["exchangeId"]
         endpoints = req["endpoints"]
         owners = {int(p): o for p, o in req["partitionOwners"].items()}
@@ -880,9 +915,9 @@ class ServerInstance:
         timer = self.metrics.timed("exchangeStage")
         timer.__enter__()
         try:
-            with trace.span("server.compile", tracer):
+            with trace.span("server.plan", tracer):
                 plan = compile_plan(parse_sql(req["sql"]), catalog)
-            with trace.span("server.exchange", tracer):
+            with trace.span("server.exchange", tracer, quiet=True):
                 merged = run_exchange_stage(
                     self.engine, plan, spec, mailbox, send, done,
                     deadline, device=self.engine.device)
@@ -894,11 +929,12 @@ class ServerInstance:
             self.metrics.count("exchangeBytesShipped", shipped["bytes"])
             self.queries_served += 1
             if tracer is not None:
-                tracer.add_ms("server.total", tracer.elapsed_ms())
                 merged.trace = tracer.to_json()
             return encode(merged)
         finally:
             timer.__exit__()
+            if tracer is not None:
+                tracer.end()
             # the barrier guarantees every peer payload addressed to
             # this worker has arrived before the stage returns, so the
             # mailbox (and its spill files) can be reclaimed here; a

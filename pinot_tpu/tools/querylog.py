@@ -25,13 +25,15 @@ import json
 import sys
 
 
-# phase buckets for the waterfall, matched on the span name's LAST dotted
-# segment (nesting depth varies: "gather" from an embedded engine,
-# "server.execute.gather" from a cluster server) — full-name buckets
-# first. Matching a raw suffix substring would misbucket e.g.
+# phase buckets for the waterfall, matched on the span's full name first
+# and then on its LAST dotted segment ("executor.gather" from a served
+# query, "gather" / "server.execute.gather" in logs written before spans
+# had parent links). Matching a raw suffix substring would misbucket e.g.
 # "broker.scatter_gather" as the gather phase.
 PHASE_FULL_NAMES = {
     "server.queue": "queue",
+    "executor.launch_wait": "queue",
+    "server.plan": "compile",
     "server.compile": "compile",
     "server.trim": "reduce",
     "broker.reduce": "reduce",
@@ -46,6 +48,7 @@ PHASE_FULL_NAMES = {
 }
 PHASE_LAST_SEGMENTS = {
     "gather": "gather",
+    "device_wait": "kernel",
     "kernel": "kernel",
     "link": "link",
     "host_scan": "host_scan",

@@ -53,7 +53,8 @@ def make_instance_request(sql: str, segments: list, request_id: int,
                           table: str = None, time_filter: dict = None,
                           timeout_ms: float = None, trace_id: str = None,
                           attempt: str = "primary", workload: str = None,
-                          priority: str = None) -> bytes:
+                          priority: str = None,
+                          parent_span: int = None) -> bytes:
     """``table``: physical table override (hybrid split sends the same SQL to
     X_OFFLINE and X_REALTIME); ``time_filter``: {column, op le|gt, value}
     AND-ed server-side (the time-boundary predicate); ``timeout_ms``: the
@@ -66,7 +67,9 @@ def make_instance_request(sql: str, segments: list, request_id: int,
     (the reference's InstanceRequest ``enableTrace`` + requestId): when
     the query runs with SET trace=true the broker sets traceEnabled on
     EVERY attempt — primary, retry, or hedge, ``attempt`` naming which —
-    so the per-server span ladders all join one trace id.
+    so the per-server span ladders all join one trace id;
+    ``parent_span`` is the id of the broker's span that waits for this
+    request, the parent of the server's root span.
 
     ``workload``/``priority`` (ISSUE 14): the broker-resolved tenant and
     priority class — the server's weighted-fair scheduler groups slots
@@ -81,6 +84,7 @@ def make_instance_request(sql: str, segments: list, request_id: int,
             "brokerId": broker_id,
             "traceEnabled": trace,
             "traceId": trace_id,
+            "parentSpanId": parent_span,
             "attempt": attempt,
             "table": table,
             "timeFilter": time_filter,
