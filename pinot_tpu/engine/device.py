@@ -279,8 +279,75 @@ def _hll_regs(slot, rho, num_groups, log2m, mm_mode, pallas_mode="off"):
     return regs[: num_groups * m].reshape(num_groups, m).astype(jnp.int8)
 
 
+def _sum_route(num_groups: int, total_ch: int, n_total: int, mm_mode: str,
+               pallas_mode: str):
+    """Which kernel takes a dense group-by's COUNT/SUM/AVG channels:
+    "pallas" (ops/pallas_scatter.py plane_group_sums), "mm"
+    (ops/groupby_mm.py group_sums) or None (the XLA scatter). One
+    decision for the trace-time routing and the template-build plan of
+    the prepared operands."""
+    from pinot_tpu.ops import groupby_mm as mm
+    from pinot_tpu.ops import pallas_scatter as ps
+
+    if (pallas_mode != "off" and ps.sums_supported(num_groups, total_ch)
+            and (pallas_mode == "interpret"
+                 or n_total >= ps.PALLAS_MIN_ROWS)):
+        return "pallas"
+    if (mm_mode != "off" and mm.mm_supported(num_groups, total_ch - 1)
+            and (mm_mode == "interpret" or n_total >= mm.MM_MIN_ROWS)):
+        return "mm"
+    return None
+
+
+def plan_prepared_groupby(template, widths, n_total: int, mm_mode: str,
+                          pallas_mode: str, offsets: dict):
+    """Template-build plan of the PREPARED operand form of a dense
+    group-by (ops/groupby_mm.py "prepared operands"): ``(route, ids cols
+    key, ((agg index, planes cols key, nplanes), ...))``, or None where
+    the per-launch preparation has to run. It engages on what the
+    template shows: one group column, every SUM/AVG argument a bare
+    integer column with a plane count known from metadata, a kernel route
+    chosen, and a row tile that holds whole tiles of the 8-bit operands.
+    ``offsets``: {agg index: the python int behind its ``off{i}`` param}.
+    The byte budget and the mesh are the executor's to check."""
+    from pinot_tpu.ops import groupby_mm as mm
+    from pinot_tpu.ops import pallas_scatter as ps
+
+    shape, _ft, group_cols, group_cards, aggs, _sk, _final = template
+    mm_mode = _resolve_mm_mode(mm_mode)
+    if shape != "groupby" or len(group_cols) != 1 \
+            or (mm_mode == "off" and pallas_mode == "off"):
+        return None
+    num_groups = group_cards[0]
+    planes = []
+    total_ch = 1  # the count channel
+    for i, (name, argt, extra) in enumerate(aggs):
+        if name not in ("sum", "avg") or not isinstance(extra, tuple):
+            continue
+        ck = ps._direct_colkey(argt)
+        w = _col_width(widths, ck) if ck else None
+        if w is None or np.dtype(w[3] or w[0]).kind not in "iu":
+            return None  # an expression or float argument: per launch
+        nplanes = extra[0]
+        if nplanes is None or total_ch + nplanes > mm.MAX_CHANNELS + 1:
+            continue  # the exact scatter takes this one, as per launch
+        planes.append((i, BatchContext.groupby_planes_key(
+            ck, offsets[i], nplanes), nplanes))
+        total_ch += nplanes
+    route = _sum_route(num_groups, total_ch, n_total, mm_mode, pallas_mode)
+    if route is None or not (planes or any(
+            a[0] in ("count", "avg") for a in aggs)):
+        return None
+    blk = ps.sums_blk(num_groups, total_ch) if route == "pallas" \
+        else mm.group_sums_blk(num_groups, total_ch)
+    if not mm.prepared_tile_ok(blk):
+        return None
+    return (route, "gk::" + group_cols[0], tuple(planes))
+
+
 def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
-                    widths=None, pallas_mode="off"):
+                    widths=None, pallas_mode="off", prepared=None,
+                    mask=None):
     """Route COUNT/SUM/AVG through ONE factored one-hot launch when
     eligible: the Pallas tiled local-accumulate scatter
     (ops/pallas_scatter.py plane_group_sums — group-range partitioned,
@@ -288,9 +355,34 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     when the pallas tier is on, else the single-accumulator matmul
     kernel (ops/groupby_mm.py). Fills outs["gcount"] +
     outs[f"a{i}_sum"] and returns the set of agg indexes handled;
-    scatter code covers the rest. All decisions are trace-time static."""
+    scatter code covers the rest. All decisions are trace-time static.
+
+    ``prepared``: plan_prepared_groupby's plan — the kernel then reads
+    the batch's operands out of ``cols`` as they are and the launch's
+    ``mask``, relaid out to lanes at one byte a row; nothing else
+    row-scale is computed."""
     from pinot_tpu.ops import groupby_mm as mm
     from pinot_tpu.ops import pallas_scatter as ps
+
+    if prepared is not None:
+        route, ids_key, plane_plan = prepared
+        with jax.named_scope("pinot.mask"):
+            mask_lane = mm.mask_lanes(mask)
+        planes = [cols[key] for _i, key, _n in plane_plan]
+        with jax.named_scope("pinot.groupby_kernel"):
+            if route == "pallas":
+                sums = ps.plane_group_sums_prepared(
+                    cols[ids_key], mask_lane, planes, num_groups,
+                    interpret=(pallas_mode == "interpret"))
+            else:
+                sums = mm.group_sums_prepared(
+                    cols[ids_key], mask_lane, planes, num_groups,
+                    interpret=(mm_mode == "interpret"))
+        specs, row = [], 1
+        for i, _key, nplanes in plane_plan:
+            specs.append((i, "int", slice(row, row + nplanes)))
+            row += nplanes
+        return _recombine_sums(sums, specs, params, outs)
 
     if mm_mode == "off" and pallas_mode == "off":
         return set()
@@ -316,18 +408,8 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
             continue
         plans.append((i, kind, nplanes, v))
         total_ch += nplanes
-    use_pallas = (
-        pallas_mode != "off"
-        and ps.sums_supported(num_groups, total_ch)
-        and (pallas_mode == "interpret" or n_total >= ps.PALLAS_MIN_ROWS)
-    )
-    use_mm = (
-        not use_pallas
-        and mm_mode != "off"
-        and mm.mm_supported(num_groups, total_ch - 1)
-        and (mm_mode == "interpret" or n_total >= mm.MM_MIN_ROWS)
-    )
-    if not use_pallas and not use_mm:
+    route = _sum_route(num_groups, total_ch, n_total, mm_mode, pallas_mode)
+    if route is None:
         return set()
     has_count_or_avg = any(a[0] in ("count", "avg") for a in aggs)
     if not plans and not has_count_or_avg:
@@ -351,7 +433,7 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     # pad, relayout to lanes and the Pallas kernel itself
     # (pinot_scatter_sums / pinot_groupby_mm in the device trace)
     with jax.named_scope("pinot.groupby_kernel"):
-        if use_pallas:
+        if route == "pallas":
             sums = ps.plane_group_sums(
                 gid.reshape(-1), stacked, num_groups,
                 interpret=(pallas_mode == "interpret"),
@@ -362,6 +444,14 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
                 gid.reshape(-1), stacked, num_groups,
                 interpret=(mm_mode == "interpret"), first_channel_ones=True,
             )
+    return _recombine_sums(sums, specs, params, outs)
+
+
+def _recombine_sums(sums, specs, params, outs) -> set:
+    """(A, G) f64 channel sums → outs["gcount"] and the int64 / float
+    SUMs of ``specs`` [(agg index, kind, channel rows)]."""
+    from pinot_tpu.ops import groupby_mm as mm
+
     with jax.named_scope("pinot.recombine"):
         gcount = jnp.round(sums[0]).astype(jnp.int64)
         outs["gcount"] = gcount
@@ -738,9 +828,14 @@ def _unpack_outs(bufs: dict, layout) -> dict:
     return outs
 
 
+# cols keys of the dense group-by's prepared kernel operands
+# (BatchContext.groupby_operand): lane-major, not (S, L)
+_GB_OPERAND_PREFIXES = ("gk::", "gv::")
+
+
 def build_pipeline(template, mm_mode: str = "auto",
                    sorted_hll_ok: bool = False, blockskip=False,
-                   widths=None, pallas_mode: str = "off"):
+                   widths=None, pallas_mode: str = "off", prepared=None):
     """template (hashable) → jitted fn(cols, n_docs, params) → outputs dict.
 
     ``mm_mode``: "auto" → the factored one-hot matmul kernel
@@ -785,6 +880,12 @@ def build_pipeline(template, mm_mode: str = "auto",
     (ops/pallas_scatter.py): tiled local-accumulate group sums, min/max
     scatter, HLL register-max, and the fused filter+gather+aggregate
     form of the block-skip path.
+
+    ``prepared``: plan_prepared_groupby's plan, or None. With it the
+    DENSE form's COUNT/SUM/AVG kernel reads the batch's prepared operands
+    (``gk::`` / ``gv::`` entries of ``cols``) and the launch computes only
+    the mask; the block-skip form's gathered branch has other rows and
+    keeps the per-launch preparation.
     """
     shape, filter_tpl, group_cols, group_cards, aggs, sorted_k, _final = template
     mm_mode = _resolve_mm_mode(mm_mode)
@@ -813,7 +914,8 @@ def build_pipeline(template, mm_mode: str = "auto",
         # L // factor bytes, so the LOGICAL row count multiplies back
         data_cols = {k: v for k, v in cols.items()
                      if not k.startswith((bs_ops.ZLO, bs_ops.ZHI))}
-        any_key = next(k for k in data_cols if not k.startswith("sk::"))
+        any_key = next(k for k in data_cols if not k.startswith(
+            ("sk::",) + _GB_OPERAND_PREFIXES))
         any_col = data_cols[any_key]
         S = any_col.shape[0]  # MV blocks are (S, L, K); masks are (S, L)
         L = any_col.shape[1] * _kfactor(any_key)
@@ -845,7 +947,7 @@ def build_pipeline(template, mm_mode: str = "auto",
             outs = _stat_outs(
                 seg_matched, jnp.sum(jnp.where(alive_b, nd64, 0)),
                 blocks_total, blocks_total)
-            return _aggregate(data_cols, params, mask, outs)
+            return _aggregate(data_cols, params, mask, outs, prepared)
 
         if not blockskip or L % R:
             return dense(jnp.int64(0))
@@ -962,7 +1064,8 @@ def build_pipeline(template, mm_mode: str = "auto",
             with jax.named_scope("pinot.gather_blocks"):
                 g_cols = {
                     k: bs_ops.gather_blocks(v, cand, NB, R // _kfactor(k))
-                    for k, v in data_cols.items()}
+                    for k, v in data_cols.items()
+                    if not k.startswith(_GB_OPERAND_PREFIXES)}
             with jax.named_scope("pinot.mask"):
                 mask = _eval_filter(filter_tpl, g_cols, params, (B, R),
                                     widths) & rvalid
@@ -1004,14 +1107,15 @@ def build_pipeline(template, mm_mode: str = "auto",
                             lambda: _pad_table(dense(blocks_total)),
                             lambda: _pad_table(skip()))
 
-    def _aggregate(cols, params, mask, outs):
+    def _aggregate(cols, params, mask, outs, prep=None):
         with jax.named_scope("pinot.aggregate"):
-            return _aggregate_stages(cols, params, mask, outs)
+            return _aggregate_stages(cols, params, mask, outs, prep)
 
-    def _aggregate_stages(cols, params, mask, outs):
+    def _aggregate_stages(cols, params, mask, outs, prep=None):
         """Filter mask → aggregation outputs; shape-agnostic over the row
         layout (dense (S, L) or gathered (B, R) — every reduction lands in
-        template-shaped accumulators either way)."""
+        template-shaped accumulators either way). ``prep``: the prepared
+        operand plan, from the dense (S, L) form only."""
         if shape == "groupby_sorted":
             # RADIX-PARTITIONED high-cardinality regime (the MAP_BASED
             # analog of DictionaryBasedGroupKeyGenerator): dense
@@ -1088,7 +1192,7 @@ def build_pipeline(template, mm_mode: str = "auto",
             gid = agg_ops.group_ids_combine(per_col, group_cards, mask, num_groups)
             mm_done = _try_mm_groupby(
                 aggs, gid, cols, params, num_groups, mm_mode, outs, widths,
-                pallas_mode=pallas_mode,
+                pallas_mode=pallas_mode, prepared=prep, mask=mask,
             )
             if "gcount" not in outs:
                 outs["gcount"] = agg_ops.group_count(gid, num_groups)
@@ -1310,6 +1414,11 @@ class DeviceExecutor:
         self.batch_hits = 0
         self.batch_misses = 0
         self.batch_evictions = 0
+        # dense group-by launches by where the kernel's operands came
+        # from: the batch's prepared ones, this launch built them, or
+        # per-launch preparation (BatchContext.groupby_operand)
+        self.groupby_operand_launches = {
+            "prepared": 0, "built": 0, "perLaunch": 0}
         # device-error recovery (failure-domain hardening): per-(template,
         # batch) failure counts feed a quarantine circuit breaker — a
         # pipeline that keeps failing on device routes to the host path
@@ -1469,6 +1578,10 @@ class DeviceExecutor:
         cached batches."""
         return sum(b.narrow_saved_bytes() for b in self._batch_list())
 
+    def groupby_operand_bytes(self) -> int:
+        """Of ``resident_bytes``: the dense group-by's prepared operands."""
+        return sum(b.groupby_operand_bytes() for b in self._batch_list())
+
     # ---- device partials cache (sub-RTT repeat queries) ------------------
     def _partials_get(self, key):
         """LRU lookup; counts the hit/miss. Returns (bufs_dev, layout) or
@@ -1560,18 +1673,25 @@ class DeviceExecutor:
                 "partials_cache_invalidations": self.partials_invalidations,
                 "device_reduce_queries": self.device_reduce_queries,
                 "device_reduce_ms": round(self.device_reduce_ms_total, 3),
+                # dense group-by launches by their operands' origin
+                "groupby_operand_launches":
+                    dict(self.groupby_operand_launches),
             }
         per_batch = [
             {
                 "segments": len(key),
                 "resident_bytes": ctx.device_bytes(),
                 "narrow_saved_bytes": ctx.narrow_saved_bytes(),
+                "groupby_operand_bytes": ctx.groupby_operand_bytes(),
             }
             for key, ctx in batches
         ]
         snap.update(
             cached_batches=len(per_batch),
             resident_bytes=sum(b["resident_bytes"] for b in per_batch),
+            # of resident_bytes: the prepared group-by operands
+            groupby_operand_bytes=sum(
+                b["groupby_operand_bytes"] for b in per_batch),
             narrow_saved_bytes=sum(
                 b["narrow_saved_bytes"] for b in per_batch),
             max_cached_bytes=self.MAX_CACHED_BYTES,
@@ -1926,6 +2046,8 @@ class DeviceExecutor:
                    "cacheHit": cache_hit}
             if gather_bytes:
                 rec["gatherBytes"] = gather_bytes
+            if flight.get("groupby_operands"):
+                rec["groupbyOperands"] = flight["groupby_operands"]
             gbps = None
             if not cache_hit and kernel_s > 1e-9:
                 gbps = bytes_moved / kernel_s / 1e9
@@ -2043,6 +2165,9 @@ class DeviceExecutor:
 
                 off = math.floor(bounds[0])
                 params[f"off{i}"] = jnp.int64(off)
+                # the python int, for the prepared operands' plan (a side
+                # channel like __hostsig__; _launch_pinned pops it)
+                params.setdefault("__offsets__", {})[i] = off
                 sig = params.get("__hostsig__")
                 if sig is not None:
                     sig.append((f"off{i}", "<i8", (),
@@ -2221,6 +2346,7 @@ class DeviceExecutor:
         agg_tpls = tuple(
             self._agg_template(i, a, ctx, params, counter) for i, a in enumerate(aggs)
         )
+        offsets = params.pop("__offsets__", {})
         shape = "groupby" if group_cols else "agg"
         if group_cols and total > MAX_DENSE_GROUPS:
             # sort-based high-cardinality regime (MAP_BASED analog): no
@@ -2429,9 +2555,35 @@ class DeviceExecutor:
                 pmode = pmode2
                 adv_notes.append(note)
 
-        pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode)
+        # the dense group-by's statement-invariant kernel operands: taken
+        # from the batch where the template allows it and the batch's
+        # bytes plus theirs stay under the byte cap (plan_prepared_groupby
+        # has the rest of the conditions); else today's per-launch
+        # preparation runs. A mesh keeps the per-launch form: the lane
+        # blocks are not laid out by segment shard.
+        prepared = None
+        if shape == "groupby" and self.mesh is None:
+            prepared = plan_prepared_groupby(
+                template, widths, ctx.S * ctx.pad_to, self.mm_mode, pmode,
+                offsets)
+            if prepared is not None:
+                gb_keys = (prepared[1],) + tuple(
+                    k for _i, k, _n in prepared[2])
+                # what is built already costs nothing more
+                cost = ctx.groupby_operand_cost(gb_keys)
+                if cost and ctx.device_bytes() + cost > self.MAX_CACHED_BYTES:
+                    prepared = None
+                else:
+                    needed.update(gb_keys)
+        # what the launch's spans, its flight record and EXPLAIN ANALYZE
+        # say of it: prepared | built (this launch built them) | perLaunch
+        gb_operands = None if shape != "groupby" else \
+            "perLaunch" if prepared is None else "prepared"
+
+        pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode,
+                                  prepared)
         entry = self._pipeline_entry(template, agg_tpls, final, use_bs,
-                                     widths, wsig, trim, pmode)
+                                     widths, wsig, trim, pmode, prepared)
         # fused filter+gather+aggregate eligibility (label + bytes-moved
         # model): the plan walk is cheap and mirrors the one
         # build_pipeline compiled into the pipeline
@@ -2515,8 +2667,17 @@ class DeviceExecutor:
                     cols[c] = ctx.bytes_plane_column(c[4:])
                 elif c.startswith("mv::"):
                     cols[c] = ctx.mv_column(c[4:])
+                elif c.startswith(_GB_OPERAND_PREFIXES):
+                    cols[c], built = ctx.groupby_operand(c)
+                    if built:
+                        gb_operands = "built"
                 else:
                     cols[c] = ctx.column(c)
+        if gb_operands is not None:
+            with self._lock:
+                self.groupby_operand_launches[gb_operands] += 1
+            if flight is not None:
+                flight["groupby_operands"] = gb_operands
         if os.environ.get("PINOT_TPU_WIDTH_AUDIT", "") not in ("", "0"):
             _width_audit(ctx, cols, widths)
 
@@ -2533,11 +2694,16 @@ class DeviceExecutor:
             # the block-skip form reads zone planes fully but data planes
             # only for gathered blocks (_note_flight applies the ratio
             # the kernel reports)
+            # with prepared operands the dense form reads them and the
+            # filter's columns, not the (S, L) value and key planes
+            read = None if prepared is None else \
+                self._needed_columns(filter_tpl)
             for ck, cv in cols.items():
                 nb = int(getattr(cv, "nbytes", 0))
                 if ck.startswith((bs_ops.ZLO, bs_ops.ZHI)):
                     flight["zone_bytes"] += nb
-                else:
+                elif read is None or ck in read \
+                        or ck.startswith(_GB_OPERAND_PREFIXES):
                     flight["data_bytes"] += nb
 
         # ONE packed buffer crosses the host link: device_get fetches tree
@@ -2547,7 +2713,8 @@ class DeviceExecutor:
         # traces without touching the device.
         lkey = (ctx.S, next(
             v for k, v in cols.items()
-            if not k.startswith(("sk::", bs_ops.ZLO, bs_ops.ZHI))).shape[1])
+            if not k.startswith(("sk::", bs_ops.ZLO, bs_ops.ZHI)
+                                + _GB_OPERAND_PREFIXES)).shape[1])
         layout = entry["layouts"].get(lkey)
         if layout is None:
             layout = _out_layout(
@@ -2564,7 +2731,8 @@ class DeviceExecutor:
                                   lambda: synth)
         resolve = self._dispatch(
             entry, batch_key, cols, n_docs, params, lkey, layout, tracer,
-            cache_key, flight, adv_key=adv_key, adv_notes=adv_notes)
+            cache_key, flight, adv_key=adv_key, adv_notes=adv_notes,
+            gb_operands=gb_operands)
         handle = InflightLaunch(self, q, ctx, template, aggs, batch_key,
                                 resolve)
         handle.flight = flight
@@ -2576,15 +2744,17 @@ class DeviceExecutor:
 
     # ---- dispatch: solo vs coalesced -------------------------------------
     def _pipeline_key(self, template, blockskip, wsig, trim,
-                      pallas: str = "off") -> tuple:
+                      pallas: str = "off", prepared=None) -> tuple:
         """The ONE composition of the compiled-pipeline cache key — the
         partials cache namespaces its entries by the same tuple, so a
         future compile-affecting component added here automatically
         splits both caches together. ``pallas`` keys the scatter-tier
         mode so the Pallas form and the XLA scatter form (the
         PINOT_TPU_PALLAS=0 / SET usePallas=false escape hatch and the
-        quarantine XLA rung) coexist compiled in one process."""
-        return (template, self.mm_mode, blockskip, wsig, trim, pallas)
+        quarantine XLA rung) coexist compiled in one process; ``prepared``
+        (plan_prepared_groupby's plan) keys the operand form."""
+        return (template, self.mm_mode, blockskip, wsig, trim, pallas,
+                prepared)
 
     @staticmethod
     def _post_chain(template, agg_tpls, final, trim):
@@ -2605,7 +2775,7 @@ class DeviceExecutor:
     def _pipeline_entry(self, template, agg_tpls, final,
                         blockskip=False, widths=None,
                         wsig: tuple = (), trim=None,
-                        pallas: str = "off") -> dict:
+                        pallas: str = "off", prepared=None) -> dict:
         """Compiled-pipeline cache entry for (template, mm_mode, blockskip,
         width-plan sig, trim sig): the solo jitted pipeline, the pre-pack
         inner fn (eval_shape layouts), the raw pipeline (cohort rebuilds
@@ -2618,7 +2788,7 @@ class DeviceExecutor:
         under the executor lock so concurrent same-template launches
         share ONE entry."""
         pkey = self._pipeline_key(template, blockskip, wsig, trim,
-                                  pallas)
+                                  pallas, prepared)
         with self._lock:
             entry = self._pipelines.get(pkey)
             if entry is not None:
@@ -2626,7 +2796,7 @@ class DeviceExecutor:
             raw = build_pipeline(template, self.mm_mode,
                                  sorted_hll_ok=(self.mesh is None),
                                  blockskip=blockskip, widths=widths,
-                                 pallas_mode=pallas)
+                                 pallas_mode=pallas, prepared=prepared)
             # cohorts vmap the pipeline over stacked member params, and a
             # vmapped lax.cond lowers to select — BOTH branches would run
             # for every member. Cohorts therefore ride the DENSE form;
@@ -2635,7 +2805,7 @@ class DeviceExecutor:
             # subsets stay correct.
             raw_cohort = build_pipeline(
                 template, self.mm_mode, sorted_hll_ok=(self.mesh is None),
-                widths=widths, pallas_mode=pallas,
+                widths=widths, pallas_mode=pallas, prepared=prepared,
             ) if blockskip else raw
             if self.mesh is not None:
                 from pinot_tpu.parallel.mesh import shard_pipeline
@@ -2671,7 +2841,7 @@ class DeviceExecutor:
 
     def _dispatch(self, entry, batch_key, cols, n_docs, params, lkey, layout,
                   tracer=None, cache_key=None, flight=None, adv_key=None,
-                  adv_notes=None):
+                  adv_notes=None, gb_operands=None):
         """Dispatch one query: through the coalescer when concurrency makes
         a cohort partner likely, else solo. Returns the resolve() closure
         the InflightLaunch fetch phase blocks on. Coalescing is disabled
@@ -2681,7 +2851,9 @@ class DeviceExecutor:
         leader's window wait (``executor.launch_wait``), its ``stack``
         and ``dispatch``; a solo launch's ``dispatch``. A member's join
         returns at once — it records its waits in its fetch phase
-        (InflightLaunch._traced_resolve)."""
+        (InflightLaunch._traced_resolve). ``gb_operands``: where a dense
+        group-by's kernel operands came from (``groupbyOperands`` on the
+        dispatch and device_wait spans; the leader's, for a cohort)."""
         co = self.coalescer
         if (co is not None and not self.profile_enabled
                 and co.should_window(self.inflight)):
@@ -2714,7 +2886,8 @@ class DeviceExecutor:
                 if _ak is not None and self.advisor is not None:
                     self.advisor.observe(_ak, cohort=len(members))
                 return self._cohort_launch(
-                    entry, cols, n_docs, members, lkey, tracer, flight)
+                    entry, cols, n_docs, members, lkey, tracer, flight,
+                    gb_operands)
 
             window.__enter__()
             try:
@@ -2733,10 +2906,10 @@ class DeviceExecutor:
             resolve.cohort, resolve.index = cohort, idx
             return resolve
         return self._solo_launch(entry, cols, n_docs, params, layout, tracer,
-                                 cache_key, flight)
+                                 cache_key, flight, gb_operands)
 
     def _solo_launch(self, entry, cols, n_docs, params, layout, tracer=None,
-                     cache_key=None, flight=None):
+                     cache_key=None, flight=None, gb_operands=None):
         pipeline = entry["pipeline"]
         if self.profile_enabled:
             with self._lock:
@@ -2746,8 +2919,9 @@ class DeviceExecutor:
                         * v.dtype.itemsize for v in cols.values()),
                 )
         launch_id = next(self._launch_ids)
+        origin = {"groupbyOperands": gb_operands} if gb_operands else {}
         dispatch = trace_span("executor.dispatch", tracer)
-        dispatch.set(launchId=launch_id)
+        dispatch.set(launchId=launch_id, **origin)
         with dispatch:
             bufs_dev = pipeline(cols, n_docs, params)  # async dispatch
         if cache_key is not None:
@@ -2759,10 +2933,10 @@ class DeviceExecutor:
         return self._make_resolve(
             bufs_dev, layout, flight,
             attrs={"launchId": launch_id, "cohortSize": 1,
-                   "cohortPadded": 1})
+                   "cohortPadded": 1, **origin})
 
     def _cohort_launch(self, entry, cols, n_docs, members, lkey, tracer=None,
-                       flight=None):
+                       flight=None, gb_operands=None):
         """Leader side of a coalesced cohort: stack every member's params
         along a leading axis and dispatch ONE vmapped launch; the shared
         resolve() fetches ONE packed buffer for the whole cohort (each
@@ -2773,7 +2947,8 @@ class DeviceExecutor:
             # whole extra compile of the template for nothing
             layout = entry["layouts"][lkey]
             base = self._solo_launch(entry, cols, n_docs, members[0], layout,
-                                     tracer, flight=flight)
+                                     tracer, flight=flight,
+                                     gb_operands=gb_operands)
 
             def alone():
                 return {k: v[None] for k, v in base().items()}
@@ -2806,14 +2981,15 @@ class DeviceExecutor:
                     jax.eval_shape(inner_v, cols, n_docs, pstack))
                 with self._lock:
                     entry["cohort_layouts"][ck] = layout
+        origin = {"groupbyOperands": gb_operands} if gb_operands else {}
         dispatch = trace_span("executor.dispatch", tracer)
-        dispatch.set(launchId=launch_id)
+        dispatch.set(launchId=launch_id, **origin)
         with dispatch:
             bufs_dev = pipeline_v(cols, n_docs, pstack)  # async dispatch
         return self._make_resolve(
             bufs_dev, layout, flight,
             attrs={"launchId": launch_id, "cohortSize": n_real,
-                   "cohortPadded": n_pad})
+                   "cohortPadded": n_pad, **origin})
 
     def _cohort_pipeline(self, entry):
         """(jitted packed pipeline, inner fn) over params carrying a

@@ -178,9 +178,13 @@ def _kernel_line(rec: dict) -> str:
         perf = f"{gbps} GB/s ({pct}% of HBM peak {peak} GB/s)"
     else:
         perf = f"{gbps} GB/s"
+    # a dense group-by says where its kernel's operands came from
+    operands = f", groupbyOperands={rec['groupbyOperands']}" \
+        if rec.get("groupbyOperands") else ""
     return (f"    KERNEL({label}{where}: {perf}, "
             f"bytes={rec.get('bytesMoved')}, "
-            f"kernelMs={rec.get('kernelMs')}, linkMs={rec.get('linkMs')})")
+            f"kernelMs={rec.get('kernelMs')}, linkMs={rec.get('linkMs')}"
+            f"{operands})")
 
 
 def annotate_analyze(plan: dict, resp: dict) -> dict:

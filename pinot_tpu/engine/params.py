@@ -174,6 +174,13 @@ class BatchContext:
         self._prehashed: dict[str, object] = {}     # name -> (S, L) value hashes
         self._mv_columns: dict[str, object] = {}    # name -> (S, L, K) id blocks
         self._sorted_hll: dict = {}   # (group_cols, hash_col, log2m) -> sorted keys
+        # the dense group-by's statement-invariant kernel operands
+        # (ops/groupby_mm.py "prepared operands"), by cols key: "gk::<col>"
+        # lane-major key ids, "gv::<colkey>::<off>::<nplanes>" uint8 byte
+        # planes of value - off. Built on the device at first use; they
+        # live and die with this batch
+        self._gb_operands: dict = {}
+        self._gb_operand_bytes = 0
         # col key -> ((S, NB) lo, (S, NB) hi) device zone maps in the
         # column's device value space (global ids / decoded / raw); built
         # eagerly alongside the column block (the host data is in hand
@@ -649,6 +656,61 @@ class BatchContext:
             self._decoded[key] = self._put(blocks)
             self._note_resident(self._decoded[key])
         return self._decoded[key]
+
+    # ---- dense group-by kernel operands (ops/groupby_mm.py) -------------
+    @staticmethod
+    def groupby_planes_key(colkey: str, off: int, nplanes: int) -> str:
+        return f"gv::{colkey}::{off}::{nplanes}"
+
+    def groupby_operand(self, key: str):
+        """(device array, built now?) for a "gk::" / "gv::" cols key: the
+        group column's lane-major ids, or a value column's uint8 byte
+        planes, built once a batch by one jitted program over the stored
+        plane and then kept like the batch's other derived blocks."""
+        with self._lock:
+            arr = self._gb_operands.get(key)
+            if arr is not None:
+                return arr, False
+            from pinot_tpu.ops import groupby_mm as mm
+
+            if key.startswith("gk::"):
+                name = key[4:]
+                arr = mm.prepared_ids(
+                    self._column_locked(name), self.n_docs_dev,
+                    num_groups=len(self._global_dict_locked(name)),
+                    bits=self._width_plan_locked(name).bits)
+            else:
+                colkey, off, nplanes = key[4:].rsplit("::", 2)
+                stored = self._decoded_column_locked(colkey[4:]) \
+                    if colkey.startswith("dv::") else self._column_locked(colkey)
+                fo = self._width_plan_locked(colkey).offset or 0
+                arr = mm.prepared_planes(stored, delta=fo - int(off),
+                                         nplanes=int(nplanes))
+            self._gb_operands[key] = arr
+            self._gb_operand_bytes += int(arr.nbytes)
+            self._note_resident(arr)
+            return arr, True
+
+    def groupby_operand_cost(self, keys) -> int:
+        """HBM bytes that building the not-yet-built operands among
+        ``keys`` would add (lock-free: the byte budget's check)."""
+        from pinot_tpu.ops.groupby_mm import SUPERBLOCK
+
+        n_pad = -(-self.S * self.pad_to // SUPERBLOCK) * SUPERBLOCK
+        cost = 0
+        for key in keys:
+            if key in self._gb_operands:
+                continue
+            if key.startswith("gk::"):
+                dt = np.dtype(self.width_plan(key[4:]).dtype)
+                cost += n_pad * (dt.itemsize if dt.kind == "u" else 4)
+            else:
+                cost += n_pad * int(key.rsplit("::", 1)[1])
+        return cost
+
+    def groupby_operand_bytes(self) -> int:
+        """Resident bytes of the operands (part of ``device_bytes``)."""
+        return self._gb_operand_bytes
 
     def _note_resident(self, arr) -> None:
         """Caller holds self._lock; device_bytes reads the counter

@@ -128,9 +128,9 @@ def _hpad(num_groups: int, lo: int = 128) -> int:
     return max(8, ((num_groups // lo + 1 + 7) // 8) * 8)
 
 
-def _kernel(ids_ref, ch_ref, out_ref, acc_ref,
-            *, ninner, hpad, a_real, blk, lo, rho_mode, stacked,
-            ones_first):
+def _kernel(*refs, ninner, hpad, a_real, blk, lo, rho_mode, stacked,
+            ones_first, prepared=None):
+    out_ref, acc_ref = refs[-2:]
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -138,7 +138,14 @@ def _kernel(ids_ref, ch_ref, out_ref, acc_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     lo_shift = lo.bit_length() - 1                  # lo is a power of two
-    ids_r = ids_ref[:].reshape(1, blk)              # sublane→lane merge: OK
+    if prepared is None:
+        ids_ref, ch_ref = refs[:2]
+        ids_r = ids_ref[:].reshape(1, blk)          # sublane→lane merge: OK
+    else:
+        # batch-resident operands: ids, the launch's mask, byte planes
+        num_groups, plane_rows = prepared
+        ids_r = prepared_ids_row(refs[0], refs[1], num_groups, blk)
+        plane_at = prepared_plane_index(refs[2:-2], plane_rows)
     lo_r = ids_r & (lo - 1)
     hi_r = ids_r >> lo_shift
 
@@ -164,6 +171,8 @@ def _kernel(ids_ref, ch_ref, out_ref, acc_ref,
             # saved per block; callers guarantee overflow-slot slicing
             # absorbs the pad rows this also counts)
             return oh_hi
+        if prepared is not None:
+            return oh_hi * prepared_plane_row(*plane_at[a - 1], blk)
         return oh_hi * ch_ref[pl.ds(a, 1), :]       # (1, blk) bf16
 
     if stacked:
@@ -188,20 +197,34 @@ def _kernel(ids_ref, ch_ref, out_ref, acc_ref,
 
 
 def _launch(ids_lane, ch_operand, ch_spec_kind, *, a_real, hpad, lo, nsuper,
-            rho_mode, interpret, ones_first=False):
+            rho_mode, interpret, ones_first=False, num_groups=None):
     blk, ninner, stacked = _plan_blk(a_real, hpad, lo)
+    lane_spec = pl.BlockSpec(
+        (blk // 128, 128), lambda s, i: (s * ninner + i, _i32(0)),
+        memory_space=pltpu.VMEM)
+    prepared = None
+    if ch_spec_kind == "channels":
+        operands = (ch_operand,)
+        ch_specs = [pl.BlockSpec(
+            (a_real, blk), lambda s, i: (_i32(0), s * ninner + i),
+            memory_space=pltpu.VMEM)]
+    elif ch_spec_kind == "prepared":  # (mask_lane, [uint8 lane planes])
+        mask_lane, planes = ch_operand
+        operands = (mask_lane, *planes)
+        prepared = (num_groups, tuple(p.shape[0] for p in planes))
+        ch_specs = [lane_spec] + [
+            pl.BlockSpec((p.shape[0], blk // 128, 128),
+                         lambda s, i: (_i32(0), s * ninner + i, _i32(0)),
+                         memory_space=pltpu.VMEM)
+            for p in planes]
+    else:  # lane-major rho operand
+        operands = (ch_operand,)
+        ch_specs = [lane_spec]
     kern = functools.partial(
         _kernel, ninner=ninner, hpad=hpad, a_real=a_real, blk=blk, lo=lo,
         rho_mode=rho_mode, stacked=stacked, ones_first=ones_first,
+        prepared=prepared,
     )
-    if ch_spec_kind == "channels":
-        ch_spec = pl.BlockSpec(
-            (a_real, blk), lambda s, i: (_i32(0), s * ninner + i),
-            memory_space=pltpu.VMEM)
-    else:  # lane-major rho operand
-        ch_spec = pl.BlockSpec(
-            (blk // 128, 128), lambda s, i: (s * ninner + i, _i32(0)),
-            memory_space=pltpu.VMEM)
     # acc scratch + out block are each a_real*hpad*128 f32; the out block is
     # double-buffered by the pipeline and Mosaic stacks further transient
     # copies. Default scoped-vmem limit is 16MB — raise it for large-G
@@ -219,11 +242,7 @@ def _launch(ids_lane, ch_operand, ch_spec_kind, *, a_real, hpad, lo, nsuper,
     out = pl.pallas_call(
         kern,
         grid=(nsuper, ninner),
-        in_specs=[
-            pl.BlockSpec((blk // 128, 128), lambda s, i: (s * ninner + i, _i32(0)),
-                         memory_space=pltpu.VMEM),
-            ch_spec,
-        ],
+        in_specs=[lane_spec, *ch_specs],
         out_specs=pl.BlockSpec(
             (1, a_real, hpad, lo),
             lambda s, i: (s, _i32(0), _i32(0), _i32(0)),
@@ -235,7 +254,7 @@ def _launch(ids_lane, ch_operand, ch_spec_kind, *, a_real, hpad, lo, nsuper,
         interpret=interpret,
         # the name the profiler's trace shows the kernel under
         name="pinot_hll_mm" if rho_mode else "pinot_groupby_mm",
-    )(ids_lane, ch_operand)
+    )(ids_lane, *operands)
     return jnp.sum(out, axis=0, dtype=jnp.float64)
 
 
@@ -271,6 +290,149 @@ def group_sums(gid, channels, num_groups: int, *, interpret: bool = False,
     tot = _launch(ids_lane, ch, "channels", a_real=a_real, hpad=hpad, lo=lo,
                   nsuper=nsuper, rho_mode=False, interpret=interpret,
                   ones_first=first_channel_ones)
+    return tot.reshape(a_real, hpad * lo)[:, :num_groups]
+
+
+# ---------------------------------------------------------------------------
+# prepared operands: what a dense group-by's kernel reads that does not
+# depend on the statement, kept in HBM in the kernel's own layout
+# ---------------------------------------------------------------------------
+# A launch used to rebuild, per statement and per cohort member, the value
+# column's byte planes (64-bit shifts the TPU emulates), a ones channel the
+# kernel never reads, the clipped key ids and their (S, L) → lanes
+# relayouts. None of it depends on the statement; only the filter mask
+# does. engine/params.py BatchContext builds these once a batch:
+#
+# - ``prepared_ids``: the key column's ids, clipped, rows past a segment's
+#   end already at ``num_groups``, lane-major ``(n_pad/128, 128)`` at the
+#   stored width (the kernel widens in VMEM);
+# - ``prepared_planes``: the byte planes of ``value - off`` as uint8,
+#   ``(nplanes, n_pad/128, 128)`` (the kernel converts to bf16 in VMEM:
+#   half the HBM of bf16 planes, and dense (32, 128) tiles).
+#
+# The launch hands the kernel its mask as a third operand (``mask_lanes``,
+# one byte a row) and ``where(mask, ids, num_groups)`` happens in VMEM: no
+# masked id array is written. The count channel has no operand rows.
+# (Evaluating the filter in lanes over a lane copy of its columns, so that
+# a launch relays nothing out, was built and measured in PR 30: no faster
+# than relaying the one-byte mask, 0.3 ms at 100M rows, and 100 MB more.)
+
+PREP_SUBLANES = 32  # an 8-bit operand tiles at (32, 128)
+
+
+def prepared_tile_ok(blk: int) -> bool:
+    """The kernel's row tile holds whole (32, 128) tiles of an 8-bit
+    operand. ``_plan_blk`` shrinks ``blk`` to 2048 for very large group
+    counts; those launches keep the per-launch operands."""
+    return (blk // 128) % PREP_SUBLANES == 0
+
+
+def _to_lanes(x, fill):
+    """(S, L) → lane-major (n_pad/128, 128), padded to whole superblocks.
+    (S, L) has the segment axis on sublanes, so the flatten is a relayout.
+    It is written as the split of L into (L/128, 128), a barrier, and the
+    (free) merge of the two major axes: the TPU's compiler takes a fifth
+    of a second over that at any width, and 17 s (32-bit) to 4 minutes
+    (8-bit) over the one-step flatten XLA would make of it without the
+    barrier (compiles for a described v5e at (8, 12,500,992), PR 30)."""
+    S, L = x.shape
+    if L % 128:
+        lanes = x.reshape(-1)  # not a BatchContext's (S, L): pad the tail
+        lanes = jnp.concatenate(
+            [lanes, jnp.full(-lanes.shape[0] % 128, fill, x.dtype)]
+        ).reshape(-1, 128)
+    else:
+        lanes = jax.lax.optimization_barrier(
+            x.reshape(S, L // 128, 128)).reshape(-1, 128)
+    pad_rows = -lanes.shape[0] % (SUPERBLOCK // 128)
+    if pad_rows:
+        lanes = jnp.concatenate(
+            [lanes, jnp.full((pad_rows, 128), fill, x.dtype)])
+    return lanes
+
+
+@functools.partial(jax.jit, static_argnames=("num_groups", "bits"))
+def prepared_ids(col, n_docs, *, num_groups: int, bits: int = 0):
+    """Stored (S, L) id plane (sub-byte planes at ``bits``) → lane-major
+    ids in [0, num_groups]; padding rows carry ``num_groups``. Unsigned
+    planes keep their width (their pad sentinel C == num_groups fits by
+    the width plan's tier rule)."""
+    from pinot_tpu.ops import masks as mask_ops
+
+    if bits:
+        col = mask_ops.unpack_subbyte(col, bits)
+    valid = mask_ops.valid_mask(n_docs, col.shape[1], batched=True)
+    dt = col.dtype if jnp.issubdtype(col.dtype, jnp.unsignedinteger) \
+        else jnp.int32
+    ids = jnp.where(valid, jnp.clip(col.astype(jnp.int32), 0, num_groups - 1),
+                    num_groups)
+    return _to_lanes(ids.astype(dt), num_groups)
+
+
+@functools.partial(jax.jit, static_argnames=("delta", "nplanes"))
+def prepared_planes(col, *, delta: int, nplanes: int):
+    """Stored (S, L) integer plane → (nplanes, n_pad/128, 128) uint8 byte
+    planes of ``stored + delta`` (``delta`` = the plane's frame-of-
+    reference offset less the agg's ``off``: 0 when they agree, and then
+    the planes are the stored integer's own bytes)."""
+    wide = jnp.uint32 if nplanes <= 4 else jnp.uint64
+    if delta:
+        v = (col.astype(jnp.int64) + delta).astype(wide)
+    else:
+        v = col.astype(wide)
+    lanes = _to_lanes(v, 0)  # one relayout, at the value's own width
+    return jnp.stack([((lanes >> (8 * k)) & 0xFF).astype(jnp.uint8)
+                      for k in range(nplanes)])
+
+
+def mask_lanes(mask):
+    """The launch's (S, L) filter mask → the kernel's third operand:
+    lane-major uint8, one byte a row (padding rows 0)."""
+    return _to_lanes(mask.astype(jnp.uint8), 0)
+
+
+def prepared_ids_row(ids_ref, mask_ref, num_groups: int, blk: int):
+    """In-kernel: (blk/128, 128) ids and mask blocks → the (1, blk) int32
+    row of masked ids the one-hots compare against. Widening and the
+    select run on the dense tile, before the sublane→lane merge."""
+    ids = ids_ref[:].astype(jnp.int32)
+    keep = mask_ref[:].astype(jnp.int32) != _i32(0)
+    return jnp.where(keep, ids, _i32(num_groups)).reshape(1, blk)
+
+
+def prepared_plane_index(plane_refs, plane_rows):
+    """[(ref, row)] per value channel, in channel order (channel 0, the
+    folded count channel, has no operand rows)."""
+    return [(ref, k) for ref, rows in zip(plane_refs, plane_rows)
+            for k in range(rows)]
+
+
+def prepared_plane_row(ref, k: int, blk: int):
+    """In-kernel: one uint8 plane block → its (1, blk) bf16 channel row
+    (bytes are bf16-exact)."""
+    return ref[k].astype(jnp.int32).astype(jnp.float32) \
+        .reshape(1, blk).astype(jnp.bfloat16)
+
+
+def group_sums_blk(num_groups: int, a_real: int) -> int:
+    """The row tile ``group_sums_prepared`` runs at."""
+    lo = _plan_lo(num_groups, a_real, True)
+    return _plan_blk(a_real, _hpad(num_groups, lo), lo)[0]
+
+
+def group_sums_prepared(ids_lane, mask_lane, planes, num_groups: int, *,
+                        interpret: bool = False):
+    """``group_sums`` over prepared operands: channel 0 counts, channels
+    1.. are the rows of ``planes`` in order. Returns (1 + Σ rows,
+    num_groups) float64."""
+    a_real = 1 + sum(p.shape[0] for p in planes)
+    lo = _plan_lo(num_groups, a_real, True)
+    hpad = _hpad(num_groups, lo)
+    nsuper = ids_lane.shape[0] * 128 // SUPERBLOCK
+    tot = _launch(ids_lane, (mask_lane, list(planes)), "prepared",
+                  a_real=a_real, hpad=hpad, lo=lo, nsuper=nsuper,
+                  rho_mode=False, interpret=interpret, ones_first=True,
+                  num_groups=num_groups)
     return tot.reshape(a_real, hpad * lo)[:, :num_groups]
 
 

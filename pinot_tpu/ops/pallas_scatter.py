@@ -70,6 +70,7 @@ from jax.experimental.pallas import tpu as pltpu
 # and the block-size planner live in ops/groupby_mm.py (already
 # re-measured and retuned there once) — a retune must reach both kernel
 # tiers, so this module imports rather than restating them
+from pinot_tpu.ops import groupby_mm as mm
 from pinot_tpu.ops.groupby_mm import (  # noqa: F401 — re-exported budgets
     _plan_blk as _mm_plan_blk,
     BLK,
@@ -180,8 +181,9 @@ def sums_supported(num_groups: int, n_channels: int) -> bool:
     return -(-_hpad_total(num_groups) // hp) <= MAX_PARTITIONS
 
 
-def _sums_kernel(ids_ref, ch_ref, out_ref, acc_ref, *,
-                 ninner, hpad, a_real, blk, gp, stacked, ones_first):
+def _sums_kernel(*refs, ninner, hpad, a_real, blk, gp, stacked, ones_first,
+                 prepared=None):
+    out_ref, acc_ref = refs[-2:]
     p = pl.program_id(0)
     i = pl.program_id(2)
 
@@ -189,12 +191,21 @@ def _sums_kernel(ids_ref, ch_ref, out_ref, acc_ref, *,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    ids_r = ids_ref[:].reshape(1, blk)
+    if prepared is None:
+        ids_ref, ch_ref = refs[:2]
+        ids_r = ids_ref[:].reshape(1, blk)
+    else:
+        # batch-resident operands (ops/groupby_mm.py "prepared operands")
+        num_groups, plane_rows = prepared
+        ids_r = mm.prepared_ids_row(refs[0], refs[1], num_groups, blk)
+        plane_at = mm.prepared_plane_index(refs[2:-2], plane_rows)
     oh_loT, oh_hi = _rel_onehots(ids_r, p, gp, hpad, blk)
 
     def chh(a):
         if a == 0 and ones_first:
             return oh_hi  # folded all-ones count channel
+        if prepared is not None:
+            return oh_hi * mm.prepared_plane_row(*plane_at[a - 1], blk)
         return oh_hi * ch_ref[pl.ds(a, 1), :]
 
     if stacked:
@@ -210,6 +221,60 @@ def _sums_kernel(ids_ref, ch_ref, out_ref, acc_ref, *,
     @pl.when(i == ninner - 1)
     def _():
         out_ref[0] = acc_ref[:]
+
+
+def _sums_plan(num_groups: int, a_real: int, span_hpad: int | None = None):
+    """(hp, npart, blk, ninner, stacked) of one plane-sum launch."""
+    total_h = _hpad_total(num_groups)
+    hp = min(span_hpad or _span_hpad(a_real), total_h)
+    return (hp, -(-total_h // hp)) + _plan_blk(a_real, hp)
+
+
+def sums_blk(num_groups: int, a_real: int) -> int:
+    """The row tile a plane-sum launch of this shape runs at."""
+    return _sums_plan(num_groups, a_real)[2]
+
+
+def _lane_spec(blk: int, ninner: int):
+    return pl.BlockSpec((blk // 128, 128),
+                        lambda p, s, i: (s * ninner + i, _i32(0)),
+                        memory_space=pltpu.VMEM)
+
+
+def _sums_call(ids_lane, operands, operand_specs, num_groups: int,
+               a_real: int, plan, *, interpret: bool, ones_first: bool,
+               prepared=None):
+    """The one ``pallas_call`` of the plane-sum kernel, over per-launch
+    operands (masked ids + stacked bf16 channels) or prepared ones (ids,
+    mask, uint8 lane planes). Returns (A, num_groups) float64."""
+    hp, npart, blk, ninner, stacked = plan
+    gp = hp * LO
+    nsuper = ids_lane.shape[0] * 128 // SUPERBLOCK
+    kern = functools.partial(
+        _sums_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
+        gp=gp, stacked=stacked, ones_first=ones_first, prepared=prepared)
+    out = pl.pallas_call(
+        kern,
+        grid=(npart, nsuper, ninner),
+        in_specs=[_lane_spec(blk, ninner), *operand_specs],
+        out_specs=pl.BlockSpec(
+            (1, a_real, hp, LO),
+            lambda p, s, i: (p * nsuper + s, _i32(0), _i32(0), _i32(0)),
+            memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(
+            (npart * nsuper, a_real, hp, LO), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((a_real, hp, LO), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(a_real, hp, blk, stacked)),
+        interpret=interpret,
+        name="pinot_scatter_sums",
+    )(ids_lane, *operands)
+    # (npart*nsuper, A, hp, LO) → superblock partials reduce in f64, then
+    # partitions concatenate along the group axis
+    tot = jnp.sum(out.reshape(npart, nsuper, a_real, hp, LO), axis=1,
+                  dtype=jnp.float64)
+    return jnp.transpose(tot, (1, 0, 2, 3)).reshape(
+        a_real, npart * gp)[:, :num_groups]
 
 
 def plane_group_sums(gid, channels, num_groups: int, *,
@@ -228,50 +293,42 @@ def plane_group_sums(gid, channels, num_groups: int, *,
     exactness argument of the mm kernel, per partition.
     """
     a_real, n = channels.shape
-    total_h = _hpad_total(num_groups)
-    hp = min(span_hpad or _span_hpad(a_real), total_h)
-    npart = -(-total_h // hp)
-    gp = hp * LO
-    blk, ninner, stacked = _plan_blk(a_real, hp)
+    plan = _sums_plan(num_groups, a_real, span_hpad)
+    _hp, _npart, blk, ninner, _stacked = plan
     n_pad = ((n + SUPERBLOCK - 1) // SUPERBLOCK) * SUPERBLOCK
-    nsuper = n_pad // SUPERBLOCK
-
     ids_lane = _pad_lane(gid.astype(jnp.int32), n_pad, n, num_groups)
     ch = jnp.concatenate(
         [channels, jnp.zeros((a_real, n_pad - n), channels.dtype)], axis=1
     ) if n_pad > n else channels
-    kern = functools.partial(
-        _sums_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
-        gp=gp, stacked=stacked, ones_first=first_channel_ones)
-    out = pl.pallas_call(
-        kern,
-        grid=(npart, nsuper, ninner),
-        in_specs=[
-            pl.BlockSpec((blk // 128, 128),
-                         lambda p, s, i: (s * ninner + i, _i32(0)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((a_real, blk),
-                         lambda p, s, i: (_i32(0), s * ninner + i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, a_real, hp, LO),
-            lambda p, s, i: (p * nsuper + s, _i32(0), _i32(0), _i32(0)),
-            memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
-            (npart * nsuper, a_real, hp, LO), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((a_real, hp, LO), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(a_real, hp, blk, stacked)),
-        interpret=interpret,
-        name="pinot_scatter_sums",
-    )(ids_lane, ch)
-    # (npart*nsuper, A, hp, LO) → superblock partials reduce in f64, then
-    # partitions concatenate along the group axis
-    tot = jnp.sum(out.reshape(npart, nsuper, a_real, hp, LO), axis=1,
-                  dtype=jnp.float64)
-    return jnp.transpose(tot, (1, 0, 2, 3)).reshape(
-        a_real, npart * gp)[:, :num_groups]
+    ch_spec = pl.BlockSpec((a_real, blk),
+                           lambda p, s, i: (_i32(0), s * ninner + i),
+                           memory_space=pltpu.VMEM)
+    return _sums_call(ids_lane, (ch,), [ch_spec], num_groups, a_real, plan,
+                      interpret=interpret, ones_first=first_channel_ones)
+
+
+def plane_group_sums_prepared(ids_lane, mask_lane, planes, num_groups: int,
+                              *, interpret: bool = False,
+                              span_hpad: int | None = None):
+    """``plane_group_sums`` over the batch's prepared operands
+    (ops/groupby_mm.py ``prepared_ids`` / ``prepared_planes``) and the
+    launch's ``mask_lanes``: channel 0 counts (no operand rows), channels
+    1.. are the rows of ``planes`` in order; ``where(mask, ids,
+    num_groups)`` happens in VMEM. Same f32 superblock partials, same
+    f64 reduction: bit-identical to the per-launch operands."""
+    a_real = 1 + sum(p.shape[0] for p in planes)
+    plan = _sums_plan(num_groups, a_real, span_hpad)
+    _hp, _npart, blk, ninner, _stacked = plan
+    plane_specs = [
+        pl.BlockSpec((p.shape[0], blk // 128, 128),
+                     lambda p_, s, i: (_i32(0), s * ninner + i, _i32(0)),
+                     memory_space=pltpu.VMEM)
+        for p in planes]
+    return _sums_call(
+        ids_lane, (mask_lane, *planes),
+        [_lane_spec(blk, ninner), *plane_specs], num_groups, a_real, plan,
+        interpret=interpret, ones_first=True,
+        prepared=(num_groups, tuple(p.shape[0] for p in planes)))
 
 
 # ---------------------------------------------------------------------------

@@ -262,6 +262,17 @@ class ServerInstance:
                                  "partials_evictions")):
                 self._register_gauge(
                     gname, (lambda _a=attr, _d=dev: getattr(_d, _a)))
+            # the dense group-by's prepared kernel operands: their HBM
+            # bytes, and launches by where the operands came from
+            self._register_gauge(
+                "deviceGroupbyOperandBytes",
+                (lambda _d=dev: _d.groupby_operand_bytes()))
+            for gname, origin in (("deviceGroupbyOperandHits", "prepared"),
+                                  ("deviceGroupbyOperandBuilds", "built"),
+                                  ("deviceGroupbyPerLaunch", "perLaunch")):
+                self._register_gauge(
+                    gname, (lambda _o=origin, _d=dev:
+                            _d.groupby_operand_launches[_o]))
             self._register_gauge(
                 "deviceResidentBytes",
                 (lambda _d=dev: _d.resident_bytes()))

@@ -235,7 +235,7 @@ class TestSortedHighCardGroupBy:
     def test_sorted_template_used(self, hc):
         dev, _, _ = hc
         dev.execute("SELECT user, item, SUM(spend) FROM hc GROUP BY user, item")
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev.device._pipelines}
+        shapes = {k[0][0] for k in dev.device._pipelines}
         assert "groupby_sorted" in shapes
 
     def test_unsupported_agg_falls_back_to_host(self, hc):
@@ -291,7 +291,7 @@ class TestSortedHighCardGroupBy:
         dev.add_segment("fs", seg)
         r = dev.execute("SELECT a, b, SUM(v) FROM fs WHERE a = 'a_tiny' "
                         "GROUP BY a, b ORDER BY b")
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev.device._pipelines}
+        shapes = {k[0][0] for k in dev.device._pipelines}
         assert "groupby_sorted" in shapes
         got = [row[2] for row in r["resultTable"]["rows"]]
         assert got == [1.25, 2.5, 1.25], got
@@ -325,7 +325,7 @@ class TestSortedHighCardGroupBy:
         sql = ("SELECT a, b, SUM(v) FROM bigs GROUP BY a, b "
                "ORDER BY SUM(v) DESC, a, b LIMIT 50")
         rd, rh = dev.execute(sql), host.execute(sql)
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev.device._pipelines}
+        shapes = {k[0][0] for k in dev.device._pipelines}
         assert "groupby_sorted" in shapes
         assert rd["resultTable"]["rows"] == rh["resultTable"]["rows"]
 
@@ -344,7 +344,7 @@ class TestDeviceDistinct:
             rd, rh = dev.execute(sql), host.execute(sql)
             assert not rd.get("exceptions"), rd
             assert rd["resultTable"]["rows"] == rh["resultTable"]["rows"], sql
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev.device._pipelines}
+        shapes = {k[0][0] for k in dev.device._pipelines}
         assert "groupby" in shapes
 
     def test_distinct_expression_falls_back(self, engines):
@@ -484,7 +484,7 @@ class TestSortedRegimeBoundaries:
         """D < sorted_k: the radix regime answers on device, exactly."""
         dev, host = self._engines(bc, limit=6000)
         rd, rh = self._assert_parity(dev, host)
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev.device._pipelines}
+        shapes = {k[0][0] for k in dev.device._pipelines}
         assert "groupby_sorted" in shapes
         assert rd["numGroupsLimitReached"] is False
         assert rh["numGroupsLimitReached"] is False
@@ -511,7 +511,7 @@ class TestSortedRegimeBoundaries:
         monkeypatch.setattr(devmod, "MAX_SORTED_GROUPS", 1 << 17)
         dev2, host2 = self._engines(bc, limit=100_000)
         rd, _rh = self._assert_parity(dev2, host2)
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in dev2.device._pipelines}
+        shapes = {k[0][0] for k in dev2.device._pipelines}
         assert "groupby_sorted" in shapes
         assert rd["numGroupsLimitReached"] is False
 

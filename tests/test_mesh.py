@@ -138,7 +138,7 @@ class TestSortedRegimeMesh:
     def test_mesh_sorted_template_on_device(self, hc_engines):
         sharded, _, _ = hc_engines
         sharded.execute("SELECT u, i, SUM(v) FROM hcm GROUP BY u, i")
-        shapes = {t[0] for (t, _m, _bs, _w, _tr, _pl) in sharded.device._pipelines}
+        shapes = {k[0][0] for k in sharded.device._pipelines}
         assert "groupby_sorted" in shapes
 
     def test_mesh_overflow_still_falls_back(self, hc_engines):

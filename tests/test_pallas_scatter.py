@@ -37,7 +37,7 @@ from pinot_tpu.storage.creator import build_segment
 from pinot_tpu.storage.segment import ImmutableSegment
 
 
-def _rows_close(rows_a, rows_b):
+def _rows_close(rows_a, rows_b, rtol=1e-5):
     if len(rows_a) != len(rows_b):
         return False
     for ra, rb in zip(rows_a, rows_b):
@@ -45,7 +45,7 @@ def _rows_close(rows_a, rows_b):
             if isinstance(x, str) or x is None:
                 if x != y:
                     return False
-            elif not np.isclose(float(x), float(y), rtol=1e-5, atol=1e-6):
+            elif not np.isclose(float(x), float(y), rtol=rtol, atol=1e-6):
                 return False
     return True
 
@@ -301,15 +301,39 @@ DIFF_QUERIES = [
 ]
 
 
+# statements whose group-by sums a FLOAT column through the plane kernels
+# (the xla engine keeps mm_mode="interpret", so its float sums ride
+# ops/groupby_mm.py's kernel): a float's three bf16 planes are added up in
+# f32 inside a superblock, and that is not order-independent the way byte
+# planes, min/max and presence are. The Pallas kernel contracts at radix
+# 128 and the matmul kernel at the radix _plan_lo picks (32 at 220
+# groups), so the two dots add the 8192 rows of a step up in different
+# orders and a group's sum can differ in its last f32 bit (seen: 2e-10
+# relative in one group of 220: in every run of this file alone, in most
+# whole runs under six workers, where the CPU's dot presumably splits the
+# contraction among its threads another way). Each engine repeats its own
+# answer exactly. These are held to f32 rounding, a tenth of the host
+# comparison's tolerance.
+FLOAT_SUM_QUERIES = {
+    "SELECT d, SUM(fv), AVG(fv) FROM t GROUP BY d ORDER BY d LIMIT 250",
+}
+
+
 @pytest.mark.parametrize("sql", DIFF_QUERIES)
 def test_pallas_xla_host_parity(engines, sql):
     pallas, xla, host, _ = engines
     rp, rx, rh = pallas.execute(sql), xla.execute(sql), host.execute(sql)
     for r in (rp, rx, rh):
         assert not r.get("exceptions"), (sql, r)
-    # the two device paths are BIT-exact (order-independent kernels)
-    assert rp["resultTable"]["rows"] == rx["resultTable"]["rows"], (
-        sql, rp["resultTable"]["rows"][:4], rx["resultTable"]["rows"][:4])
+    if sql in FLOAT_SUM_QUERIES:
+        assert _rows_close(rp["resultTable"]["rows"],
+                           rx["resultTable"]["rows"], rtol=1e-6), (
+            sql, rp["resultTable"]["rows"][:4], rx["resultTable"]["rows"][:4])
+    else:
+        # the two device paths are BIT-exact (order-independent kernels)
+        assert rp["resultTable"]["rows"] == rx["resultTable"]["rows"], (
+            sql, rp["resultTable"]["rows"][:4],
+            rx["resultTable"]["rows"][:4])
     # host compares at the established float tolerance (device floats
     # live in the f32 value space)
     assert _rows_close(rp["resultTable"]["rows"], rh["resultTable"]["rows"]), (

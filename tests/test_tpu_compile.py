@@ -198,3 +198,102 @@ def test_pipeline_q2_blockskip_real_batch(spec):
               "pr4": spec((), "int64"), "pr5": spec((), "int64")}
     _, mem = _compile(fn, cols, spec((8,), "int32"), params)
     assert mem.temp_size_in_bytes < 64 << 20, mem
+
+
+# ---- the dense group-by over the batch's prepared operands (ISSUE 30) ------
+
+# the benchmark's banded group-by (benchmark/traffic/groupby_bands_c4.json)
+# as the executor plans it on the SSB batch
+BANDS_TEMPLATE = ("groupby", ("range_dict", "lo_discount", "pr0", "pr1"),
+                  ("lo_suppkey",), (2000,), (Q2_SUM_REVENUE,), 0, False)
+BANDS_WIDTHS = {"lo_revenue": ("<i4", 0, False, ""),
+                "lo_suppkey": ("<u2", 0, False, ""),
+                "lo_discount": ("|u1", 0, False, "")}
+N_BATCH = BATCH[0] * BATCH[1]  # a whole number of superblocks: no padding
+
+
+def _big_results(hlo_text: str, op: str = "") -> list:
+    """(dtype, instruction line) of every non-parameter instruction whose
+    result has at least one element a row of the batch; ``op`` keeps one
+    kind of instruction."""
+    import re
+
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]+)\]", line)
+        if not m or " parameter(" in line or (op and f" {op}(" not in line):
+            continue
+        n = 1
+        for d in m.group(2).split(","):
+            n *= int(d)
+        if n >= N_BATCH:
+            out.append((m.group(1), line.strip()[:160]))
+    return out
+
+
+def _assert_split_relayout(hlo_text: str, n: int):
+    """``n`` (S, L) -> lanes relayouts, each the layout-changing copy of
+    the (8, 97664, 128) view that ops/groupby_mm.py _to_lanes asks for,
+    and no row-scale ``reshape`` instruction: that is the one-step
+    flatten, which costs the compiler 17 s at 32 bits and minutes at 8."""
+    assert not _big_results(hlo_text, "reshape")
+    copies = _big_results(hlo_text, "copy")
+    assert len(copies) == n and all(
+        "[8,97664,128]" in line for _dt, line in copies), copies
+
+
+@pytest.mark.parametrize("width", [1, 2, 4])
+def test_pipeline_prepared_groupby_real_batch(spec, width):
+    """The cell's statement over prepared operands, alone and as a cohort
+    of two and of four: a launch computes the mask, relays it out and
+    calls the kernel. No byte planes, ones channel or masked ids are
+    written (nothing row-scale in bf16 or 64 bits), the value and key
+    columns are not read, and the one (S, L) -> lanes relayout left is
+    the mask's (alone: at one byte a row; in a cohort XLA relays the
+    filter's column out once, widened, and compares in lanes), written
+    as the split of L (ops/groupby_mm.py _to_lanes): the one-step flatten
+    of an 8-bit plane costs this compiler three to four minutes."""
+    prepared = dev.plan_prepared_groupby(
+        BANDS_TEMPLATE, BANDS_WIDTHS, N_BATCH, "tpu", "tpu", {0: 100})
+    assert prepared == ("pallas", "gk::lo_suppkey",
+                        ((0, "gv::lo_revenue::100::3", 3),))
+    fn = dev.build_pipeline(BANDS_TEMPLATE, mm_mode="tpu",
+                            sorted_hll_ok=True, widths=BANDS_WIDTHS,
+                            pallas_mode="tpu", prepared=prepared)
+    cols = {"lo_revenue": spec(BATCH, "int32"),
+            "lo_suppkey": spec(BATCH, "uint16"),
+            "lo_discount": spec(BATCH, "uint8"),
+            "gk::lo_suppkey": spec((N_BATCH // 128, 128), "uint16"),
+            "gv::lo_revenue::100::3": spec((3, N_BATCH // 128, 128), "uint8")}
+    params = {"off0": spec((), "int64"), "ps_alive": spec((8,), "bool"),
+              "pr0": spec((), "int32"), "pr1": spec((), "int32")}
+    if width > 1:
+        params = {k: spec((width,) + v.shape, v.dtype)
+                  for k, v in params.items()}
+        solo = fn
+
+        def fn(c, nd, pstack):
+            return jax.vmap(lambda p: solo(c, nd, p))(pstack)
+
+    compiled, mem = _compile(fn, cols, spec((8,), "int32"), params)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # jit drops the (S, L) value and key columns: nothing reads them
+    assert mem.argument_size_in_bytes < 620 << 20, mem
+    big = _big_results(text)
+    assert not [b for b in big if b[0] in ("bf16", "s64", "u64", "f64")], big
+    _assert_split_relayout(text, 1)
+
+
+@pytest.mark.parametrize("name", ["prepared_ids", "prepared_planes"])
+def test_prepared_operand_builders_real_batch(spec, name):
+    """The once-a-batch builders: one relayout each, by the split of L."""
+    if name == "prepared_ids":
+        compiled, _ = _compile(
+            lambda c, nd: mm.prepared_ids(c, nd, num_groups=2000),
+            spec(BATCH, "uint16"), spec((8,), "int32"))
+    else:
+        compiled, _ = _compile(
+            lambda c: mm.prepared_planes(c, delta=-100, nplanes=3),
+            spec(BATCH, "int32"))
+    _assert_split_relayout(compiled.as_text(), 1)
