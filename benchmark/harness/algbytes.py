@@ -2,10 +2,12 @@
 from HBM to answer it, whatever the program does — never a number the
 program reports.
 
-Rows the statement must read (rows of the segments whose range of the layout
-column meets the statement's predicates on it; every row where the layout
-prunes nothing) x the summed widths of the columns it names, each the fewest
-whole bytes that hold the column's value range.
+Rows the statement must read (rows of the segments whose range of a column
+the layout narrows — the layout column, or one derived from it alone, as a
+date's year — meets the statement's predicates on it; every row where the
+layout prunes nothing) x the summed widths of the columns it names, each the
+fewest whole bytes that hold the column's domain: a number's value range, a
+string's index in its dictionary.
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ from . import table
 
 
 def column_width_bytes(config: dict, column: str) -> int:
-    n = table.domain_size(table.column_spec(config, column))
+    n = table.domain_size(config, column)
     return max(1, -(-(n - 1).bit_length() // 8))
 
 
@@ -35,12 +37,12 @@ def _meets(lo: int, hi: int, op: str, args: list) -> bool:
 def segments_read(config: dict, statement: dict) -> int:
     """Segments that min/max pruning on the layout column cannot drop."""
     n = 0
+    where = statement["reference"].get("where", ())
     for k in range(config["segments"]):
-        rng = table.segment_date_range(config, k)
-        if rng is None or all(
-                _meets(rng[0], rng[1], op, args)
-                for col, op, *args in statement["reference"].get("where", ())
-                if col == config["layout"]["column"]):
+        ranges = {col: table.segment_range(config, k, col)
+                  for col in {w[0] for w in where}}
+        if all(ranges[col] is None or _meets(*ranges[col], op, args)
+               for col, op, *args in where):
             n += 1
     return n
 

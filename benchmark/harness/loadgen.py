@@ -18,6 +18,8 @@ stdout:
 
   url U  where the broker listens (first, once the cluster is up)
   once   every statement once, one after another, on one connection
+  burst  2, 3, .. ``clients`` callers send at the same instant, three times
+         each, distinct statements: the warm-up's cohorts of every width
   start  the clients begin; answered at once with the start time
   stop   the clients finish the request they are in and stop; answered with
          every request's record and the time the stop arrived
@@ -35,7 +37,8 @@ import time
 
 STATS_KEPT = ("timeUsedMs", "numSegmentsQueried", "numSegmentsProcessed",
               "numSegmentsPrunedByServer", "deviceKernelMs", "deviceLinkMs",
-              "deviceBytesMoved", "partialResult", "numDocsScanned")
+              "deviceBytesMoved", "partialResult", "numDocsScanned",
+              "numSegmentsOnHost")
 
 
 def _span_names(stats: dict) -> list:
@@ -45,9 +48,10 @@ def _span_names(stats: dict) -> list:
 
 
 def send(client, conn, statement: dict, text: str, traced: bool) -> dict:
-    """One request: send -> rows parsed, on this process's clock. Only a
-    ``traced`` statement's response carries spans, so only there can a
-    ``host_fallback`` span be looked for (``off_device``)."""
+    """One request: send -> rows parsed, on this process's clock.
+    ``off_device``: a ``device`` statement that the host executor answered
+    a segment of — every response counts those (``numSegmentsOnHost``), and
+    a ``traced`` one carries the ``host_fallback`` span besides."""
     rec = {"statement": statement["name"], "ok": False,
            "device": bool(statement.get("device")), "t_send": time.time()}
     try:
@@ -60,9 +64,9 @@ def send(client, conn, statement: dict, text: str, traced: bool) -> dict:
         rec["rows"] = rows
         # under load only the leader of a coalesced launch is charged
         # deviceBytesMoved, so a 0 there proves nothing about its cohort
-        if traced:
-            rec["off_device"] = rec["device"] and any(
-                "host_fallback" in p for p in _span_names(stats))
+        rec["off_device"] = rec["device"] and bool(
+            stats.get("numSegmentsOnHost") or (traced and any(
+                "host_fallback" in p for p in _span_names(stats))))
         if stats.get("partialResult"):
             rec["error"] = "partialResult"
         else:
@@ -109,6 +113,38 @@ class Generator:
                          True) for s in self.statements]
         finally:
             conn.close()
+
+    def burst(self, repeats: int = 3) -> list:
+        """Cohorts of every width the window can meet, on purpose: the
+        executor coalesces callers that arrive together into one launch and
+        compiles one program a width, and rounds of paced traffic meet the
+        wider ones by chance — a width first met in the window compiled
+        there (PERF.md, PR 31). ``w`` callers wait at a barrier and send
+        distinct statements at once, for each w from 2 to ``clients``."""
+        conns = [self._connect() for _ in range(self.traffic["clients"])]
+        records = []
+
+        def one(barrier, conn, s):
+            barrier.wait(timeout=60)
+            records.append(send(self.client, conn, s, self.texts[s["name"]],
+                                self.trace))
+
+        try:
+            for width in range(2, len(conns) + 1):
+                for turn in range(repeats):
+                    barrier = threading.Barrier(width)
+                    callers = [threading.Thread(target=one, args=(
+                        barrier, conns[i],
+                        self.statements[(i + turn) % len(self.statements)]))
+                        for i in range(width)]
+                    for t in callers:
+                        t.start()
+                    for t in callers:
+                        t.join(timeout=180)
+        finally:
+            for conn in conns:
+                conn.close()
+        return records
 
     def _client(self, index: int, out: list) -> None:
         rng = random.Random(self.seed * 1000 + index)
@@ -168,6 +204,8 @@ def main() -> int:
             reply = {"url": cmd[4:]}
         elif cmd == "once":
             reply = {"records": gen.once()}
+        elif cmd == "burst":
+            reply = {"records": gen.burst()}
         elif cmd == "start":
             reply = {"t_start": gen.start()}
         elif cmd == "stop":

@@ -3,10 +3,14 @@ columns by numpy alone, and the comparison that decides ``correct``.
 
 It imports nothing of the program and takes nothing the program made. A
 statement's meaning comes from the ``reference`` entry beside its SQL in the
-traffic file: a conjunction of predicates, group-by columns, SUM / COUNT
-aggregates, an order and a limit. Answers are folded in one segment at a
-time; integer sums are exact (a segment's float64 bincount stays far below
-2**53, and the running totals are int64).
+traffic file: a conjunction of predicates, group-by columns of any generator
+kind (a kind gives the maps between a value and its index in the domain),
+SUM / COUNT aggregates — a SUM's argument a column or a whole-number
+expression over columns, ``["sub", "lo_revenue", "lo_supplycost"]``, whose
+operators are files found by name (``harness/expr.py``) — an order and a
+limit. Strings compare as SQL's binary collation does, code point by code
+point. Answers are folded in one segment at a time, and whole-number sums
+are exact to the unit (``_exact_sums``); the running totals are int64.
 
 ``mode`` plants the controls — the reference put in the program's place with
 one stated guarantee broken: ``f32_partials`` carries each segment's partial
@@ -20,7 +24,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import table
+from . import expr, table
 
 MODES = ("exact", "f32_partials", "drop_segment")
 
@@ -35,22 +39,49 @@ _OPS = {
 }
 
 
+def _exact_sums(cell, weights, n: int):
+    """Per-cell sums of int64 ``weights``, exact. ``bincount`` adds in
+    float64, which holds every whole number below 2**53: a segment's sums
+    are exact while rows x the largest weight stays under that. SSB's
+    largest argument, ``lo_extendedprice * lo_discount``, is at most 50 x
+    209,900 cents x 10 = 1.05e8 a row: 1.97e15 over 18,750,000 rows, a
+    quarter of 2**53 = 9.0e15: that argument's ceiling is 85.8M rows a
+    segment, a bare ``lo_revenue``'s (under 1.05e7) 858M. A segment that
+    could pass the ceiling, by its own largest weight, has its weights split
+    into a high part and 26 low bits, each summed exactly (2**26 x fewer
+    than 2**27 rows) and put together in int64."""
+    peak = int(np.abs(weights).max(initial=0)) * len(weights)
+    if peak < 1 << 53:
+        return np.rint(np.bincount(cell, weights=weights.astype(np.float64),
+                                   minlength=n)).astype(np.int64)
+    if len(weights) >= 1 << 27:
+        raise ValueError("a segment of 2**27 rows or more: cut it")
+    high, low = np.divmod(weights, 1 << 26)
+    return (_exact_sums(cell, high, n) << 26) + _exact_sums(cell, low, n)
+
+
 class _Statement:
     def __init__(self, config: dict, spec: dict, mode: str):
         self.spec, self.mode = spec, mode
-        self.group_specs = [table.column_spec(config, c)
-                            for c in spec.get("group_by", ())]
-        for g in self.group_specs:
-            if g["kind"] not in ("integers", "choice"):
-                raise ValueError(f"cannot group by a {g['kind']} column")
-        for fn, _col in spec["aggregates"]:
+        # a group key: (its kind's file, its generator entry, its domain's size)
+        self.groups = []
+        for column in spec.get("group_by", ()):
+            g = table.column_spec(config, column)
+            self.groups.append((table.kind_of(g), g,
+                                table.kind_of(g).domain_size(g)))
+        for fn, arg in spec["aggregates"]:
             if fn not in ("sum", "count"):
                 raise ValueError(f"the reference has no aggregate {fn!r}")
+            if fn == "sum":
+                expr.check(arg)
+        for name in self.columns():
+            if table.column_spec(config, name).get("helper"):
+                raise ValueError(f"{name} is a helper of the generator, no "
+                                 "column of the table")
         if spec.get("limit") and spec.get("group_by") \
                 and not spec.get("order_by"):
             raise ValueError("a limit over groups needs an order")
-        self.sizes = [table.domain_size(g) for g in self.group_specs]
-        n = int(np.prod(self.sizes)) if self.sizes else 1
+        n = int(np.prod([size for _, _, size in self.groups]))
         acc = np.float32 if mode == "f32_partials" else np.int64
         self.count = np.zeros(n, dtype=np.int64)
         self.aggs = [np.zeros(n, dtype=np.int64 if fn == "count" else acc)
@@ -59,7 +90,8 @@ class _Statement:
     def columns(self) -> set:
         s = self.spec
         return ({w[0] for w in s.get("where", ())} | set(s.get("group_by", ()))
-                | {c for fn, c in s["aggregates"] if fn != "count"})
+                | set().union(*(expr.columns(arg) for fn, arg
+                                in s["aggregates"] if fn != "count")))
 
     def _mask(self, cols: dict):
         mask = None
@@ -78,38 +110,37 @@ class _Statement:
         pick = (lambda v: v) if mask is None else (lambda v: v[mask])
         n = len(self.count)
         cell = np.zeros(len(pick(next(iter(cols.values())))), dtype=np.int64)
-        for g, size in zip(self.group_specs, self.sizes):
-            v = pick(cols[g["column"]])
-            ids = v.astype(np.int64) - g["low"] if g["kind"] == "integers" \
-                else np.searchsorted(np.sort(np.array(g["values"])), v)
-            cell = cell * size + ids
+        for kind, g, size in self.groups:
+            cell = cell * size + kind.index_of(g, pick(cols[g["column"]]))
         self.count += np.bincount(cell, minlength=n)
-        for (fn, col), total in zip(s["aggregates"], self.aggs):
+        for (fn, arg), total in zip(s["aggregates"], self.aggs):
             if fn == "count":
                 total += np.bincount(cell, minlength=n)
                 continue
-            part = np.rint(np.bincount(
-                cell, weights=pick(cols[col]).astype(np.float64),
-                minlength=n)).astype(np.int64)
+            part = _exact_sums(cell, expr.evaluate(
+                arg, {c: pick(cols[c]) for c in expr.columns(arg)}), n)
             total += part.astype(total.dtype)
 
     def rows(self) -> list:
         s = self.spec
         live = np.flatnonzero(self.count)
-        keys = []
+        ids = []
         rest = live
-        for g, size in zip(reversed(self.group_specs), reversed(self.sizes)):
-            rest, ids = np.divmod(rest, size)
-            keys.append(ids + g["low"] if g["kind"] == "integers"
-                        else np.sort(np.array(g["values"]))[ids])
-        keys.reverse()
-        if not self.group_specs:
+        for _, _, size in reversed(self.groups):
+            rest, i = np.divmod(rest, size)
+            ids.append(i)
+        ids.reverse()
+        keys = [kind.value_of(g, i)
+                for (kind, g, _), i in zip(self.groups, ids)]
+        if not self.groups:
             live = np.array([0])  # an aggregate without groups: one row
         vals = [a[live] for a in self.aggs]
-        # ties fall to the group key, ascending, then to the order given
+        # ties fall to the group key, ascending, then to the order given; a
+        # key orders as its index in the domain does (domains ascend), which
+        # a string has and a negation has not
         order = [live]
         for kind, i, direction in reversed(s.get("order_by", ())):
-            v = vals[i] if kind == "agg" else keys[i]
+            v = vals[i] if kind == "agg" else ids[i]
             order.append(-v if direction == "desc" else v)
         idx = np.lexsort(order)
         if s.get("limit"):
@@ -177,11 +208,13 @@ LIMITS = {"answers_wrong": 0, "answers_missing": 0, "max_abs_err": 0.0,
 def compare(records: list, want: dict) -> dict:
     """Every answer of the window against the reference. ``records`` are the
     load generator's, one per request sent. Returns the numbers compared,
-    each beside its limit, and ``correct``. ``off_device`` is compared only
-    where the requests were traced (a record then has the key): an untraced
-    response carries no span to look for, and a limit on nothing is no
-    limit."""
-    wrong = missing = off_device = compared = traced = 0
+    each beside its limit, and ``correct``. ``off_device`` counts the
+    answers to a ``device`` statement that say the host executor answered
+    for a segment — ``numSegmentsOnHost`` above 0, which every response
+    carries, or in a traced one a ``host_fallback`` span: a group-by whose
+    key space passes the device's table reruns on the host without an
+    error, and would otherwise be timed as a device number."""
+    wrong = missing = off_device = compared = 0
     worst = 0.0
     for r in records:
         if not r["ok"]:
@@ -192,14 +225,10 @@ def compare(records: list, want: dict) -> dict:
         if err:
             wrong += 1
             worst = max(worst, err)
-        if "off_device" in r:
-            traced += 1
-            off_device += bool(r["off_device"])
+        off_device += bool(r.get("off_device"))
     # JSON has no infinity: a difference that no number measures reads 1e308
     values = {"answers_wrong": wrong, "answers_missing": missing,
-              "max_abs_err": min(worst, 1e308)}
-    if traced:
-        values["off_device"] = off_device
+              "max_abs_err": min(worst, 1e308), "off_device": off_device}
     numbers = {k: {"value": v, "limit": LIMITS[k]} for k, v in values.items()}
     numbers["answers_compared"] = {"value": compared, "at_least": 1}
     correct = compared >= 1 and all(v <= LIMITS[k] for k, v in values.items())

@@ -2,9 +2,12 @@
 and the spawned children that turn each segment's columns into segment
 files with the program's ``build_segment``.
 
-The generator is a copy of ``pinot_tpu/tools/ssb.py segment_columns`` turned
-into data: a configuration's ``generator`` lists one draw per column, and the
-draws are made in that order. Segment ``k`` has a generator of its own,
+The generator is data: a configuration's ``generator`` lists one entry per
+column, each of a ``kind`` that is a file of ``benchmark/generators/`` found
+by that name, and the columns are made in that order, a later one from the
+earlier ones where its kind derives it (the first three kinds are a copy of
+``pinot_tpu/tools/ssb.py segment_columns``). No kind is named here. Segment
+``k`` has a generator of its own,
 ``numpy.random.default_rng([seed, k])``, so that all children start at once
 instead of waiting for the parent to draw through the segments before
 theirs. Nothing here touches JAX; the children pin it to the CPU before the
@@ -19,25 +22,7 @@ import time
 
 import numpy as np
 
-
-def _date_value(spec: dict, day_index):
-    """Day index in [0, years*months*days) -> the date-like int."""
-    per_year = spec["months"] * spec["days"]
-    y, rest = np.divmod(day_index, per_year)
-    m, d = np.divmod(rest, spec["days"])
-    return spec["base"] + y * 10000 + m * 100 + d
-
-
-def domain_size(spec: dict) -> int:
-    """How many distinct values a generated column can take."""
-    kind = spec["kind"]
-    if kind == "integers":
-        return spec["high"] - spec["low"]
-    if kind == "choice":
-        return len(spec["values"])
-    if kind == "date_ymd":
-        return spec["years"] * spec["months"] * spec["days"]
-    raise ValueError(f"unknown generator kind {kind!r}")
+from . import spec as spec_mod
 
 
 def column_spec(config: dict, column: str) -> dict:
@@ -47,91 +32,133 @@ def column_spec(config: dict, column: str) -> dict:
     raise KeyError(f"configuration {config['name']} generates no {column!r}")
 
 
+def kind_of(spec: dict):
+    """The file that says how an entry's column is drawn and what its
+    domain is: ``benchmark/generators/<kind>.py`` (its parts are listed in
+    ``generators/integers.py``)."""
+    return spec_mod.found("generators", spec["kind"])
+
+
+def check_generator(config: dict) -> None:
+    """Every kind the configuration names is found and has looked its entry
+    over, before a row is drawn or a child is started."""
+    for spec in config["generator"]:
+        kind = kind_of(spec)
+        if hasattr(kind, "check"):
+            kind.check(spec)
+
+
+def domain_size(config: dict, column: str) -> int:
+    """How many distinct values a generated column can take."""
+    spec = column_spec(config, column)
+    return kind_of(spec).domain_size(spec)
+
+
 def segment_rng(seed: int, k: int) -> np.random.Generator:
     return np.random.default_rng([seed, k])
 
 
-def draw_segment(config: dict, rng: np.random.Generator, only=None) -> dict:
+def _wanted(config: dict, only) -> tuple:
+    """(the columns to hand back, those to materialise for them): a
+    column a wanted one is derived from is made too, and a ``helper`` is
+    made for others and handed to nobody."""
+    back = {s["column"] for s in config["generator"]
+            if not s.get("helper") and (only is None or s["column"] in only)}
+    made = set(back)
+    for spec in reversed(config["generator"]):
+        kind = kind_of(spec)
+        if spec["column"] in made and hasattr(kind, "needs"):
+            made.update(kind.needs(spec))
+    return back, made
+
+
+def draw_segment(config: dict, rng: np.random.Generator, only=None,
+                 seed: int = 0, k=None) -> dict:
     """One segment's columns in generated order. Every draw is made, so
     that a column's values do not depend on which others are asked for;
-    ``only`` names the columns worth materialising."""
+    ``only`` names the columns worth materialising. A kind may derive its
+    column from those before it and from ``seed`` (a dimension table is the
+    same in every segment of a run). With ``k`` the layout column is re-mapped
+    to segment ``k``'s share of its domain as soon as it is drawn
+    (``by_date``), so that what is derived from it follows."""
     n = config["rows_per_segment"]
-    out = {}
+    back, made = _wanted(config, only)
+    layout = config["layout"]
+    cols = {}
     for spec in config["generator"]:
-        name, kind = spec["column"], spec["kind"]
-        keep = only is None or name in only
-        if kind == "integers":
-            v = rng.integers(spec["low"], spec["high"], n)
-            if keep:
-                out[name] = v.astype(np.int32)
-        elif kind == "choice":
-            v = rng.integers(0, len(spec["values"]), n)
-            if keep:
-                out[name] = np.array(spec["values"])[v]
-        elif kind == "date_ymd":
-            y = rng.integers(0, spec["years"], n)
-            m = rng.integers(0, spec["months"], n)
-            d = rng.integers(0, spec["days"], n)
-            if keep:
-                out[name] = (spec["base"] + y * 10000 + m * 100 + d
-                             ).astype(np.int32)
-        else:
-            raise ValueError(f"unknown generator kind {kind!r}")
-    return out
+        name, kind = spec["column"], kind_of(spec)
+        raw = kind.draw(spec, rng, n) if hasattr(kind, "draw") else None
+        if name not in made:
+            continue
+        cols[name] = kind.column(spec, raw, cols, seed)
+        if k is not None and layout["kind"] != "generated" \
+                and name == layout["column"]:
+            cols[name] = _remap(config, k, cols[name])
+    return {name: v for name, v in cols.items() if name in back}
 
 
-def segment_date_range(config: dict, k: int):
-    """(lowest, highest) value of the layout column in segment ``k``, or
-    None where the layout leaves every segment spanning every value."""
+def _layout_share(config: dict, k: int):
+    """(the layout column's entry, its kind, the first domain index of
+    segment ``k``, the first of segment ``k+1``): S segments cut the domain
+    into S runs of whole values, equal where S divides it."""
     layout = config["layout"]
-    if layout["kind"] == "generated":
-        return None
-    spec = column_spec(config, layout["column"])
-    per_seg, rem = divmod(domain_size(spec), config["segments"])
-    if rem:
-        raise ValueError("by_date needs the date values to divide by the "
-                         "segment count")
-    return (int(_date_value(spec, k * per_seg)),
-            int(_date_value(spec, (k + 1) * per_seg - 1)))
-
-
-def remap_layout_column(config: dict, k: int, cols: dict) -> None:
-    """by_date: segment ``k`` of S keeps its rows and re-maps the layout
-    column to day index ``per_seg*k + day_index // S`` (in place). The
-    answer to a statement does not depend on row order, so this is all the
-    reference needs."""
-    layout = config["layout"]
-    if layout["kind"] == "generated":
-        return
     if layout["kind"] != "by_date":
         raise ValueError(f"unknown layout {layout['kind']!r}")
     spec = column_spec(config, layout["column"])
-    s = config["segments"]
-    per_seg = domain_size(spec) // s
-    segment_date_range(config, k)  # raises where it does not divide
-    # a table from old value to new: the column has few distinct values
-    days = np.arange(domain_size(spec))
-    old = _date_value(spec, days) - spec["base"]
-    lut = np.zeros(old.max() + 1, dtype=np.int32)
-    lut[old] = _date_value(spec, per_seg * k + days // s)
-    cols[layout["column"]] = lut[cols[layout["column"]] - spec["base"]]
+    kind = kind_of(spec)
+    size, s = kind.domain_size(spec), config["segments"]
+    if size < s:
+        raise ValueError("by_date needs a date value a segment at least")
+    return spec, kind, k * size // s, (k + 1) * size // s
+
+
+def segment_range(config: dict, k: int, column: str):
+    """(lowest, highest) value ``column`` can take in segment ``k``, or None
+    where the layout does not narrow it: the layout column itself, and a
+    column derived from it alone (a date's year), read off the segment's
+    share of the dates."""
+    layout = config["layout"]
+    if layout["kind"] == "generated":
+        return None
+    spec, kind, lo, hi = _layout_share(config, k)
+    dates = kind.value_of(spec, np.arange(lo, hi))
+    if column != layout["column"]:
+        derived = column_spec(config, column)
+        of = kind_of(derived)
+        if hasattr(of, "draw") or not hasattr(of, "needs") \
+                or list(of.needs(derived)) != [layout["column"]]:
+            return None
+        dates = of.column(derived, None, {layout["column"]: dates}, 0)
+    distinct = np.unique(dates)  # sorts strings too, where min() cannot
+    return distinct[0].item(), distinct[-1].item()
+
+
+def _remap(config: dict, k: int, values):
+    """by_date: segment ``k`` of S keeps its rows, and a value at index i
+    of the N in the layout column's domain moves to index ``lo + i * (hi -
+    lo) // N`` of the segment's own share [lo, hi) — ``per_seg*k + i // S``
+    where S divides N. The answer to a statement does not depend on row
+    order, so this is all the reference needs."""
+    spec, kind, lo, hi = _layout_share(config, k)
+    size = kind.domain_size(spec)
+    # the domain is small: where each of its values moves to, then a gather
+    moved = kind.value_of(spec, lo + np.arange(size) * (hi - lo) // size)
+    return moved.astype(values.dtype)[kind.index_of(spec, values)]
 
 
 def reference_segments(config: dict, seed: int, only):
     """Each segment's columns as the reference needs them: drawn from the
     segment's own generator, the layout column re-mapped, rows unsorted."""
     for k in range(config["segments"]):
-        cols = draw_segment(config, segment_rng(seed, k), only=only)
-        remap_layout_column(config, k, cols)
-        yield cols
+        yield draw_segment(config, segment_rng(seed, k), only=only,
+                           seed=seed, k=k)
 
 
-def lay_out(config: dict, k: int, cols: dict) -> dict:
-    """The segment as it is stored: by_date re-maps the layout column and
-    stable-sorts the rows by it."""
+def lay_out(config: dict, cols: dict) -> dict:
+    """The segment as it is stored: by_date stable-sorts the rows by the
+    layout column, which ``draw_segment`` re-mapped."""
     if config["layout"]["kind"] == "generated":
         return cols
-    remap_layout_column(config, k, cols)
     order = np.argsort(cols[config["layout"]["column"]], kind="stable")
     for name in cols:
         # one column at a time: a second copy of the whole segment in each
@@ -162,8 +189,8 @@ def _build_segment_job(job):
     from pinot_tpu.storage.creator import build_segment
 
     t0 = time.time()
-    cols = _HandOver(
-        lay_out(config, k, draw_segment(config, segment_rng(seed, k))))
+    cols = _HandOver(lay_out(config, draw_segment(
+        config, segment_rng(seed, k), seed=seed, k=k)))
     build_segment(Schema.from_json(config["schema"]), cols, out_dir,
                   TableConfig.from_json(config["table_config"]),
                   os.path.basename(out_dir))
@@ -174,6 +201,7 @@ def build_table(config: dict, seed: int, out_root: str, reference, say):
     """Build every segment in spawned children while this process draws the
     same columns from the same generator and folds those the reference
     needs into it. Returns the segment directories."""
+    check_generator(config)
     n_seg = config["segments"]
     dirs = [os.path.join(out_root, f"s{k}") for k in range(n_seg)]
     workers = min(n_seg, max(1, (os.cpu_count() or 2) - 1))

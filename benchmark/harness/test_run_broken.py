@@ -1,9 +1,12 @@
 """A whole run at a tiny size on the CPU, the look for a chip skipped: sound
 as it stands, and ``correct`` false with the timed path broken underneath —
 an answer altered where the broker hands it out, a caller handed the answer
-to another caller's statement, and a segment of the table left out of what
-the server holds."""
+to another caller's statement, a segment of the table left out of what the
+server holds, and the device statements answered by the host executor. Also
+SSB flat at its tiny size, from a BENCHMARK file the test writes: a
+configuration is files and entries."""
 
+import json
 import os
 
 import pytest
@@ -21,10 +24,10 @@ def _look(chips):
     return jax.devices()[:chips]
 
 
-def _run(workload, seed):
+def _run(workload, seed, benchmark_json=TINY):
     args = run_mod.parse(["--workload", workload, "--seed", str(seed),
                           "--seconds", "2", "--trace", "0",
-                          "--benchmark-json", TINY])
+                          "--benchmark-json", benchmark_json])
     return run_mod.run(args, _look)
 
 
@@ -72,11 +75,35 @@ def _leave_a_segment_out(monkeypatch):
         lambda self, dirs, say: sound(self, dirs[:1] + dirs[2:], say))
 
 
+def _answer_on_the_host(monkeypatch):
+    """What a group-by whose key space passes the device's table does: once
+    the warm-up is over every launch is refused, the host executor answers,
+    exactly, and nothing errs."""
+    from pinot_tpu.engine.device import DeviceExecutor
+    from pinot_tpu.engine.params import DeviceUnsupported
+
+    sound_launch, sound_check = DeviceExecutor.launch, run_mod.check_counters
+    refusing = []
+
+    def launch(self, *a, **kw):
+        if refusing:
+            raise DeviceUnsupported("refused by the test")
+        return sound_launch(self, *a, **kw)
+
+    def check_counters(cluster, where):  # the last check before the window
+        sound_check(cluster, where)
+        refusing.append(where)
+
+    monkeypatch.setattr(DeviceExecutor, "launch", launch)
+    monkeypatch.setattr(run_mod, "check_counters", check_counters)
+
+
 @pytest.mark.parametrize("workload", ["tiny.groupby_scan",
                                       "tiny_bydate.range_sum"])
 @pytest.mark.parametrize("fault", [None, _alter_an_answer,
                                    _hand_over_anothers_answer,
-                                   _leave_a_segment_out])
+                                   _leave_a_segment_out,
+                                   _answer_on_the_host])
 def test_run(monkeypatch, workload, fault):
     if fault:
         fault(monkeypatch)
@@ -87,9 +114,15 @@ def test_run(monkeypatch, workload, fault):
         assert result["correct"] and result["failed"] == 0
         assert set(result["metrics"]) == {"queries_per_s", "query_p50_ms",
                                           "query_p95_ms", "setup_s"}
-        assert "off_device" not in numbers  # an untraced run has no span
+        assert numbers["off_device"] == {"value": 0, "limit": 0}
+    elif fault is _answer_on_the_host:
+        # every answer is right and none came from the device: only the
+        # always-on count of the untraced responses says so
+        assert not result["correct"] and result["failed"] == 0
+        assert numbers["off_device"]["value"] == result["attempted"]
     else:
         assert not result["correct"]
+        assert numbers["off_device"]["value"] == 0
         wrong = numbers["answers_wrong"]["value"]
         assert result["failed"] == wrong
         if fault is _alter_an_answer or (
@@ -101,3 +134,28 @@ def test_run(monkeypatch, workload, fault):
             # row of
             assert 0 < wrong <= result["attempted"]
     assert list(result)[-1] == "compared"
+
+
+def test_a_configuration_is_files_and_entries(tmp_path):
+    """SSB flat joins the two tiny cells by a configuration file, a traffic
+    file and two entries of a BENCHMARK file: no file of the harness names
+    its kinds, its operators or its columns. All 13 statements are sent:
+    the program answers each of them on the device path at this size (the
+    seed is one at which no flight is empty)."""
+    with open(TINY) as f:
+        bench = json.load(f)
+    bench["configs"].append({
+        "name": "ssb_flat_tiny", "source": "test", "reduced": [],
+        "file": "benchmark/harness/testdata/ssb_flat_tiny.json",
+        "why": "test"})
+    bench["workloads"].append({
+        "name": "tiny_ssbflat.13q", "config": "ssb_flat_tiny",
+        "traffic": "ssb_flat_13q_c4", "chips": 1, "why": "test"})
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(bench))
+    result = _run("tiny_ssbflat.13q", 4_000_000_013, str(path))
+    numbers = result["compared"]
+    assert result["correct"] and result["failed"] == 0
+    assert result["attempted"] == numbers["answers_compared"]["value"] >= 13
+    assert not any(numbers[k]["value"] for k in (
+        "answers_wrong", "answers_missing", "off_device", "device_failures"))

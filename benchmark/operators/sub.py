@@ -1,0 +1,6 @@
+"""``sub``: the difference of two whole-number columns or constants, in int64
+(``harness/expr.py``)."""
+
+
+def apply(a, b):
+    return a - b

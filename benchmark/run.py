@@ -140,8 +140,16 @@ def stalls(records: list) -> str:
         by_client[r["client"]] = by_client.get(r["client"], 0) + 1
     slowest = max((r["t_done"] - r["t_send"] for r in records), default=0.0)
     gap = max((b - a for a, b in zip(done, done[1:])), default=0.0)
+    # the latencies' shape: a median that stands where few requests do
+    # swings from run to run with the mix of cohort widths (PERF.md, PR 31)
+    lat = [(r["t_done"] - r["t_send"]) * 1000 for r in records if r["ok"]]
+    deciles = [round(float(q), 1) for q in
+               np.percentile(lat, range(10, 100, 10))] if lat else []
+    # only the leader of a coalesced launch is charged deviceKernelMs
+    led = sum(bool(r.get("stats", {}).get("deviceKernelMs")) for r in records)
     return (f"by_client={[by_client[c] for c in sorted(by_client)]} "
-            f"slowest_ms={slowest * 1000:.0f} longest_gap_ms={gap * 1000:.0f}")
+            f"slowest_ms={slowest * 1000:.0f} longest_gap_ms={gap * 1000:.0f} "
+            f"latency_deciles_ms={deciles} launches_led={led}")
 
 
 def trace_middle(trace_dir: str, seconds: float) -> tuple:
@@ -219,13 +227,19 @@ def run(args, look_for_chip) -> dict:
         cluster.load(dirs, say)
 
         # warm-up: each statement once (uploads its columns, compiles), then
-        # rounds at the window's own concurrency until one builds nothing,
-        # so that every cohort shape the coalescer stacks is compiled
+        # callers sending together in twos, threes, .. (a cohort of every
+        # width the coalescer can stack, on purpose), then rounds at the
+        # window's own concurrency until one builds nothing
         loadgen.ask("url " + cluster.url)
         t0 = time.time()
         check_warm(loadgen.ask("once")["records"], "first pass", alone=True)
         say(f"warmup first_pass_seconds={time.time() - t0:.1f} "
             f"executables_built={compiles.built}")
+        before = compiles.built
+        burst = loadgen.ask("burst")["records"]
+        check_warm(burst, "burst")
+        say(f"warmup burst requests={len(burst)} "
+            f"executables_built={compiles.built - before}")
         for round_no in range(1, 6):
             before = compiles.built
             loadgen.ask("start")
