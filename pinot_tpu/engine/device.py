@@ -72,6 +72,37 @@ MAX_PRESENCE_CELLS = 1 << 24      # distinctcount (G, C) presence guard
 # min(num_groups_limit, this)); overflow falls back to the host path
 MAX_SORTED_GROUPS = 1 << 17
 SORTED_AGGS = ("count", "sum", "avg", "min", "max", "minmaxrange")
+# NARROWED key space: the regime between the dense table over the whole
+# cartesian product and the sort of every row. A group-by over two or
+# more columns whose product is large and whose filter leaves few keys
+# (SSB Q3.2: 250 cities x 250 cities x 7 years = 437,500 cells, 600 of
+# them live) finds, under the launch's mask, which 128-cell BLOCKS of
+# the key space hold a row (one count pass of the dense kernel over
+# cells / 128 groups), and sums into those blocks alone: the kernel's hi
+# one-hot compares against the table of live blocks, NARROW_BLOCKS rows
+# however large the key space is. The live cells leave as a keyed table
+# of NARROW_GROUPS entries, the sorted regime's output form, so the trim
+# sorts 4,096 entries and not the product (that sort is what took the
+# TPU's compiler five minutes at 437,500 cells). More live blocks or
+# cells than that is an overflow: counted, and answered by the host.
+# The floor is where the hi one-hot outgrows the rest of the kernel's
+# VPU work: a dense launch costs 2*128 + (2 + planes) * cells/128 lane
+# compares and multiplies a row (ops/groupby_mm.py _plan_lo), the two
+# narrowed passes 2*128 + 2 * cells/16,384 and 2*128 + (2 + planes) * the
+# live blocks in whole chunks of 32 (ops/pallas_scatter.py NARROW_CHUNK:
+# the kernel skips a chunk of the table that lists no block); with a
+# full table and three value planes they meet at 23,000 cells, with
+# one chunk at 11,000, with none and no plane at 34,000 and 22,000.
+NARROW_MIN_CELLS = 1 << 15
+NARROW_BLOCK = 128       # cells a block: the lo one-hot, one lane tile
+NARROW_BLOCKS = 128      # live blocks a launch keeps (hi one-hot rows)
+NARROW_GROUPS = 1 << 12  # live cells a launch hands on
+NARROW_AGGS = ("count", "sum", "avg")
+_COUNT_ONLY = (("count", None, None),)
+# what executor.dispatch / device_wait, the flight record and EXPLAIN
+# ANALYZE call a group-by's key space (``groupbyKeySpace``)
+KEY_SPACES = {"groupby": "dense", "groupby_narrow": "narrowed",
+              "groupby_sorted": "sorted"}
 
 log = logging.getLogger("pinot_tpu.engine.device")
 
@@ -347,7 +378,7 @@ def plan_prepared_groupby(template, widths, n_total: int, mm_mode: str,
 
 def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
                     widths=None, pallas_mode="off", prepared=None,
-                    mask=None):
+                    mask=None, narrow=None):
     """Route COUNT/SUM/AVG through ONE factored one-hot launch when
     eligible: the Pallas tiled local-accumulate scatter
     (ops/pallas_scatter.py plane_group_sums — group-range partitioned,
@@ -360,7 +391,13 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     ``prepared``: plan_prepared_groupby's plan — the kernel then reads
     the batch's operands out of ``cols`` as they are and the launch's
     ``mask``, relaid out to lanes at one byte a row; nothing else
-    row-scale is computed."""
+    row-scale is computed.
+
+    ``narrow``: ``(hi_table, slot_ids)`` of a narrowed key space
+    (``_aggregate_narrowed``). ``gid`` is then the cartesian id, masked
+    rows past the key space, and ``num_groups`` the slots of the
+    narrowed table: the Pallas kernel compares against ``hi_table``
+    itself, the matmul kernel takes ``slot_ids()``."""
     from pinot_tpu.ops import groupby_mm as mm
     from pinot_tpu.ops import pallas_scatter as ps
 
@@ -433,13 +470,21 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     # pad, relayout to lanes and the Pallas kernel itself
     # (pinot_scatter_sums / pinot_groupby_mm in the device trace)
     with jax.named_scope("pinot.groupby_kernel"):
-        if route == "pallas":
+        if route == "pallas" and narrow is not None:
+            sums = ps.plane_group_sums_narrow(
+                gid.reshape(-1), stacked, narrow[0],
+                interpret=(pallas_mode == "interpret"),
+                first_channel_ones=True,
+            )
+        elif route == "pallas":
             sums = ps.plane_group_sums(
                 gid.reshape(-1), stacked, num_groups,
                 interpret=(pallas_mode == "interpret"),
                 first_channel_ones=True,
             )
         else:
+            if narrow is not None:
+                gid = narrow[1]()
             sums = mm.group_sums(
                 gid.reshape(-1), stacked, num_groups,
                 interpret=(mm_mode == "interpret"), first_channel_ones=True,
@@ -513,11 +558,14 @@ def _template_uses_pallas(template, widths, fused: bool,
             name == "distinctcounthll"
             and ps.hll_supported(1 << extra, mmod.hll_nrho(extra))
             for name, _a, extra in agg_tpls)
-    if shape != "groupby":
+    if shape not in ("groupby", "groupby_narrow"):
         return False  # the sorted radix regime never consults the tier
     num_groups = 1
     for c in group_cards:
         num_groups *= c
+    if shape == "groupby_narrow":  # both passes: blocks, then slots
+        return ps.sums_supported(-(-num_groups // NARROW_BLOCK), 2) \
+            or ps.sums_supported(NARROW_BLOCKS * NARROW_BLOCK, 2)
     for name, argt, extra in agg_tpls:
         if name in ("count", "sum", "avg"):
             if ps.sums_supported(num_groups, 2):
@@ -826,6 +874,45 @@ def _unpack_outs(bufs: dict, layout) -> dict:
         else:
             outs[name] = buf[off:off + size].view(dt).reshape(shp)
     return outs
+
+
+def _then_join(resolve, threads):
+    """``resolve`` that also waits for ``threads``, with the attributes
+    the fetch phase reads off it (stamp, abandon, cohort, index)."""
+    def joined():
+        outs = resolve()
+        for thread in threads:
+            thread.join()
+        return outs
+
+    joined.__dict__.update(resolve.__dict__)
+    return joined
+
+
+def _table_len(template) -> int:
+    """Entries of a group-by template's table: the keyed table's length,
+    or the cartesian product of a dense one."""
+    if template[5]:
+        return template[5]
+    n = 1
+    for c in template[3]:
+        n *= c
+    return n
+
+
+def _narrow_outcome(outs: dict) -> dict:
+    """What a narrowed group-by's result says of its key space: the
+    cells of the blocks it kept (the fullest member's, for a cohort),
+    and ``overflow`` where a member's did not fit."""
+    live = outs.get("narrow_live")
+    if live is None:
+        return {}
+    live = int(np.max(live))
+    read = {"keySpaceLive": min(live, NARROW_BLOCKS) * NARROW_BLOCK}
+    if live > NARROW_BLOCKS \
+            or int(np.max(outs["n_groups_total"])) > NARROW_GROUPS:
+        read["groupbyKeySpace"] = "overflow"
+    return read
 
 
 # cols keys of the dense group-by's prepared kernel operands
@@ -1186,6 +1273,9 @@ def build_pipeline(template, mm_mode: str = "auto",
                         empty, _neutral_fill(f"{k}_max", col.dtype), col)
             return outs
 
+        if shape == "groupby_narrow":
+            return _aggregate_narrowed(cols, params, mask, outs)
+
         if shape == "groupby":
             # columns are already global ids: the group key IS the column
             per_col = [_ids_col(cols, c, widths) for c in group_cols]
@@ -1322,6 +1412,83 @@ def build_pipeline(template, mm_mode: str = "auto",
                 outs[f"{k}_v{suff}"] = vb
         return outs
 
+    def _aggregate_narrowed(cols, params, mask, outs):
+        """COUNT/SUM/AVG over a NARROWED key space (NARROW_MIN_CELLS has
+        the why). Pass 1 counts the mask's rows by 128-cell block of the
+        cartesian key space and ranks the blocks that hold any into
+        ``hi_table``; pass 2 is the dense kernel over those blocks alone
+        (``_try_mm_groupby(narrow=)``); the cells that hold a row leave
+        as the first ``sorted_k`` entries of a keyed table, ``skeys``
+        their cartesian ids in ascending order: the sorted regime's
+        output form, which the trim, the mesh combine and the unpack
+        already read. Every shape is static; under ``vmap`` each member
+        narrows by its own mask. More live blocks than NARROW_BLOCKS, or
+        more live cells than ``sorted_k``, and ``n_groups_total`` says
+        so by passing ``sorted_k``: the host answers (the executor
+        counts it), as for the sorted regime's overflow."""
+        shift = NARROW_BLOCK.bit_length() - 1
+        slots = NARROW_BLOCKS * NARROW_BLOCK
+        n_blocks = -(-num_groups // NARROW_BLOCK)
+        per_col = [_ids_col(cols, c, widths) for c in group_cols]
+        with jax.named_scope("pinot.narrow"):
+            # a masked row's id lies past the key space, in a block of
+            # its own: the count's overflow slot, and in no table
+            gid = agg_ops.group_ids_combine(
+                per_col, group_cards, mask, n_blocks * NARROW_BLOCK)
+            block = gid >> shift
+            seen = {}
+            _try_mm_groupby(_COUNT_ONLY, block, cols, params, n_blocks,
+                            mm_mode, seen, widths, pallas_mode=pallas_mode)
+            rows_in = seen["gcount"] if seen \
+                else agg_ops.group_count(block, n_blocks)
+            live = rows_in > 0
+            n_live = jnp.sum(live, dtype=jnp.int64)
+            hi_table = jnp.nonzero(live, size=NARROW_BLOCKS,
+                                   fill_value=-1)[0].astype(jnp.int32)
+
+        memo = []
+
+        def slot_ids():
+            """A row's slot in the (blocks, 128) table, ``slots`` where
+            its block is not kept: what the kernels that cannot compare
+            against the table (matmul tier, XLA scatter) group by."""
+            if not memo:
+                tab = jnp.where(hi_table < 0, n_blocks + 1, hi_table)
+                at = jnp.clip(jnp.searchsorted(tab, block), 0,
+                              NARROW_BLOCKS - 1).astype(jnp.int32)
+                memo.append(jnp.where(
+                    tab[at] == block,
+                    at * NARROW_BLOCK + (gid & (NARROW_BLOCK - 1)), slots))
+            return memo[0]
+
+        table = {}
+        done = _try_mm_groupby(
+            aggs, gid, cols, params, slots, mm_mode, table, widths,
+            pallas_mode=pallas_mode, narrow=(hi_table, slot_ids))
+        if "gcount" not in table:
+            table["gcount"] = agg_ops.group_count(slot_ids(), slots)
+        for i, (name, argt, extra) in enumerate(aggs):
+            if i not in done and name != "count":
+                v = _eval_expr(argt, cols, params, widths)
+                table[f"a{i}_sum"] = agg_ops.group_sum(
+                    slot_ids(), v, slots,
+                    _rows_per_block(v, _legacy_rpb(extra)))
+        with jax.named_scope("pinot.narrow_table"):
+            present = table["gcount"] > 0
+            n_present = jnp.sum(present, dtype=jnp.int64)
+            at = jnp.nonzero(present, size=sorted_k, fill_value=slots)[0]
+            kept = at < slots
+            at = jnp.minimum(at, slots - 1)
+            cell = hi_table[at >> shift].astype(jnp.int64) * NARROW_BLOCK \
+                + (at & (NARROW_BLOCK - 1))
+            outs["skeys"] = jnp.where(kept, cell, radix_ops.INT64_SENTINEL)
+            for k, v in table.items():
+                outs[k] = jnp.where(kept, v[at], jnp.zeros((), v.dtype))
+            outs["n_groups_total"] = jnp.where(
+                n_live > NARROW_BLOCKS, sorted_k + n_live, n_present)
+            outs["narrow_live"] = n_live
+        return outs
+
     return pipeline  # caller jits (single-device) or shard_maps (mesh)
 
 
@@ -1335,6 +1502,8 @@ class DeviceExecutor:
     # byte-aware cap: column blocks are materialized lazily, so the byte
     # check runs again as each in-flight launch drains (_release_launch)
     MAX_CACHED_BYTES = int(os.environ.get("PINOT_TPU_BATCH_CACHE_BYTES", 6 << 30))
+    # cohort widths built with a template's first launch (_prebuild_cohorts)
+    PREBUILD_WIDTHS = (2, 4)
 
     def __init__(self, mesh=None, mm_mode: str = "auto",
                  num_groups_limit: int = 100_000,
@@ -1350,6 +1519,11 @@ class DeviceExecutor:
         PINOT_TPU_PALLAS=0 and per-query SET usePallas=false force the
         XLA scatter path end to end."""
         self.mesh = mesh
+        # build a template's cohort programs with its first launch
+        # (_prebuild_cohorts); None: on a TPU, where a program takes
+        # seconds to build, and not elsewhere (asked at the first launch:
+        # constructing an executor does not touch the backend)
+        self.prebuild_cohorts = None
         self.mm_mode = mm_mode
         self.pallas_mode = pallas_mode
         self.num_groups_limit = max(1, num_groups_limit)
@@ -1419,6 +1593,10 @@ class DeviceExecutor:
         # per-launch preparation (BatchContext.groupby_operand)
         self.groupby_operand_launches = {
             "prepared": 0, "built": 0, "perLaunch": 0}
+        # launches of the narrowed key space, and queries whose live keys
+        # did not fit it (answered by the host)
+        self.groupby_narrowed_launches = 0
+        self.groupby_narrow_overflows = 0
         # device-error recovery (failure-domain hardening): per-(template,
         # batch) failure counts feed a quarantine circuit breaker — a
         # pipeline that keeps failing on device routes to the host path
@@ -1676,6 +1854,8 @@ class DeviceExecutor:
                 # dense group-by launches by their operands' origin
                 "groupby_operand_launches":
                     dict(self.groupby_operand_launches),
+                "groupby_narrowed_launches": self.groupby_narrowed_launches,
+                "groupby_narrow_overflows": self.groupby_narrow_overflows,
             }
         per_batch = [
             {
@@ -1962,6 +2142,10 @@ class DeviceExecutor:
                     self.fetch_leaves_total += len(bufs)
                 self.metrics.time_ms("deviceFetchMs", wait * 1e3)
                 outs = _unpack_outs(bufs, layout)
+                # what only the result can say of a narrowed key space
+                read = _narrow_outcome(outs)
+                stamp["attrs"].update(read)
+                wait_span.set(**read)
                 if flight is not None:
                     self._note_flight(flight, outs, fetched,
                                       _t_kernel - _t_get,
@@ -2046,8 +2230,8 @@ class DeviceExecutor:
                    "cacheHit": cache_hit}
             if gather_bytes:
                 rec["gatherBytes"] = gather_bytes
-            if flight.get("groupby_operands"):
-                rec["groupbyOperands"] = flight["groupby_operands"]
+            rec.update(flight.get("origin") or {})
+            rec.update(_narrow_outcome(outs))
             gbps = None
             if not cache_hit and kernel_s > 1e-9:
                 gbps = bytes_moved / kernel_s / 1e9
@@ -2364,6 +2548,9 @@ class DeviceExecutor:
                     raise DeviceUnsupported(
                         f"agg {a.name} not on the sorted group-by path")
             shape = "groupby_sorted"
+        elif len(group_cols) > 1 and total > NARROW_MIN_CELLS and all(
+                a.name in NARROW_AGGS for a in aggs):
+            shape = "groupby_narrow"
         for name, argt, extra in agg_tpls:
             if shape == "groupby" and name in (
                     "distinctcount", "distinctcounthll", "hllmerge"):
@@ -2372,8 +2559,10 @@ class DeviceExecutor:
                     cells *= c
                 if cells > MAX_PRESENCE_CELLS:
                     raise DeviceUnsupported(f"{name} per-group state too large ({cells})")
-        sorted_k = min(self.num_groups_limit, MAX_SORTED_GROUPS) \
-            if shape == "groupby_sorted" else 0
+        # the keyed table's length (0: the table is the key space itself)
+        sorted_k = {"groupby_sorted": min(self.num_groups_limit,
+                                          MAX_SORTED_GROUPS),
+                    "groupby_narrow": NARROW_GROUPS}.get(shape, 0)
         # final only changes sketch outputs; don't fork the jit cache for
         # templates where it is a no-op
         final = final and any(
@@ -2518,8 +2707,7 @@ class DeviceExecutor:
         # pipeline entry; the exact keep count rides as the tr_k param.
         trim = None
         adv_trim_keep = None
-        if reduce_mode is not None and shape in ("groupby",
-                                                 "groupby_sorted"):
+        if reduce_mode is not None and shape in KEY_SPACES:
             # advisor: group_trim_size tightened toward the template's
             # observed group count (trim_bound still floors the keep at
             # the reference's 5*(offset+limit), so parity semantics
@@ -2579,6 +2767,10 @@ class DeviceExecutor:
         # say of it: prepared | built (this launch built them) | perLaunch
         gb_operands = None if shape != "groupby" else \
             "perLaunch" if prepared is None else "prepared"
+        # and of its key space: dense | narrowed | sorted (| overflow,
+        # which only the result can say: _make_resolve)
+        key_space = {"groupbyKeySpace": KEY_SPACES[shape],
+                     "keySpaceCells": total} if shape in KEY_SPACES else {}
 
         pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode,
                                   prepared)
@@ -2673,11 +2865,16 @@ class DeviceExecutor:
                         gb_operands = "built"
                 else:
                     cols[c] = ctx.column(c)
+        origin = dict(key_space)
         if gb_operands is not None:
+            origin["groupbyOperands"] = gb_operands
             with self._lock:
                 self.groupby_operand_launches[gb_operands] += 1
-            if flight is not None:
-                flight["groupby_operands"] = gb_operands
+        if shape == "groupby_narrow":
+            with self._lock:
+                self.groupby_narrowed_launches += 1
+        if flight is not None:
+            flight["origin"] = origin
         if os.environ.get("PINOT_TPU_WIDTH_AUDIT", "") not in ("", "0"):
             _width_audit(ctx, cols, widths)
 
@@ -2732,7 +2929,7 @@ class DeviceExecutor:
         resolve = self._dispatch(
             entry, batch_key, cols, n_docs, params, lkey, layout, tracer,
             cache_key, flight, adv_key=adv_key, adv_notes=adv_notes,
-            gb_operands=gb_operands)
+            origin=origin)
         handle = InflightLaunch(self, q, ctx, template, aggs, batch_key,
                                 resolve)
         handle.flight = flight
@@ -2802,11 +2999,13 @@ class DeviceExecutor:
             # for every member. Cohorts therefore ride the DENSE form;
             # per-member ps_alive still applies Level-1 segment pruning
             # inside the vmap, so members pruning different segment
-            # subsets stay correct.
-            raw_cohort = build_pipeline(
-                template, self.mm_mode, sorted_hll_ok=(self.mesh is None),
-                widths=widths, pallas_mode=pallas, prepared=prepared,
-            ) if blockskip else raw
+            # subsets stay correct. The dense form is the template's
+            # entry without block skip, which also holds the cohort's
+            # programs: the advisor's switch from the block-skip form to
+            # the dense one (advise_blockskip) then builds nothing new.
+            dense = self._pipeline_entry(
+                template, agg_tpls, final, False, widths, wsig, trim,
+                pallas, prepared) if blockskip else None
             if self.mesh is not None:
                 from pinot_tpu.parallel.mesh import shard_pipeline
 
@@ -2831,17 +3030,19 @@ class DeviceExecutor:
 
             pipeline = jax.jit(pinot_pipeline)
             entry = {
-                "pipeline": pipeline, "inner": inner, "raw": raw_cohort,
+                "pipeline": pipeline, "inner": inner, "raw": raw,
                 "agg_tpls": agg_tpls, "final": final,
                 "template": template, "trim": trim, "pallas": pallas,
                 "layouts": {}, "cohort": None, "cohort_layouts": {},
+                "prebuilt": set(),  # batch shapes whose cohorts are built
+                "dense": dense,     # a block-skip entry's dense twin
             }
             self._pipelines[pkey] = entry
             return entry
 
     def _dispatch(self, entry, batch_key, cols, n_docs, params, lkey, layout,
                   tracer=None, cache_key=None, flight=None, adv_key=None,
-                  adv_notes=None, gb_operands=None):
+                  adv_notes=None, origin=None):
         """Dispatch one query: through the coalescer when concurrency makes
         a cohort partner likely, else solo. Returns the resolve() closure
         the InflightLaunch fetch phase blocks on. Coalescing is disabled
@@ -2851,10 +3052,21 @@ class DeviceExecutor:
         leader's window wait (``executor.launch_wait``), its ``stack``
         and ``dispatch``; a solo launch's ``dispatch``. A member's join
         returns at once — it records its waits in its fetch phase
-        (InflightLaunch._traced_resolve). ``gb_operands``: where a dense
-        group-by's kernel operands came from (``groupbyOperands`` on the
-        dispatch and device_wait spans; the leader's, for a cohort)."""
+        (InflightLaunch._traced_resolve). ``origin``: what the dispatch
+        and device_wait spans say of a group-by (the leader's, for a
+        cohort): ``groupbyOperands``, where a dense group-by's kernel
+        operands came from; ``groupbyKeySpace`` and ``keySpaceCells``."""
         co = self.coalescer
+        if co is not None and not self.profile_enabled:
+            builders = self._prebuild_cohorts(entry, cols, n_docs, params,
+                                              lkey)
+            if builders:
+                # the template's first answer waits for its cohorts: after
+                # it no launch of the template builds a program
+                return _then_join(self._dispatch(
+                    entry, batch_key, cols, n_docs, params, lkey, layout,
+                    tracer, cache_key, flight, adv_key, adv_notes, origin),
+                    builders)
         if (co is not None and not self.profile_enabled
                 and co.should_window(self.inflight)):
             # cohort key: same pipeline entry + same batch + same column
@@ -2887,7 +3099,7 @@ class DeviceExecutor:
                     self.advisor.observe(_ak, cohort=len(members))
                 return self._cohort_launch(
                     entry, cols, n_docs, members, lkey, tracer, flight,
-                    gb_operands)
+                    origin)
 
             window.__enter__()
             try:
@@ -2906,10 +3118,65 @@ class DeviceExecutor:
             resolve.cohort, resolve.index = cohort, idx
             return resolve
         return self._solo_launch(entry, cols, n_docs, params, layout, tracer,
-                                 cache_key, flight, gb_operands)
+                                 cache_key, flight, origin)
+
+    def _prebuild_cohorts(self, entry, cols, n_docs, params, lkey):
+        """With a template's first launch on a batch shape, build the
+        other programs it will meet: its cohorts (the statement is
+        launched PREBUILD_WIDTHS times over in one cohort) and, for a
+        block-skip entry, its dense twin — each on a thread of its own,
+        beside the solo program's build, so that the first answer takes
+        the slowest build and not their sum. A cohort forms when callers
+        send one template together, which the first requests for it seldom
+        do, and the advisor switches a template to the dense form at its
+        fourth launch: either, first met under load, stalled its callers
+        for the seconds the program takes to build (PERF.md, PR 31 and
+        PR 32). Returns the building threads; none where built or being
+        built."""
+        on = self.prebuild_cohorts
+        if on is None:
+            on = self.prebuild_cohorts = jax.default_backend() == "tpu"
+        if not on:
+            return []
+        dense = entry["dense"]
+        with self._lock:
+            if lkey in entry["prebuilt"]:
+                return []
+            entry["prebuilt"].add(lkey)
+            if dense is not None:
+                dense["prebuilt"].add(lkey)
+
+        def dense_twin():
+            # the program the advisor switches the template to once it
+            # has seen that block skip prunes nothing
+            jax.block_until_ready(dense["pipeline"](
+                {k: v for k, v in cols.items()
+                 if not k.startswith((bs_ops.ZLO, bs_ops.ZHI))},
+                n_docs, params))
+
+        def cohort_of(width):
+            return lambda: self._cohort_launch(
+                entry, cols, n_docs, [params] * width, lkey)()
+
+        def build(program):
+            try:
+                program()
+            except Exception:  # noqa: BLE001 — its first launch builds it
+                log.exception("prebuild of a template's programs failed")
+
+        programs = [dense_twin] if dense is not None else []
+        programs += [cohort_of(w) for w in self.PREBUILD_WIDTHS
+                     if w <= self.coalescer.max_cohort]
+        builders = [threading.Thread(target=build, args=(program,),
+                                     daemon=True,
+                                     name="pinot-cohort-prebuild")
+                    for program in programs]
+        for builder in builders:
+            builder.start()
+        return builders
 
     def _solo_launch(self, entry, cols, n_docs, params, layout, tracer=None,
-                     cache_key=None, flight=None, gb_operands=None):
+                     cache_key=None, flight=None, origin=None):
         pipeline = entry["pipeline"]
         if self.profile_enabled:
             with self._lock:
@@ -2919,7 +3186,7 @@ class DeviceExecutor:
                         * v.dtype.itemsize for v in cols.values()),
                 )
         launch_id = next(self._launch_ids)
-        origin = {"groupbyOperands": gb_operands} if gb_operands else {}
+        origin = origin or {}
         dispatch = trace_span("executor.dispatch", tracer)
         dispatch.set(launchId=launch_id, **origin)
         with dispatch:
@@ -2936,7 +3203,7 @@ class DeviceExecutor:
                    "cohortPadded": 1, **origin})
 
     def _cohort_launch(self, entry, cols, n_docs, members, lkey, tracer=None,
-                       flight=None, gb_operands=None):
+                       flight=None, origin=None):
         """Leader side of a coalesced cohort: stack every member's params
         along a leading axis and dispatch ONE vmapped launch; the shared
         resolve() fetches ONE packed buffer for the whole cohort (each
@@ -2947,8 +3214,7 @@ class DeviceExecutor:
             # whole extra compile of the template for nothing
             layout = entry["layouts"][lkey]
             base = self._solo_launch(entry, cols, n_docs, members[0], layout,
-                                     tracer, flight=flight,
-                                     gb_operands=gb_operands)
+                                     tracer, flight=flight, origin=origin)
 
             def alone():
                 return {k: v[None] for k, v in base().items()}
@@ -2957,6 +3223,11 @@ class DeviceExecutor:
             return alone
         launch_id = next(self._launch_ids)
         pipeline_v, inner_v = self._cohort_pipeline(entry)
+        # the dense form reads no zone map: a block-skip entry's cohort and
+        # its dense twin's are one program over the same operands
+        entry = entry["dense"] or entry
+        cols = {k: v for k, v in cols.items()
+                if not k.startswith((bs_ops.ZLO, bs_ops.ZHI))}
         # pad the cohort to the next power of two (repeating the last
         # member's params): jit re-specializes per stack size, and ragged
         # cohort sizes under churn would compile up to max_cohort variants
@@ -2981,7 +3252,7 @@ class DeviceExecutor:
                     jax.eval_shape(inner_v, cols, n_docs, pstack))
                 with self._lock:
                     entry["cohort_layouts"][ck] = layout
-        origin = {"groupbyOperands": gb_operands} if gb_operands else {}
+        origin = origin or {}
         dispatch = trace_span("executor.dispatch", tracer)
         dispatch.set(launchId=launch_id, **origin)
         with dispatch:
@@ -2998,6 +3269,7 @@ class DeviceExecutor:
         vmaps pipeline + combine (+ finalize) per member —
         parallel/mesh.py shard_pipeline(cohort=True). jit re-specializes
         per cohort size; the coalescer's max_cohort bounds that."""
+        entry = entry["dense"] or entry
         with self._lock:
             cached = entry["cohort"]
         if cached is not None:
@@ -3016,8 +3288,15 @@ class DeviceExecutor:
 
             inner_v = shard_pipeline(raw, self.mesh, cohort=True, post=post)
         else:
+            # a trim over thousands of entries runs member by member: the
+            # TPU's compiler takes minutes over the batched form of its
+            # multi-operand 64-bit sort (two members of 4,096 entries:
+            # 398 s where one member takes 7, compiled for a described
+            # v5e), and the loop's body is the one-member sort
+            by_member = post is not None and entry["trim"] is not None \
+                and _table_len(entry["template"]) > dr_ops.VMAP_SORT_MAX
             one = raw
-            if post is not None:
+            if post is not None and not by_member:
                 def one(cols, n_docs, p, _raw=raw, _post=post):
                     return _post(_raw(cols, n_docs, p), p)
 
@@ -3025,7 +3304,10 @@ class DeviceExecutor:
                 def pinot_cohort_member(p):
                     return _one(cols, n_docs, p)
 
-                return jax.vmap(pinot_cohort_member)(pstack)
+                outs = jax.vmap(pinot_cohort_member)(pstack)
+                if by_member:
+                    outs = jax.lax.map(lambda op: post(*op), (outs, pstack))
+                return outs
 
         def pinot_cohort_pipeline(cols, n_docs, pstack):
             return _pack_outs(inner_v(cols, n_docs, pstack))
@@ -3099,13 +3381,15 @@ class DeviceExecutor:
             ]
             return IntermediateResult("aggregation", agg_partials=partials, stats=stats)
 
-        if shape == "groupby_sorted" and \
-                int(outs["n_groups_total"]) > sorted_k:
+        if sorted_k and int(outs["n_groups_total"]) > sorted_k:
             # the capped table dropped groups: re-run on the host so device
             # truncation policy never shapes results (host applies its own
             # numGroupsLimit semantics)
+            if shape == "groupby_narrow":
+                with self._lock:
+                    self.groupby_narrow_overflows += 1
             raise DeviceUnsupported(
-                f"sorted group table overflow "
+                f"{KEY_SPACES[shape]} group table overflow "
                 f"({int(outs['n_groups_total'])} > {sorted_k})")
         opts = q.options_ci()
         # numGroupsLimit applies on the device path too (engine default or
@@ -3127,7 +3411,7 @@ class DeviceExecutor:
                 and not cache_hit:
             if trimmed:
                 obs_groups = int(outs["n_present_total"])
-            elif shape == "groupby_sorted":
+            elif sorted_k:
                 obs_groups = int(outs["n_groups_total"])
             else:
                 obs_groups = int((np.asarray(outs["gcount"]) > 0).sum())
@@ -3155,7 +3439,7 @@ class DeviceExecutor:
             # decode the combined key (dense: the gid itself; sorted: the
             # int64 key recorded per table slot) → per-column global ids
             # → values
-            if shape == "groupby_sorted":
+            if sorted_k:
                 rem = outs["skeys"][present].astype(np.int64)
             else:
                 rem = present.copy()

@@ -179,8 +179,10 @@ def _kernel_line(rec: dict) -> str:
     else:
         perf = f"{gbps} GB/s"
     # a dense group-by says where its kernel's operands came from
-    operands = f", groupbyOperands={rec['groupbyOperands']}" \
-        if rec.get("groupbyOperands") else ""
+    operands = "".join(
+        f", {k}={rec[k]}" for k in ("groupbyOperands", "groupbyKeySpace",
+                                    "keySpaceCells", "keySpaceLive")
+        if rec.get(k))
     return (f"    KERNEL({label}{where}: {perf}, "
             f"bytes={rec.get('bytesMoved')}, "
             f"kernelMs={rec.get('kernelMs')}, linkMs={rec.get('linkMs')}"

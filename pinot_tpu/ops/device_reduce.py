@@ -67,8 +67,12 @@ from pinot_tpu.ops.join import next_pow2
 # vectors, not group-table columns)
 STAT_KEYS = frozenset((
     "doc_count", "seg_matched", "n_alive", "rows_filter",
-    "blocks_total", "blocks_scanned", "n_groups_total",
+    "blocks_total", "blocks_scanned", "n_groups_total", "narrow_live",
 ))
+
+# the longest table whose trim a cohort applies under vmap; longer ones
+# are trimmed member by member (engine/device.py _cohort_pipeline)
+VMAP_SORT_MAX = 2048
 
 # aggregations whose finalized value the device can order by; the field
 # names the finalize produces (engine/aggspec.py → engine/reduce.py env)
@@ -131,7 +135,7 @@ def plan_trim(q, group_exprs, aggs, shape: str, table_len: int,
     """
     if mode not in ("terminal", "partial"):
         return None
-    if shape not in ("groupby", "groupby_sorted"):
+    if shape not in ("groupby", "groupby_sorted", "groupby_narrow"):
         return None
     if q.distinct or q.having is not None:
         return None
@@ -210,7 +214,7 @@ def apply_trim(outs: dict, params: dict, template, spec) -> dict:
     G = gcount.shape[0]
     present = gcount > 0
     n_present = jnp.sum(present, dtype=jnp.int64)
-    if shape == "groupby_sorted":
+    if "skeys" in outs:  # a keyed table: the sorted and narrowed regimes
         keys64 = outs["skeys"].astype(jnp.int64)
     else:
         keys64 = jnp.arange(G, dtype=jnp.int64)

@@ -83,6 +83,7 @@ from pinot_tpu.ops.groupby_mm import (  # noqa: F401 — re-exported budgets
 )
 
 LO = 128                 # low-radix factor: the dot's N dim = one lane tile
+NARROW_CHUNK = 32        # hi-table rows the narrowed kernel takes at a time
 MAX_PARTITIONS = 8       # row re-reads per launch: npart passes over the
                          # tile stream bound the bandwidth trade
 PALLAS_MIN_ROWS = 1 << 17  # below this the scatter's fixed cost wins (the
@@ -167,6 +168,55 @@ def _rel_onehots(ids_r, p, gp: int, hpad: int, blk: int):
     return oh_loT, oh_hi
 
 
+def _narrow_kernel(ids_ref, tab_ref, ch_ref, out_ref, acc_ref, *, ninner: int,
+                   hpad: int, a_real: int, blk: int, ones_first: bool):
+    """The plane-sum kernel over a NARROWED key space, one row tile: hi row
+    ``h`` matches the rows whose 128-cell block (``id >> 7``) is
+    ``tab_ref[h]``, so the accumulator holds only the blocks the table
+    lists. A block that is not listed (a masked row's sentinel, a pad row)
+    matches no row. The table is ascending with -1 past its last block
+    and is taken NARROW_CHUNK rows at a time: a chunk that lists no block
+    is skipped, so the tile costs what the live blocks cost and not what
+    the table could hold (3 of 4 chunks skipped for SSB Q3.2's 15 live
+    blocks). Channels are stacked into one dot a chunk, as the dense
+    form stacks them."""
+    i = pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    ids_r = ids_ref[:].reshape(1, blk)
+    lo_r = ids_r & _i32(LO - 1)
+    hi_r = ids_r >> _i32(7)  # LO = 128
+    jsub = jax.lax.broadcasted_iota(jnp.int32, (LO, blk), 0)
+    oh_loT = jnp.where(lo_r == jsub, jnp.float32(1), jnp.float32(0)) \
+        .astype(jnp.bfloat16)
+    hc = NARROW_CHUNK
+    for c in range(hpad // hc):
+        rows = pl.ds(c * hc, hc)
+        # the table arrives lane-replicated, (hpad, 128)
+        tab_c = tab_ref[rows, :]
+
+        @pl.when(jnp.max(tab_c.astype(jnp.float32)) >= jnp.float32(0))
+        def _(rows=rows, tab_c=tab_c):
+            # tile the chunk over the row block's lanes
+            tab = pltpu.repeat(tab_c, blk // 128, axis=1)
+            oh_hi = jnp.where(hi_r == tab, jnp.float32(1), jnp.float32(0)) \
+                .astype(jnp.bfloat16)
+            chh_all = jnp.concatenate(
+                [oh_hi if a == 0 and ones_first
+                 else oh_hi * ch_ref[pl.ds(a, 1), :]
+                 for a in range(a_real)], axis=0)
+            acc_ref[:, rows, :] += jax.lax.dot_general(
+                chh_all, oh_loT, _NT, preferred_element_type=jnp.float32
+            ).reshape(a_real, hc, LO)
+
+    @pl.when(i == ninner - 1)
+    def _():
+        out_ref[0] = acc_ref[:]
+
+
 # ---------------------------------------------------------------------------
 # 1) tiled local-accumulate group scatter (sums / counts)
 # ---------------------------------------------------------------------------
@@ -243,16 +293,22 @@ def _lane_spec(blk: int, ninner: int):
 
 def _sums_call(ids_lane, operands, operand_specs, num_groups: int,
                a_real: int, plan, *, interpret: bool, ones_first: bool,
-               prepared=None):
+               prepared=None, narrow: bool = False):
     """The one ``pallas_call`` of the plane-sum kernel, over per-launch
     operands (masked ids + stacked bf16 channels) or prepared ones (ids,
     mask, uint8 lane planes). Returns (A, num_groups) float64."""
     hp, npart, blk, ninner, stacked = plan
     gp = hp * LO
     nsuper = ids_lane.shape[0] * 128 // SUPERBLOCK
-    kern = functools.partial(
-        _sums_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
-        gp=gp, stacked=stacked, ones_first=ones_first, prepared=prepared)
+    if narrow:
+        kern = functools.partial(
+            _narrow_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
+            ones_first=ones_first)
+    else:
+        kern = functools.partial(
+            _sums_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
+            gp=gp, stacked=stacked, ones_first=ones_first,
+            prepared=prepared)
     out = pl.pallas_call(
         kern,
         grid=(npart, nsuper, ninner),
@@ -329,6 +385,41 @@ def plane_group_sums_prepared(ids_lane, mask_lane, planes, num_groups: int,
         [_lane_spec(blk, ninner), *plane_specs], num_groups, a_real, plan,
         interpret=interpret, ones_first=True,
         prepared=(num_groups, tuple(p.shape[0] for p in planes)))
+
+
+def plane_group_sums_narrow(gid, channels, hi_table, *,
+                            interpret: bool = False,
+                            first_channel_ones: bool = False):
+    """``plane_group_sums`` over a NARROWED key space (engine/device.py
+    "groupby_narrow"): ``hi_table`` (H,) int32 lists the live 128-cell
+    blocks (``gid >> 7``) of a large, sparsely filled key space, -1 past
+    the last; the accumulator holds those H blocks and nothing else, so
+    the hi one-hot is H rows however large the key space is. A row whose
+    block the table does not list adds nothing: masked rows carry an id
+    past the key space. Returns (A, H * 128) float64; row ``h * 128 + j``
+    is the cell ``hi_table[h] * 128 + j``. Same planes, same f32
+    superblock partials, same f64 reduction as the dense form."""
+    a_real, n = channels.shape
+    hp = hi_table.shape[0]
+    assert hp % NARROW_CHUNK == 0, hp
+    blk, ninner, stacked = _plan_blk(a_real, hp)
+    n_pad = ((n + SUPERBLOCK - 1) // SUPERBLOCK) * SUPERBLOCK
+    # a pad row's block (2^24 - 1) is in no table
+    ids_lane = _pad_lane(gid.astype(jnp.int32), n_pad, n,
+                         jnp.iinfo(jnp.int32).max)
+    ch = jnp.concatenate(
+        [channels, jnp.zeros((a_real, n_pad - n), channels.dtype)], axis=1
+    ) if n_pad > n else channels
+    tab = jnp.broadcast_to(hi_table.astype(jnp.int32)[:, None], (hp, 128))
+    tab_spec = pl.BlockSpec((hp, 128), lambda p, s, i: (_i32(0), _i32(0)),
+                            memory_space=pltpu.VMEM)
+    ch_spec = pl.BlockSpec((a_real, blk),
+                           lambda p, s, i: (_i32(0), s * ninner + i),
+                           memory_space=pltpu.VMEM)
+    return _sums_call(ids_lane, (tab, ch), [tab_spec, ch_spec], hp * LO,
+                      a_real, (hp, 1, blk, ninner, stacked),
+                      interpret=interpret, ones_first=first_channel_ones,
+                      narrow=True)
 
 
 # ---------------------------------------------------------------------------

@@ -105,6 +105,8 @@ def _combine_sorted_table(outs: dict) -> dict:
     for k in ("n_alive", "rows_filter", "blocks_total", "blocks_scanned"):
         if k in outs:
             combined[k] = jax.lax.psum(outs[k], SEG_AXIS)
+    if "narrow_live" in outs:  # the fullest shard's live blocks
+        combined["narrow_live"] = jax.lax.pmax(outs["narrow_live"], SEG_AXIS)
     combined.update(merged)
     return combined
 

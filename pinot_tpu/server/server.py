@@ -273,6 +273,14 @@ class ServerInstance:
                 self._register_gauge(
                     gname, (lambda _o=origin, _d=dev:
                             _d.groupby_operand_launches[_o]))
+            # the narrowed key space: its launches, and the queries whose
+            # live keys did not fit it (the host answered)
+            for gname, attr in (
+                    ("deviceGroupbyNarrowed", "groupby_narrowed_launches"),
+                    ("deviceGroupbyNarrowOverflow",
+                     "groupby_narrow_overflows")):
+                self._register_gauge(
+                    gname, (lambda _a=attr, _d=dev: getattr(_d, _a)))
             self._register_gauge(
                 "deviceResidentBytes",
                 (lambda _d=dev: _d.resident_bytes()))

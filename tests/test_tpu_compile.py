@@ -297,3 +297,108 @@ def test_prepared_operand_builders_real_batch(spec, name):
             lambda c: mm.prepared_planes(c, delta=-100, nplanes=3),
             spec(BATCH, "int32"))
     _assert_split_relayout(compiled.as_text(), 1)
+
+
+# ---- the narrowed key space (ISSUE 32): SSB Q3.2 and Q4.3, flat -------------
+
+# Q3.2 and Q4.3 of benchmark/traffic/ssb_flat_13q_c4.json as the executor
+# plans them on ssb_sf100_chipshare's batch (captured from a served run at
+# the tiny size; the cardinalities are SF100's)
+FLAT_BATCH = (3, BATCH[1])     # 3 segments of 12,500,000 rows, padded
+FLAT_NB = NB
+NARROWED = {
+    "q3_2": dict(
+        template=(
+            "groupby_narrow",
+            ("and", ("eq_dict", "c_nation", "pr0"),
+             ("eq_dict", "s_nation", "pr1"),
+             ("range_dict", "d_year", "pr2", "pr3")),
+            ("c_city", "s_city", "d_year"), (250, 250, 7),
+            (("sum", ("raw", "lo_revenue"), (3, None)),), 4096, False),
+        trim=(1024, (("col", 2, True), ("agg", 0, "sum", False))),
+        widths={"c_city": ("|u1", 0, False, ""),
+                "c_nation": ("|u1", 0, False, ""),
+                "d_year": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "s_city": ("|u1", 0, False, ""),
+                "s_nation": ("|u1", 0, False, "")},
+        zones=("c_nation", "d_year", "s_nation"),
+        params={"pr0": ((), "int32"), "pr1": ((), "int32"),
+                "pr2": ((), "int32"), "pr3": ((), "int32")}),
+    "q4_3": dict(
+        template=(
+            "groupby_narrow",
+            ("and", ("eq_dict", "c_region", "pr0"),
+             ("eq_dict", "s_nation", "pr1"), ("in_dict", "d_year", "pr2", 2),
+             ("eq_dict", "p_category", "pr3")),
+            ("d_year", "s_city", "p_brand1"), (7, 250, 1000),
+            (("sum", ("minus", ("raw", "lo_revenue"),
+                      ("raw", "lo_supplycost")), (3, None)),), 4096, False),
+        trim=(1024, (("col", 0, True), ("col", 1, True), ("col", 2, True))),
+        widths={"c_region": ("|u1", 0, False, ""),
+                "d_year": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "lo_supplycost": ("<u2", 0, True, "<i4"),
+                "p_brand1": ("<u2", 0, False, ""),
+                "p_category": ("|u1", 0, False, ""),
+                "s_city": ("|u1", 0, False, ""),
+                "s_nation": ("|u1", 0, False, "")},
+        zones=("c_region", "d_year", "p_category", "s_nation"),
+        params={"pr0": ((), "int32"), "pr1": ((), "int32"),
+                "pr2": ((2,), "int32"), "pr3": ((), "int32"),
+                "fo::lo_supplycost": ((), "int32")}),
+}
+# the first answer of these took the chip's host 304 to 366 s (PR 31): the
+# trim's sort over the whole cartesian table (437,500 and 1,750,000 cells;
+# compiled for the described v5e in this sandbox: 32 s at 7,000 entries,
+# 160 s at 16,384, 22 minutes at 437,500). The narrowed table is 4,096
+# entries: either program compiles here in 18 to 37 s beside the driver's
+# five other test workers. The limit is three times that, and leaves no
+# room for the sort's return.
+NARROWED_COMPILE_LIMIT_S = 120
+
+
+@pytest.mark.parametrize("width", [1, 2])
+@pytest.mark.parametrize("name", list(NARROWED))
+def test_pipeline_narrowed_groupby_real_batch(spec, name, width):
+    """The executor's own programs: solo as it launches it (the block-skip
+    form, its dense branch under lax.cond, then trim and pack) and the
+    cohort of two (the dense form under vmap, trimmed member by member:
+    the batched form of the trim's sort took this compiler 398 s at 4,096
+    entries). Two kernel calls a branch (blocks, then slots), no table of
+    the cartesian product, and a compile inside the limit."""
+    import time
+
+    case = NARROWED[name]
+    template, widths = case["template"], case["widths"]
+    executor = dev.DeviceExecutor(mm_mode="tpu", pallas_mode="tpu")
+    entry = executor._pipeline_entry(
+        template, template[4], False, True, widths,
+        tuple(sorted(widths.items())), case["trim"], "tpu", None)
+    cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
+    n_seg = FLAT_BATCH[0]
+    params = {"off0": spec((), "int64"), "ps_alive": spec((n_seg,), "bool"),
+              "tr_k": spec((), "int32"),
+              **{k: spec(*v) for k, v in case["params"].items()}}
+    if width == 1:
+        fn = entry["pipeline"]
+        for k in case["zones"]:
+            cols["zlo::" + k] = spec((n_seg, FLAT_NB), widths[k][0])
+            cols["zhi::" + k] = spec((n_seg, FLAT_NB), widths[k][0])
+    else:
+        fn = executor._cohort_pipeline(entry)[0]
+        params = {k: spec((width,) + v.shape, v.dtype)
+                  for k, v in params.items()}
+        params["__member__"] = spec((width,), "int32")
+    t0 = time.perf_counter()
+    compiled = fn.lower(cols, spec((n_seg,), "int32"), params).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (4 if width == 1 else 2)
+    cells = 1
+    for c in template[3]:
+        cells *= c
+    assert f"[{cells}]" not in text and f"[{cells + 1}]" not in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 << 30, mem
+    assert seconds < NARROWED_COMPILE_LIMIT_S, seconds
