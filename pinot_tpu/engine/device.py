@@ -19,6 +19,7 @@ merge: groups are already aligned across segments when the scatter lands.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import logging
@@ -330,15 +331,42 @@ def _sum_route(num_groups: int, total_ch: int, n_total: int, mm_mode: str,
     return None
 
 
+# functions that keep whole numbers whole, and whose range expr_bounds
+# (engine/params.py) knows from the columns' metadata
+_INT_CLOSED = ("plus", "minus", "times", "abs")
+
+
+def _column_expr_key(argt, widths):
+    """Canonical form of a SUM/AVG argument whose value depends on the
+    batch alone: a stored integer column (its cols key, as ever) or an
+    expression with such columns as its only leaves. None for anything a
+    launch has a say in (a literal is a launch parameter) or that may
+    leave the integers (float columns, casts, division)."""
+    from pinot_tpu.ops.pallas_scatter import _direct_colkey
+
+    ck = _direct_colkey(argt)
+    if ck:
+        w = _col_width(widths, ck)
+        return ck if w is not None \
+            and np.dtype(w[3] or w[0]).kind in "iu" else None
+    if not isinstance(argt, tuple) or argt[0] not in _INT_CLOSED:
+        return None
+    leaves = [_column_expr_key(a, widths) for a in argt[1:]]
+    return None if None in leaves else f"{argt[0]}({','.join(leaves)})"
+
+
 def plan_prepared_groupby(template, widths, n_total: int, mm_mode: str,
                           pallas_mode: str, offsets: dict):
-    """Template-build plan of the PREPARED operand form of a dense
-    group-by (ops/groupby_mm.py "prepared operands"): ``(route, ids cols
-    key, ((agg index, planes cols key, nplanes), ...))``, or None where
-    the per-launch preparation has to run. It engages on what the
-    template shows: one group column, every SUM/AVG argument a bare
-    integer column with a plane count known from metadata, a kernel route
-    chosen, and a row tile that holds whole tiles of the 8-bit operands.
+    """Template-build plan of the PREPARED operand form of a dense or
+    narrowed group-by (ops/groupby_mm.py "prepared operands"): ``(route,
+    (ids cols key a group column, ...), ((agg index, planes cols key,
+    nplanes), ...))``, or None where the per-launch preparation has to
+    run. It engages on what the template shows: every SUM/AVG argument
+    an integer column or a column-only integer expression
+    (``_column_expr_key``) with a plane count known from metadata, a
+    kernel route chosen (several group columns and the narrowed form:
+    the Pallas route, whose kernel combines the ids), and row tiles that
+    hold whole tiles of the 8-bit operands.
     ``offsets``: {agg index: the python int behind its ``off{i}`` param}.
     The byte budget and the mesh are the executor's to check."""
     from pinot_tpu.ops import groupby_mm as mm
@@ -346,39 +374,99 @@ def plan_prepared_groupby(template, widths, n_total: int, mm_mode: str,
 
     shape, _ft, group_cols, group_cards, aggs, _sk, _final = template
     mm_mode = _resolve_mm_mode(mm_mode)
-    if shape != "groupby" or len(group_cols) != 1 \
+    if shape not in ("groupby", "groupby_narrow") or not group_cols \
             or (mm_mode == "off" and pallas_mode == "off"):
         return None
-    num_groups = group_cards[0]
+    num_groups = 1
+    for c in group_cards:
+        num_groups *= c
     planes = []
     total_ch = 1  # the count channel
     for i, (name, argt, extra) in enumerate(aggs):
         if name not in ("sum", "avg") or not isinstance(extra, tuple):
             continue
-        ck = ps._direct_colkey(argt)
-        w = _col_width(widths, ck) if ck else None
-        if w is None or np.dtype(w[3] or w[0]).kind not in "iu":
-            return None  # an expression or float argument: per launch
+        ek = _column_expr_key(argt, widths)
+        if ek is None:
+            return None  # a launch parameter or a float in it: per launch
         nplanes = extra[0]
         if nplanes is None or total_ch + nplanes > mm.MAX_CHANNELS + 1:
             continue  # the exact scatter takes this one, as per launch
         planes.append((i, BatchContext.groupby_planes_key(
-            ck, offsets[i], nplanes), nplanes))
+            ek, offsets[i], nplanes), nplanes))
         total_ch += nplanes
-    route = _sum_route(num_groups, total_ch, n_total, mm_mode, pallas_mode)
-    if route is None or not (planes or any(
-            a[0] in ("count", "avg") for a in aggs)):
+    if not (planes or any(a[0] in ("count", "avg") for a in aggs)):
         return None
-    blk = ps.sums_blk(num_groups, total_ch) if route == "pallas" \
-        else mm.group_sums_blk(num_groups, total_ch)
-    if not mm.prepared_tile_ok(blk):
+    if shape == "groupby_narrow":
+        # pass 1 counts by block, pass 2 sums into the table's slots
+        n_blocks = -(-num_groups // NARROW_BLOCK)
+        slots = NARROW_BLOCKS * NARROW_BLOCK
+        routes = (_sum_route(n_blocks, 1, n_total, mm_mode, pallas_mode),
+                  _sum_route(slots, total_ch, n_total, mm_mode, pallas_mode))
+        blks = (ps.sums_blk(n_blocks, 1),
+                ps.narrow_blk(total_ch, NARROW_BLOCKS))
+        route = "pallas" if routes == ("pallas", "pallas") else None
+    else:
+        route = _sum_route(num_groups, total_ch, n_total, mm_mode,
+                           pallas_mode)
+        if route == "mm" and len(group_cols) > 1:
+            route = None  # the matmul kernel takes one ids operand
+        blks = (ps.sums_blk(num_groups, total_ch) if route == "pallas"
+                else mm.group_sums_blk(num_groups, total_ch),)
+    if route is None or not all(mm.prepared_tile_ok(b) for b in blks):
         return None
-    return (route, "gk::" + group_cols[0], tuple(planes))
+    return (route, tuple("gk::" + c for c in group_cols), tuple(planes))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("argt", "wsig", "off", "nplanes"))
+def _expr_planes(cols, fo, *, argt, wsig, off: int, nplanes: int):
+    """The ``gv::`` operand of an expression argument: the expression over
+    the stored (S, L) planes (``fo``: their frame-of-reference offsets),
+    then the byte planes of ``value - off`` in lanes, one program."""
+    from pinot_tpu.ops import groupby_mm as mm
+
+    v = _eval_expr(argt, cols, fo, dict(wsig))
+    return mm.prepared_planes(v, delta=-off, nplanes=nplanes)
+
+
+def _prepared_groupby(prepared, cols, params, mask_lane, group_cards,
+                      num_groups, outs, mm_mode, pallas_mode,
+                      hi_table=None) -> set:
+    """A group-by's COUNT/SUM/AVG channels over the batch's PREPARED
+    operands (``plan_prepared_groupby``'s plan): the kernel reads them
+    out of ``cols`` as they are, and the launch's mask relaid out to
+    lanes at one byte a row (``mask_lane``); nothing else row-scale is
+    computed. ``hi_table``: the narrowed form's live blocks, and the
+    sums are then its slots'. Fills ``outs`` as ``_try_mm_groupby`` does
+    and returns the agg indexes handled."""
+    from pinot_tpu.ops import groupby_mm as mm
+    from pinot_tpu.ops import pallas_scatter as ps
+
+    route, ids_keys, plane_plan = prepared
+    ids = tuple(cols[k] for k in ids_keys)
+    planes = [cols[key] for _i, key, _n in plane_plan]
+    with jax.named_scope("pinot.groupby_kernel"):
+        if hi_table is not None:
+            sums = ps.plane_group_sums_narrow_prepared(
+                ids, group_cards, mask_lane, planes, hi_table,
+                interpret=(pallas_mode == "interpret"))
+        elif route == "pallas":
+            sums = ps.plane_group_sums_prepared(
+                ids, group_cards, mask_lane, planes, num_groups,
+                interpret=(pallas_mode == "interpret"))
+        else:
+            sums = mm.group_sums_prepared(
+                ids[0], mask_lane, planes, num_groups,
+                interpret=(mm_mode == "interpret"))
+    specs, row = [], 1
+    for i, _key, nplanes in plane_plan:
+        specs.append((i, "int", slice(row, row + nplanes)))
+        row += nplanes
+    return _recombine_sums(sums, specs, params, outs)
 
 
 def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
-                    widths=None, pallas_mode="off", prepared=None,
-                    mask=None, narrow=None):
+                    widths=None, pallas_mode="off", narrow=None):
     """Route COUNT/SUM/AVG through ONE factored one-hot launch when
     eligible: the Pallas tiled local-accumulate scatter
     (ops/pallas_scatter.py plane_group_sums — group-range partitioned,
@@ -387,11 +475,9 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     kernel (ops/groupby_mm.py). Fills outs["gcount"] +
     outs[f"a{i}_sum"] and returns the set of agg indexes handled;
     scatter code covers the rest. All decisions are trace-time static.
-
-    ``prepared``: plan_prepared_groupby's plan — the kernel then reads
-    the batch's operands out of ``cols`` as they are and the launch's
-    ``mask``, relaid out to lanes at one byte a row; nothing else
-    row-scale is computed.
+    This is the PER-LAUNCH preparation (ids combined and masked, planes
+    split, channels stacked, every launch); ``_prepared_groupby`` is the
+    form that reads the batch's operands.
 
     ``narrow``: ``(hi_table, slot_ids)`` of a narrowed key space
     (``_aggregate_narrowed``). ``gid`` is then the cartesian id, masked
@@ -400,26 +486,6 @@ def _try_mm_groupby(aggs, gid, cols, params, num_groups, mm_mode, outs,
     itself, the matmul kernel takes ``slot_ids()``."""
     from pinot_tpu.ops import groupby_mm as mm
     from pinot_tpu.ops import pallas_scatter as ps
-
-    if prepared is not None:
-        route, ids_key, plane_plan = prepared
-        with jax.named_scope("pinot.mask"):
-            mask_lane = mm.mask_lanes(mask)
-        planes = [cols[key] for _i, key, _n in plane_plan]
-        with jax.named_scope("pinot.groupby_kernel"):
-            if route == "pallas":
-                sums = ps.plane_group_sums_prepared(
-                    cols[ids_key], mask_lane, planes, num_groups,
-                    interpret=(pallas_mode == "interpret"))
-            else:
-                sums = mm.group_sums_prepared(
-                    cols[ids_key], mask_lane, planes, num_groups,
-                    interpret=(mm_mode == "interpret"))
-        specs, row = [], 1
-        for i, _key, nplanes in plane_plan:
-            specs.append((i, "int", slice(row, row + nplanes)))
-            row += nplanes
-        return _recombine_sums(sums, specs, params, outs)
 
     if mm_mode == "off" and pallas_mode == "off":
         return set()
@@ -969,10 +1035,11 @@ def build_pipeline(template, mm_mode: str = "auto",
     form of the block-skip path.
 
     ``prepared``: plan_prepared_groupby's plan, or None. With it the
-    DENSE form's COUNT/SUM/AVG kernel reads the batch's prepared operands
-    (``gk::`` / ``gv::`` entries of ``cols``) and the launch computes only
-    the mask; the block-skip form's gathered branch has other rows and
-    keeps the per-launch preparation.
+    DENSE form's COUNT/SUM/AVG kernel (shapes "groupby" and
+    "groupby_narrow", both of the latter's passes) reads the batch's
+    prepared operands (``gk::`` / ``gv::`` entries of ``cols``) and the
+    launch computes only the mask; the block-skip form's gathered branch
+    has other rows and keeps the per-launch preparation.
     """
     shape, filter_tpl, group_cols, group_cards, aggs, sorted_k, _final = template
     mm_mode = _resolve_mm_mode(mm_mode)
@@ -1274,16 +1341,20 @@ def build_pipeline(template, mm_mode: str = "auto",
             return outs
 
         if shape == "groupby_narrow":
-            return _aggregate_narrowed(cols, params, mask, outs)
+            return _aggregate_narrowed(cols, params, mask, outs, prep)
 
         if shape == "groupby":
             # columns are already global ids: the group key IS the column
             per_col = [_ids_col(cols, c, widths) for c in group_cols]
             gid = agg_ops.group_ids_combine(per_col, group_cards, mask, num_groups)
-            mm_done = _try_mm_groupby(
-                aggs, gid, cols, params, num_groups, mm_mode, outs, widths,
-                pallas_mode=pallas_mode, prepared=prep, mask=mask,
-            )
+            if prep is not None:
+                mm_done = _prepared_groupby(
+                    prep, cols, params, _mask_lanes(mask), group_cards,
+                    num_groups, outs, mm_mode, pallas_mode)
+            else:
+                mm_done = _try_mm_groupby(
+                    aggs, gid, cols, params, num_groups, mm_mode, outs,
+                    widths, pallas_mode=pallas_mode)
             if "gcount" not in outs:
                 outs["gcount"] = agg_ops.group_count(gid, num_groups)
             for i, (name, argt, extra) in enumerate(aggs):
@@ -1412,7 +1483,13 @@ def build_pipeline(template, mm_mode: str = "auto",
                 outs[f"{k}_v{suff}"] = vb
         return outs
 
-    def _aggregate_narrowed(cols, params, mask, outs):
+    def _mask_lanes(mask):
+        from pinot_tpu.ops import groupby_mm as mm
+
+        with jax.named_scope("pinot.mask"):
+            return mm.mask_lanes(mask)
+
+    def _aggregate_narrowed(cols, params, mask, outs, prep=None):
         """COUNT/SUM/AVG over a NARROWED key space (NARROW_MIN_CELLS has
         the why). Pass 1 counts the mask's rows by 128-cell block of the
         cartesian key space and ranks the blocks that hold any into
@@ -1425,11 +1502,17 @@ def build_pipeline(template, mm_mode: str = "auto",
         narrows by its own mask. More live blocks than NARROW_BLOCKS, or
         more live cells than ``sorted_k``, and ``n_groups_total`` says
         so by passing ``sorted_k``: the host answers (the executor
-        counts it), as for the sorted regime's overflow."""
+        counts it), as for the sorted regime's overflow.
+
+        ``prep``: the prepared operand plan. Both passes then read the
+        batch's id and plane operands and the mask in lanes
+        (``_prepared_groupby``): the cartesian id, its shift to a block
+        and the select are the kernel's, in VMEM."""
         shift = NARROW_BLOCK.bit_length() - 1
         slots = NARROW_BLOCKS * NARROW_BLOCK
         n_blocks = -(-num_groups // NARROW_BLOCK)
         per_col = [_ids_col(cols, c, widths) for c in group_cols]
+        mask_lane = None if prep is None else _mask_lanes(mask)
         with jax.named_scope("pinot.narrow"):
             # a masked row's id lies past the key space, in a block of
             # its own: the count's overflow slot, and in no table
@@ -1437,8 +1520,18 @@ def build_pipeline(template, mm_mode: str = "auto",
                 per_col, group_cards, mask, n_blocks * NARROW_BLOCK)
             block = gid >> shift
             seen = {}
-            _try_mm_groupby(_COUNT_ONLY, block, cols, params, n_blocks,
-                            mm_mode, seen, widths, pallas_mode=pallas_mode)
+            if prep is not None:
+                from pinot_tpu.ops import pallas_scatter as ps
+
+                seen["gcount"] = jnp.round(ps.plane_group_sums_prepared(
+                    tuple(cols[k] for k in prep[1]), group_cards, mask_lane,
+                    (), n_blocks, shift=shift,
+                    interpret=(pallas_mode == "interpret"))[0]
+                ).astype(jnp.int64)
+            else:
+                _try_mm_groupby(_COUNT_ONLY, block, cols, params, n_blocks,
+                                mm_mode, seen, widths,
+                                pallas_mode=pallas_mode)
             rows_in = seen["gcount"] if seen \
                 else agg_ops.group_count(block, n_blocks)
             live = rows_in > 0
@@ -1462,9 +1555,14 @@ def build_pipeline(template, mm_mode: str = "auto",
             return memo[0]
 
         table = {}
-        done = _try_mm_groupby(
-            aggs, gid, cols, params, slots, mm_mode, table, widths,
-            pallas_mode=pallas_mode, narrow=(hi_table, slot_ids))
+        if prep is not None:
+            done = _prepared_groupby(
+                prep, cols, params, mask_lane, group_cards, slots, table,
+                mm_mode, pallas_mode, hi_table=hi_table)
+        else:
+            done = _try_mm_groupby(
+                aggs, gid, cols, params, slots, mm_mode, table, widths,
+                pallas_mode=pallas_mode, narrow=(hi_table, slot_ids))
         if "gcount" not in table:
             table["gcount"] = agg_ops.group_count(slot_ids(), slots)
         for i, (name, argt, extra) in enumerate(aggs):
@@ -1588,9 +1686,9 @@ class DeviceExecutor:
         self.batch_hits = 0
         self.batch_misses = 0
         self.batch_evictions = 0
-        # dense group-by launches by where the kernel's operands came
-        # from: the batch's prepared ones, this launch built them, or
-        # per-launch preparation (BatchContext.groupby_operand)
+        # dense and narrowed group-by launches by where the kernel's
+        # operands came from: the batch's prepared ones, this launch built
+        # them, or per-launch preparation (BatchContext.groupby_operand)
         self.groupby_operand_launches = {
             "prepared": 0, "built": 0, "perLaunch": 0}
         # launches of the narrowed key space, and queries whose live keys
@@ -2743,19 +2841,20 @@ class DeviceExecutor:
                 pmode = pmode2
                 adv_notes.append(note)
 
-        # the dense group-by's statement-invariant kernel operands: taken
-        # from the batch where the template allows it and the batch's
-        # bytes plus theirs stay under the byte cap (plan_prepared_groupby
-        # has the rest of the conditions); else today's per-launch
-        # preparation runs. A mesh keeps the per-launch form: the lane
-        # blocks are not laid out by segment shard.
+        # the group-by kernel's statement-invariant operands (dense and
+        # narrowed forms alike): taken from the batch where the template
+        # allows it and the batch's bytes plus theirs stay under the byte
+        # cap (plan_prepared_groupby has the rest of the conditions); else
+        # the per-launch preparation runs. A mesh keeps the per-launch
+        # form: the lane blocks are not laid out by segment shard.
         prepared = None
-        if shape == "groupby" and self.mesh is None:
+        gb_exprs = {}  # an expression operand's cols key -> how to build it
+        if shape in ("groupby", "groupby_narrow") and self.mesh is None:
             prepared = plan_prepared_groupby(
                 template, widths, ctx.S * ctx.pad_to, self.mm_mode, pmode,
                 offsets)
             if prepared is not None:
-                gb_keys = (prepared[1],) + tuple(
+                gb_keys = prepared[1] + tuple(
                     k for _i, k, _n in prepared[2])
                 # what is built already costs nothing more
                 cost = ctx.groupby_operand_cost(gb_keys)
@@ -2763,10 +2862,16 @@ class DeviceExecutor:
                     prepared = None
                 else:
                     needed.update(gb_keys)
+                    for i, key, nplanes in prepared[2]:
+                        argt = agg_tpls[i][1]
+                        if argt[0] not in ("raw", "dictval"):
+                            gb_exprs[key] = functools.partial(
+                                self._build_expr_planes, ctx, argt, widths,
+                                params, offsets[i], nplanes)
         # what the launch's spans, its flight record and EXPLAIN ANALYZE
         # say of it: prepared | built (this launch built them) | perLaunch
-        gb_operands = None if shape != "groupby" else \
-            "perLaunch" if prepared is None else "prepared"
+        gb_operands = None if shape not in ("groupby", "groupby_narrow") \
+            else "perLaunch" if prepared is None else "prepared"
         # and of its key space: dense | narrowed | sorted (| overflow,
         # which only the result can say: _make_resolve)
         key_space = {"groupbyKeySpace": KEY_SPACES[shape],
@@ -2860,7 +2965,7 @@ class DeviceExecutor:
                 elif c.startswith("mv::"):
                     cols[c] = ctx.mv_column(c[4:])
                 elif c.startswith(_GB_OPERAND_PREFIXES):
-                    cols[c], built = ctx.groupby_operand(c)
+                    cols[c], built = ctx.groupby_operand(c, gb_exprs.get(c))
                     if built:
                         gb_operands = "built"
                 else:
@@ -2938,6 +3043,18 @@ class DeviceExecutor:
         handle.advisor_notes = adv_notes
         handle.adv_trim_keep = adv_trim_keep
         return handle
+
+    def _build_expr_planes(self, ctx, argt, widths, params, off, nplanes):
+        """Builder of an expression argument's ``gv::`` operand
+        (BatchContext.groupby_operand calls it once a batch)."""
+        leaves = sorted(self._needed_columns(argt))
+        cols = {c: ctx.decoded_column(c[4:]) if c.startswith("dv::")
+                else ctx.column(c) for c in leaves}
+        fo = {"fo::" + c: params["fo::" + c] for c in leaves
+              if "fo::" + c in params}
+        return _expr_planes(
+            cols, fo, argt=argt, wsig=tuple((c, widths[c]) for c in leaves),
+            off=off, nplanes=nplanes)
 
     # ---- dispatch: solo vs coalesced -------------------------------------
     def _pipeline_key(self, template, blockskip, wsig, trim,

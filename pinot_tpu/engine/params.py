@@ -176,7 +176,7 @@ class BatchContext:
         self._sorted_hll: dict = {}   # (group_cols, hash_col, log2m) -> sorted keys
         # the dense group-by's statement-invariant kernel operands
         # (ops/groupby_mm.py "prepared operands"), by cols key: "gk::<col>"
-        # lane-major key ids, "gv::<colkey>::<off>::<nplanes>" uint8 byte
+        # lane-major key ids, "gv::<value>::<off>::<nplanes>" uint8 byte
         # planes of value - off. Built on the device at first use; they
         # live and die with this batch
         self._gb_operands: dict = {}
@@ -662,18 +662,22 @@ class BatchContext:
     def groupby_planes_key(colkey: str, off: int, nplanes: int) -> str:
         return f"gv::{colkey}::{off}::{nplanes}"
 
-    def groupby_operand(self, key: str):
-        """(device array, built now?) for a "gk::" / "gv::" cols key: the
-        group column's lane-major ids, or a value column's uint8 byte
-        planes, built once a batch by one jitted program over the stored
-        plane and then kept like the batch's other derived blocks."""
+    def groupby_operand(self, key: str, build=None):
+        """(device array, built now?) for a "gk::" / "gv::" cols key: a
+        group column's lane-major ids, or a value's uint8 byte planes,
+        built once a batch by one jitted program over the stored planes
+        and then kept like the batch's other derived blocks. ``build``
+        makes the planes of a value that is an expression over columns
+        (engine/device.py ``_expr_planes``); a column's are made here."""
         with self._lock:
             arr = self._gb_operands.get(key)
             if arr is not None:
                 return arr, False
             from pinot_tpu.ops import groupby_mm as mm
 
-            if key.startswith("gk::"):
+            if build is not None:
+                arr = build()
+            elif key.startswith("gk::"):
                 name = key[4:]
                 arr = mm.prepared_ids(
                     self._column_locked(name), self.n_docs_dev,

@@ -144,7 +144,8 @@ def _kernel(*refs, ninner, hpad, a_real, blk, lo, rho_mode, stacked,
     else:
         # batch-resident operands: ids, the launch's mask, byte planes
         num_groups, plane_rows = prepared
-        ids_r = prepared_ids_row(refs[0], refs[1], num_groups, blk)
+        ids_r = prepared_ids_row(refs[:1], refs[1], (num_groups,),
+                                 num_groups, blk)
         plane_at = prepared_plane_index(refs[2:-2], plane_rows)
     lo_r = ids_r & (lo - 1)
     hi_r = ids_r >> lo_shift
@@ -303,12 +304,17 @@ def group_sums(gid, channels, num_groups: int, *, interpret: bool = False,
 # relayouts. None of it depends on the statement; only the filter mask
 # does. engine/params.py BatchContext builds these once a batch:
 #
-# - ``prepared_ids``: the key column's ids, clipped, rows past a segment's
+# - ``prepared_ids``: a key column's ids, clipped, rows past a segment's
 #   end already at ``num_groups``, lane-major ``(n_pad/128, 128)`` at the
-#   stored width (the kernel widens in VMEM);
+#   stored width (the kernel widens in VMEM). One operand a key COLUMN,
+#   not a key set: a multi-key group-by's kernel takes one ref a column
+#   and forms the cartesian id in VMEM (``prepared_ids_row``), so key sets
+#   that share a column share its operand;
 # - ``prepared_planes``: the byte planes of ``value - off`` as uint8,
 #   ``(nplanes, n_pad/128, 128)`` (the kernel converts to bf16 in VMEM:
-#   half the HBM of bf16 planes, and dense (32, 128) tiles).
+#   half the HBM of bf16 planes, and dense (32, 128) tiles). The value is
+#   a stored column or an expression over stored columns alone
+#   (engine/device.py ``_expr_planes``).
 #
 # The launch hands the kernel its mask as a third operand (``mask_lanes``,
 # one byte a row) and ``where(mask, ids, num_groups)`` happens in VMEM: no
@@ -391,13 +397,24 @@ def mask_lanes(mask):
     return _to_lanes(mask.astype(jnp.uint8), 0)
 
 
-def prepared_ids_row(ids_ref, mask_ref, num_groups: int, blk: int):
-    """In-kernel: (blk/128, 128) ids and mask blocks → the (1, blk) int32
-    row of masked ids the one-hots compare against. Widening and the
-    select run on the dense tile, before the sublane→lane merge."""
-    ids = ids_ref[:].astype(jnp.int32)
+def prepared_ids_row(id_refs, mask_ref, cards, sentinel: int, blk: int,
+                     shift: int = 0):
+    """In-kernel: one (blk/128, 128) ids block a key column and the mask
+    block → the (1, blk) int32 row of masked ids the one-hots compare
+    against. The columns' ids (each at its stored width, ``cards`` their
+    cardinalities) combine to the cartesian id ``(id0 * c1 + id1) * c2 +
+    id2``; ``shift`` keeps its high bits (the narrowed form's 128-cell
+    block); a row the mask drops, pad rows among them, carries
+    ``sentinel``. Widening, the multiply-adds and the select run on the
+    dense tile, before the sublane→lane merge; for one key column this is
+    the widen and the select alone."""
+    ids = id_refs[0][:].astype(jnp.int32)
+    for ref, card in zip(id_refs[1:], cards[1:]):
+        ids = ids * _i32(card) + ref[:].astype(jnp.int32)
+    if shift:
+        ids = ids >> _i32(shift)
     keep = mask_ref[:].astype(jnp.int32) != _i32(0)
-    return jnp.where(keep, ids, _i32(num_groups)).reshape(1, blk)
+    return jnp.where(keep, ids, _i32(sentinel)).reshape(1, blk)
 
 
 def prepared_plane_index(plane_refs, plane_rows):

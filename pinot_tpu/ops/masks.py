@@ -61,9 +61,23 @@ def eq_dict(ids, target_id):
     return ids == target_id
 
 
+IN_UNROLL_MAX = 16
+
+
 def in_dict(ids, id_vector):
-    """IN: ``id_vector`` int32 (K,) global ids, padded with -2."""
-    return jnp.any(ids[..., None] == id_vector, axis=-1)
+    """IN: ``id_vector`` int32 (K,) global ids, padded with -2. A short
+    list is K compares OR-ed at the ids' own shape: the (..., K) compare
+    with a reduction over its minor axis, relaid out to lanes under
+    ``vmap`` (a cohort's mask, ops/groupby_mm.py mask_lanes), took the
+    TPU's compiler 210 s at K = 2 where this takes 2 (PR 33, compiled
+    for a described v5e)."""
+    k_in = id_vector.shape[-1]
+    if k_in > IN_UNROLL_MAX:
+        return jnp.any(ids[..., None] == id_vector, axis=-1)
+    hit = ids == id_vector[..., 0]
+    for k in range(1, k_in):
+        hit |= ids == id_vector[..., k]
+    return hit
 
 
 def range_dict(ids, lo, hi):

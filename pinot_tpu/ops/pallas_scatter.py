@@ -168,8 +168,8 @@ def _rel_onehots(ids_r, p, gp: int, hpad: int, blk: int):
     return oh_loT, oh_hi
 
 
-def _narrow_kernel(ids_ref, tab_ref, ch_ref, out_ref, acc_ref, *, ninner: int,
-                   hpad: int, a_real: int, blk: int, ones_first: bool):
+def _narrow_kernel(*refs, ninner: int, hpad: int, a_real: int, blk: int,
+                   ones_first: bool, prepared=None):
     """The plane-sum kernel over a NARROWED key space, one row tile: hi row
     ``h`` matches the rows whose 128-cell block (``id >> 7``) is
     ``tab_ref[h]``, so the accumulator holds only the blocks the table
@@ -179,14 +179,30 @@ def _narrow_kernel(ids_ref, tab_ref, ch_ref, out_ref, acc_ref, *, ninner: int,
     is skipped, so the tile costs what the live blocks cost and not what
     the table could hold (3 of 4 chunks skipped for SSB Q3.2's 15 live
     blocks). Channels are stacked into one dot a chunk, as the dense
-    form stacks them."""
+    form stacks them. Operands as ``_sums_kernel``'s, the table between
+    the ids (and mask) and the channels."""
+    out_ref, acc_ref = refs[-2:]
     i = pl.program_id(2)
 
     @pl.when(i == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    ids_r = ids_ref[:].reshape(1, blk)
+    if prepared is None:
+        ids_ref, tab_ref, ch_ref = refs[:3]
+        ids_r = ids_ref[:].reshape(1, blk)
+    else:
+        ids_r, nk, plane_rows = _prepared_ids(refs, prepared, blk)
+        tab_ref = refs[nk + 1]
+        plane_at = mm.prepared_plane_index(refs[nk + 2:-2], plane_rows)
+
+    def chh(a, oh_hi):
+        if a == 0 and ones_first:
+            return oh_hi  # folded all-ones count channel
+        if prepared is not None:
+            return oh_hi * mm.prepared_plane_row(*plane_at[a - 1], blk)
+        return oh_hi * ch_ref[pl.ds(a, 1), :]
+
     lo_r = ids_r & _i32(LO - 1)
     hi_r = ids_r >> _i32(7)  # LO = 128
     jsub = jax.lax.broadcasted_iota(jnp.int32, (LO, blk), 0)
@@ -205,9 +221,7 @@ def _narrow_kernel(ids_ref, tab_ref, ch_ref, out_ref, acc_ref, *, ninner: int,
             oh_hi = jnp.where(hi_r == tab, jnp.float32(1), jnp.float32(0)) \
                 .astype(jnp.bfloat16)
             chh_all = jnp.concatenate(
-                [oh_hi if a == 0 and ones_first
-                 else oh_hi * ch_ref[pl.ds(a, 1), :]
-                 for a in range(a_real)], axis=0)
+                [chh(a, oh_hi) for a in range(a_real)], axis=0)
             acc_ref[:, rows, :] += jax.lax.dot_general(
                 chh_all, oh_loT, _NT, preferred_element_type=jnp.float32
             ).reshape(a_real, hc, LO)
@@ -215,6 +229,18 @@ def _narrow_kernel(ids_ref, tab_ref, ch_ref, out_ref, acc_ref, *, ninner: int,
     @pl.when(i == ninner - 1)
     def _():
         out_ref[0] = acc_ref[:]
+
+
+def _prepared_ids(refs, prepared, blk: int):
+    """A prepared launch's masked id row (ops/groupby_mm.py
+    ``prepared_ids_row``) for every kernel form: the refs open with one
+    ids block a key column, then the mask. Returns (the (1, blk) row, the
+    number of key columns, the value operands' plane counts)."""
+    cards, sentinel, shift, plane_rows = prepared
+    nk = len(cards)
+    ids_r = mm.prepared_ids_row(refs[:nk], refs[nk], cards, sentinel, blk,
+                                shift)
+    return ids_r, nk, plane_rows
 
 
 # ---------------------------------------------------------------------------
@@ -246,9 +272,8 @@ def _sums_kernel(*refs, ninner, hpad, a_real, blk, gp, stacked, ones_first,
         ids_r = ids_ref[:].reshape(1, blk)
     else:
         # batch-resident operands (ops/groupby_mm.py "prepared operands")
-        num_groups, plane_rows = prepared
-        ids_r = mm.prepared_ids_row(refs[0], refs[1], num_groups, blk)
-        plane_at = mm.prepared_plane_index(refs[2:-2], plane_rows)
+        ids_r, nk, plane_rows = _prepared_ids(refs, prepared, blk)
+        plane_at = mm.prepared_plane_index(refs[nk + 1:-2], plane_rows)
     oh_loT, oh_hi = _rel_onehots(ids_r, p, gp, hpad, blk)
 
     def chh(a):
@@ -291,19 +316,20 @@ def _lane_spec(blk: int, ninner: int):
                         memory_space=pltpu.VMEM)
 
 
-def _sums_call(ids_lane, operands, operand_specs, num_groups: int,
+def _sums_call(operands, operand_specs, num_groups: int,
                a_real: int, plan, *, interpret: bool, ones_first: bool,
                prepared=None, narrow: bool = False):
     """The one ``pallas_call`` of the plane-sum kernel, over per-launch
-    operands (masked ids + stacked bf16 channels) or prepared ones (ids,
-    mask, uint8 lane planes). Returns (A, num_groups) float64."""
+    operands (masked ids + stacked bf16 channels) or prepared ones (ids a
+    key column, mask, uint8 lane planes); the first operand is lane-major
+    ids either way. Returns (A, num_groups) float64."""
     hp, npart, blk, ninner, stacked = plan
     gp = hp * LO
-    nsuper = ids_lane.shape[0] * 128 // SUPERBLOCK
+    nsuper = operands[0].shape[0] * 128 // SUPERBLOCK
     if narrow:
         kern = functools.partial(
             _narrow_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
-            ones_first=ones_first)
+            ones_first=ones_first, prepared=prepared)
     else:
         kern = functools.partial(
             _sums_kernel, ninner=ninner, hpad=hp, a_real=a_real, blk=blk,
@@ -312,7 +338,7 @@ def _sums_call(ids_lane, operands, operand_specs, num_groups: int,
     out = pl.pallas_call(
         kern,
         grid=(npart, nsuper, ninner),
-        in_specs=[_lane_spec(blk, ninner), *operand_specs],
+        in_specs=list(operand_specs),
         out_specs=pl.BlockSpec(
             (1, a_real, hp, LO),
             lambda p, s, i: (p * nsuper + s, _i32(0), _i32(0), _i32(0)),
@@ -324,7 +350,7 @@ def _sums_call(ids_lane, operands, operand_specs, num_groups: int,
             vmem_limit_bytes=_vmem_limit(a_real, hp, blk, stacked)),
         interpret=interpret,
         name="pinot_scatter_sums",
-    )(ids_lane, *operands)
+    )(*operands)
     # (npart*nsuper, A, hp, LO) → superblock partials reduce in f64, then
     # partitions concatenate along the group axis
     tot = jnp.sum(out.reshape(npart, nsuper, a_real, hp, LO), axis=1,
@@ -359,32 +385,60 @@ def plane_group_sums(gid, channels, num_groups: int, *,
     ch_spec = pl.BlockSpec((a_real, blk),
                            lambda p, s, i: (_i32(0), s * ninner + i),
                            memory_space=pltpu.VMEM)
-    return _sums_call(ids_lane, (ch,), [ch_spec], num_groups, a_real, plan,
-                      interpret=interpret, ones_first=first_channel_ones)
+    return _sums_call((ids_lane, ch), [_lane_spec(blk, ninner), ch_spec],
+                      num_groups, a_real, plan, interpret=interpret,
+                      ones_first=first_channel_ones)
 
 
-def plane_group_sums_prepared(ids_lane, mask_lane, planes, num_groups: int,
-                              *, interpret: bool = False,
-                              span_hpad: int | None = None):
-    """``plane_group_sums`` over the batch's prepared operands
-    (ops/groupby_mm.py ``prepared_ids`` / ``prepared_planes``) and the
-    launch's ``mask_lanes``: channel 0 counts (no operand rows), channels
-    1.. are the rows of ``planes`` in order; ``where(mask, ids,
-    num_groups)`` happens in VMEM. Same f32 superblock partials, same
-    f64 reduction: bit-identical to the per-launch operands."""
-    a_real = 1 + sum(p.shape[0] for p in planes)
-    plan = _sums_plan(num_groups, a_real, span_hpad)
-    _hp, _npart, blk, ninner, _stacked = plan
+def _prepared_operands(ids_lanes, mask_lane, planes, blk: int, ninner: int,
+                       between=()):
+    """(operands, their specs) of a prepared launch: the ids a key column,
+    the mask, ``between`` [(operand, spec)], then the uint8 planes a
+    value."""
     plane_specs = [
         pl.BlockSpec((p.shape[0], blk // 128, 128),
                      lambda p_, s, i: (_i32(0), s * ninner + i, _i32(0)),
                      memory_space=pltpu.VMEM)
         for p in planes]
+    return ((*ids_lanes, mask_lane, *(o for o, _s in between), *planes),
+            [_lane_spec(blk, ninner)] * (len(ids_lanes) + 1)
+            + [spec for _o, spec in between] + plane_specs)
+
+
+def plane_group_sums_prepared(ids_lanes, cards, mask_lane, planes,
+                              num_groups: int, *, shift: int = 0,
+                              interpret: bool = False,
+                              span_hpad: int | None = None):
+    """``plane_group_sums`` over the batch's prepared operands
+    (ops/groupby_mm.py ``prepared_ids`` / ``prepared_planes``) and the
+    launch's ``mask_lanes``. ``ids_lanes``: one lane-major id operand a
+    key column, ``cards`` their cardinalities; the cartesian id, its
+    ``shift`` (then ``num_groups`` counts the ids left: the narrowed
+    form's blocks) and ``where(mask, ids, num_groups)`` happen in VMEM.
+    Channel 0 counts (no operand rows), channels 1.. are the rows of
+    ``planes`` in order. Same f32 superblock partials, same f64
+    reduction: bit-identical to the per-launch operands."""
+    a_real = 1 + sum(p.shape[0] for p in planes)
+    plan = _sums_plan(num_groups, a_real, span_hpad)
+    _hp, _npart, blk, ninner, _stacked = plan
+    operands, specs = _prepared_operands(ids_lanes, mask_lane, planes, blk,
+                                         ninner)
     return _sums_call(
-        ids_lane, (mask_lane, *planes),
-        [_lane_spec(blk, ninner), *plane_specs], num_groups, a_real, plan,
-        interpret=interpret, ones_first=True,
-        prepared=(num_groups, tuple(p.shape[0] for p in planes)))
+        operands, specs, num_groups, a_real, plan, interpret=interpret,
+        ones_first=True,
+        prepared=(tuple(cards), num_groups, shift,
+                  tuple(p.shape[0] for p in planes)))
+
+
+def _narrow_tab(hi_table):
+    """The live-block table as the kernel takes it: lane-replicated."""
+    return jnp.broadcast_to(hi_table.astype(jnp.int32)[:, None],
+                            (hi_table.shape[0], 128))
+
+
+def _narrow_tab_spec(hp: int):
+    return pl.BlockSpec((hp, 128), lambda p, s, i: (_i32(0), _i32(0)),
+                        memory_space=pltpu.VMEM)
 
 
 def plane_group_sums_narrow(gid, channels, hi_table, *,
@@ -410,16 +464,41 @@ def plane_group_sums_narrow(gid, channels, hi_table, *,
     ch = jnp.concatenate(
         [channels, jnp.zeros((a_real, n_pad - n), channels.dtype)], axis=1
     ) if n_pad > n else channels
-    tab = jnp.broadcast_to(hi_table.astype(jnp.int32)[:, None], (hp, 128))
-    tab_spec = pl.BlockSpec((hp, 128), lambda p, s, i: (_i32(0), _i32(0)),
-                            memory_space=pltpu.VMEM)
+    tab, tab_spec = _narrow_tab(hi_table), _narrow_tab_spec(hp)
     ch_spec = pl.BlockSpec((a_real, blk),
                            lambda p, s, i: (_i32(0), s * ninner + i),
                            memory_space=pltpu.VMEM)
-    return _sums_call(ids_lane, (tab, ch), [tab_spec, ch_spec], hp * LO,
+    return _sums_call((ids_lane, tab, ch),
+                      [_lane_spec(blk, ninner), tab_spec, ch_spec], hp * LO,
                       a_real, (hp, 1, blk, ninner, stacked),
                       interpret=interpret, ones_first=first_channel_ones,
                       narrow=True)
+
+
+def narrow_blk(a_real: int, hp: int) -> int:
+    """The row tile a narrowed launch of this shape runs at."""
+    return _plan_blk(a_real, hp)[0]
+
+
+def plane_group_sums_narrow_prepared(ids_lanes, cards, mask_lane, planes,
+                                     hi_table, *, interpret: bool = False):
+    """``plane_group_sums_narrow`` over the batch's prepared operands, as
+    ``plane_group_sums_prepared`` reads them: the cartesian id is formed
+    and masked in VMEM (a dropped row's block is in no table), the uint8
+    planes are the channels. Bit-identical to the per-launch operands."""
+    a_real = 1 + sum(p.shape[0] for p in planes)
+    hp = hi_table.shape[0]
+    assert hp % NARROW_CHUNK == 0, hp
+    blk, ninner, stacked = _plan_blk(a_real, hp)
+    operands, specs = _prepared_operands(
+        ids_lanes, mask_lane, planes, blk, ninner,
+        between=[(_narrow_tab(hi_table), _narrow_tab_spec(hp))])
+    return _sums_call(
+        operands, specs, hp * LO, a_real, (hp, 1, blk, ninner, stacked),
+        interpret=interpret, ones_first=True,
+        prepared=(tuple(cards), jnp.iinfo(jnp.int32).max, 0,
+                  tuple(p.shape[0] for p in planes)),
+        narrow=True)
 
 
 # ---------------------------------------------------------------------------

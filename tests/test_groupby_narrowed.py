@@ -427,7 +427,10 @@ def test_the_servers_metrics_name_the_counters(tmp_path):
 
 # the compiled-pipeline key of benchmark/traffic/groupby_bands_c4.json's
 # statement on a stand-in of its table, as the parent commit (35a68ef)
-# builds it: a key that differs compiles another program for cell 1
+# builds it: a key that differs compiles another program for cell 1.
+# (Since PR 33 the plan names one ids operand a key column, so its second
+# part is a tuple; the program is the parent's, which
+# test_tpu_compile.py's one-key case holds to.)
 BANDS_KEY = (
     ("groupby", ("range_dict", "lo_discount", "pr0", "pr1"), ("lo_suppkey",),
      (2000,), (("sum", ("raw", "lo_revenue"), (3, None)),), 0, False),
@@ -436,7 +439,7 @@ BANDS_KEY = (
      ("lo_revenue", ("<i4", 0, False, "")),
      ("lo_suppkey", ("<u2", 0, False, ""))),
     (16, (("agg", 0, "sum", False),)), "interpret",
-    ("pallas", "gk::lo_suppkey", ((0, "gv::lo_revenue::81000::3", 3),)))
+    ("pallas", ("gk::lo_suppkey",), ((0, "gv::lo_revenue::81000::3", 3),)))
 
 
 def test_groupby_bands_pipeline_key_is_the_parents(tmp_path):
@@ -467,10 +470,12 @@ def test_groupby_bands_pipeline_key_is_the_parents(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def served_ssb_flat(tmp_path_factory):
+def ssb_flat_built(tmp_path_factory):
+    """The tiny SSB table's segments, built once: (config, traffic, the
+    reference's rows by statement, harness.reference, segment dirs, the
+    work directory)."""
     sys.path.insert(0, os.path.join(ROOT, "benchmark"))
     try:
-        from harness import cluster as cluster_mod
         from harness import reference as reference_mod
         from harness import table
     finally:
@@ -493,13 +498,25 @@ def served_ssb_flat(tmp_path_factory):
         ref.add({c: cols[c] for c in ref.columns})
         dirs.append(os.path.join(work, "server_0", "built", f"s{k}"))
         build_segment(schema, cols, dirs[-1], table_config, f"s{k}")
+    return config, traffic, ref.rows(), reference_mod, dirs, work
+
+
+@pytest.fixture(scope="module")
+def served_ssb_flat(ssb_flat_built):
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    try:
+        from harness import cluster as cluster_mod
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmark"))
+    config, traffic, want, reference_mod, dirs, work = ssb_flat_built
     # the deployment's executor, with both kernel tiers in interpret mode
-    config["deployment"]["device_executor"] = DeviceExecutor(
-        mm_mode="interpret")
+    config = dict(config, deployment=dict(
+        config["deployment"],
+        device_executor=DeviceExecutor(mm_mode="interpret")))
     cluster = cluster_mod.Cluster(config, work)
     try:
         cluster.load(dirs, lambda line: None)
-        yield cluster, traffic, ref.rows(), reference_mod
+        yield cluster, traffic, want, reference_mod
     finally:
         cluster.close()
 
@@ -524,6 +541,115 @@ def test_ssb_flat_13_statements_served(served_ssb_flat):
     assert stats["groupby_narrowed_launches"] == 4
     assert stats["groupby_narrow_overflows"] == 0
     assert not any(cluster.failure_counters().values())
+
+
+# ---- both passes over the batch's prepared operands (ISSUE 33) -------------
+
+
+@pytest.fixture(scope="module")
+def flat_engines(ssb_flat_built):
+    """The tiny SSB table under three engines: the prepared form, the
+    per-launch form (the same executor under a byte budget no operand
+    fits) and the host."""
+    _config, traffic, _want, _ref, dirs, _work = ssb_flat_built
+    tables = {"lineorder": [ImmutableSegment(d) for d in dirs]}
+    engines = {"prepared": _engine(tables, mm_mode="interpret"),
+               "perLaunch": _engine(tables, mm_mode="interpret"),
+               "host": _engine(tables)}
+    engines["perLaunch"].device.MAX_CACHED_BYTES = 1
+    sqls = {s["name"]: s["sql"] for s in traffic["statements"]}
+    return engines, sqls, tables
+
+
+def _flat_variants(sqls):
+    q3_2, q4_3 = sqls["q3_2"], sqls["q4_3"]
+    return {
+        "q3_2": q3_2, "q4_3": q4_3,
+        # a filter that leaves no row and that no segment's statistics
+        # can prune: the nation lies in another region
+        "q3_2_no_row": q3_2.replace("WHERE ", "WHERE c_region = 'ASIA' AND "),
+        "q4_3_no_row": q4_3.replace("c_region = 'AMERICA'",
+                                    "c_region = 'AMERICA' AND "
+                                    "s_region = 'EUROPE'"),
+    }
+
+
+@pytest.mark.parametrize("name", ["q3_2", "q4_3", "q3_2_no_row",
+                                  "q4_3_no_row"])
+def test_prepared_narrowed_is_per_launch_is_host(flat_engines, name):
+    """Q3.2 (three one-byte keys, SUM of a column) and Q4.3 (a two-byte
+    key among them, SUM of a - b) on the tiny SSB table: both passes over
+    the batch's operands == both passes over the launch's own == the
+    host, and the launch says which it was."""
+    engines, sqls, _tables = flat_engines
+    sql = _flat_variants(sqls)[name]
+    want, _ = _rows(engines["host"], sql)
+    assert bool(want) == (not name.endswith("no_row"))
+    for form in ("perLaunch", "prepared"):
+        got, resp, spans = _traced(engines[form], sql)
+        assert got == want, (form, name)
+        assert resp.get("numSegmentsOnHost", 0) == 0
+        for phase in ("executor.dispatch", "executor.device_wait"):
+            attrs = spans[phase]
+            assert attrs["groupbyKeySpace"] == "narrowed", (form, attrs)
+            assert attrs["groupbyOperands"] in (
+                ("perLaunch",) if form == "perLaunch"
+                else ("prepared", "built")), (form, attrs)
+        rec = resp["roofline"][0]
+        assert rec["groupbyKeySpace"] == "narrowed"
+        assert (rec["keySpaceLive"] > 0) == bool(want)
+
+
+def test_prepared_narrowed_overflow_is_the_hosts_and_counted(flat_engines):
+    """Q3.2's key space with no nation named: more than 128 live blocks.
+    The host answers, exactly, whichever form the launch took."""
+    engines, sqls, _tables = flat_engines
+    sql = sqls["q3_2"].replace(
+        "c_nation = 'UNITED STATES' AND s_nation = 'UNITED STATES' AND ", "")
+    assert sql != sqls["q3_2"]
+    want, _ = _rows(engines["host"], sql)
+    assert len(want) == 1000
+    for form in ("perLaunch", "prepared"):
+        executor = engines[form].device
+        before = executor.hbm_stats()["groupby_narrow_overflows"]
+        got, resp, spans = _traced(engines[form], sql)
+        assert got == want, form
+        assert resp["numSegmentsOnHost"] == 8
+        assert executor.hbm_stats()["groupby_narrow_overflows"] == before + 1
+        assert spans["executor.dispatch"]["groupbyKeySpace"] == "narrowed"
+        assert spans["executor.device_wait"]["groupbyKeySpace"] == "overflow"
+        assert spans["executor.dispatch"]["groupbyOperands"] in (
+            ("perLaunch",) if form == "perLaunch" else ("prepared", "built"))
+
+
+def test_narrowed_statements_share_a_batchs_operands(flat_engines):
+    """Q3.2 builds the ids of c_city, s_city, d_year and lo_revenue's
+    planes; Q3.3, Q3.4 (same keys, same argument) read them; Q4.3 adds
+    p_brand1's ids and the planes of lo_revenue - lo_supplycost and shares
+    s_city's and d_year's; every launch is counted by its operands."""
+    _engines, sqls, tables = flat_engines
+    engine = _engine(tables, mm_mode="interpret")
+    host = _engines["host"]
+    seen = []
+    for name in ("q3_2", "q3_3", "q3_4", "q4_3", "q4_3"):
+        got, resp = _rows(engine, sqls[name])
+        assert got == _rows(host, sqls[name])[0], name
+        rec = resp["roofline"][0]
+        seen.append((rec["groupbyKeySpace"], rec["groupbyOperands"],
+                     engine.device.groupby_operand_bytes()))
+    unit = seen[0][2] // 6  # three one-byte id columns, three planes
+    assert seen == [("narrowed", "built", 6 * unit),
+                    ("narrowed", "prepared", 6 * unit),
+                    ("narrowed", "prepared", 6 * unit),
+                    ("narrowed", "built", 11 * unit),
+                    ("narrowed", "prepared", 11 * unit)], seen
+    ctx = engine.device.batch_for(tables["lineorder"])
+    assert sorted(k for k in ctx._gb_operands if k.startswith("gk::")) == [
+        "gk::c_city", "gk::d_year", "gk::p_brand1", "gk::s_city"]
+    stats = engine.device.hbm_stats()
+    assert stats["groupby_operand_launches"] == {
+        "prepared": 3, "built": 2, "perLaunch": 0}
+    assert stats["groupby_narrowed_launches"] == 5
 
 
 # ---- the configuration the regime was built for ----------------------------

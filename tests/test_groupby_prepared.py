@@ -203,6 +203,134 @@ def test_ids_in_lanes_at_the_stored_width(engines, tables):
     assert (flat[3 * ctx.pad_to:] == N_KEYS).all()
 
 
+# ---- several key columns, expression arguments (ISSUE 33) -------------------
+
+# key columns by stored width: a uint8 (20 values), b uint16 (300), c 4-bit
+# (5, the opt-in sub-byte tier); a x b x c = 30,000 cells stays dense
+MK_CARDS = {"a": 20, "b": 300, "c": 5}
+MK_CASES = {
+    "two_keys_u8_u16": (("a", "b"), "SUM(rev), COUNT(*)"),
+    "two_keys_u16_4bit": (("b", "c"), "AVG(rev), SUM(cost)"),
+    "three_keys": (("a", "b", "c"), "SUM(rev)"),
+    # rev - cost runs from -89,899 to 59,499: a negative offset, 3 planes
+    "three_keys_expr": (("c", "a", "b"), "SUM(rev - cost), COUNT(*)"),
+    "one_key_expr": (("b",), "SUM(rev - cost), SUM(rev)"),
+}
+
+
+def _mk_sql(case, band):
+    keys, select = MK_CASES[case]
+    ks = ", ".join(keys)
+    return (f"SELECT {ks}, {select} FROM m WHERE disc BETWEEN {band[0]} AND "
+            f"{band[1]} GROUP BY {ks} ORDER BY {ks} LIMIT 40000")
+
+
+@pytest.fixture(scope="module")
+def mk_engines(tmp_path_factory):
+    base = tmp_path_factory.mktemp("multikey_seg")
+    rng = np.random.default_rng(33)
+    cols = {k: rng.integers(0, c, N_ROWS).astype(np.int32)
+            for k, c in MK_CARDS.items()}
+    cols["disc"] = rng.integers(0, 11, N_ROWS).astype(np.int32)
+    cols["rev"] = rng.integers(100, 60_000, N_ROWS).astype(np.int32)
+    cols["cost"] = rng.integers(500, 90_000, N_ROWS).astype(np.int32)
+    for c, (lo, hi) in {"rev": (100, 59_999), "cost": (500, 89_999)}.items():
+        cols[c][0], cols[c][1] = lo, hi  # the metadata bounds
+    schema = Schema.build(
+        name="m", dimensions=[(k, DataType.INT) for k in (*MK_CARDS, "disc")],
+        metrics=[("rev", DataType.INT), ("cost", DataType.INT)])
+    cfg = TableConfig(table_name="m", indexing=IndexingConfig(
+        no_dictionary_columns=["rev", "cost"]))
+    segs = []
+    for i in range(3):
+        sl = slice(i * 10000, (i + 1) * 10000)
+        d = str(base / f"m_s{i}")
+        build_segment(schema, {k: v[sl] for k, v in cols.items()}, d, cfg,
+                      f"m_s{i}")
+        segs.append(ImmutableSegment(d))
+    tables = {"m": segs}
+    engines = {
+        "prepared": _engine(tables, mm_mode="interpret"),
+        "perLaunch": _engine(tables, mm_mode="interpret"),
+        "mm": _engine(tables, mm_mode="interpret", pallas_mode="off"),
+        "xla": _engine(tables, mm_mode="off", pallas_mode="off"),
+        "host": _engine(tables)}
+    engines["perLaunch"].device.MAX_CACHED_BYTES = 1
+    # a batch reads the sub-byte switch when it is made
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PINOT_TPU_SUBBYTE", "1")
+        for name, e in engines.items():
+            if name != "host":
+                e.device.partials_cache_enabled = False
+                e.device.batch_for(segs)
+    return engines, segs
+
+
+@pytest.mark.parametrize("width", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(MK_CASES))
+def test_multikey_and_expression_parity(mk_engines, case, width):
+    """Two and three key columns of mixed stored widths and a column-only
+    expression argument: prepared == per launch == XLA == host, bit for
+    bit, alone and as a cohort of two and of four. The kernel combines
+    the columns' ids in VMEM; the expression's planes are the batch's."""
+    engines, segs = mk_engines
+    keys = MK_CASES[case][0]
+    sqls = [_mk_sql(case, band) for band in BANDS[:width]]
+    want = [_rows(engines["host"], s)[0] for s in sqls]
+    assert len({json.dumps(w) for w in want}) == width and want[0]
+    for name in ("xla", "perLaunch", "mm", "prepared"):
+        e = engines[name]
+        got = [_rows(e, s) for s in sqls] if width == 1 \
+            else _cohort(e, sqls)
+        for s, (rows, _resp), w in zip(sqls, got, want):
+            assert rows == w, (name, s, rows[:3], w[:3])
+        origins = {o for _rows_, resp in got for o in _origins(resp)}
+        # the matmul tier takes one ids operand: several keys are the
+        # launch's there
+        took = name == "prepared" or (name == "mm" and len(keys) == 1)
+        if took:
+            assert origins and origins <= {"prepared", "built"}, origins
+        elif name != "xla":
+            assert origins == {"perLaunch"}, origins
+    ctx = engines["prepared"].device.batch_for(segs)
+    assert ctx.width_plan("c").bits == 4
+    for k in keys:  # one operand a key COLUMN, at its stored width
+        ids, built = ctx.groupby_operand("gk::" + k)
+        assert not built and ids.dtype == {
+            "a": jnp.uint8, "b": jnp.uint16, "c": jnp.uint8}[k]
+    if "expr" in case:
+        planes, built = ctx.groupby_operand(
+            "gv::minus(rev,cost)::-89899::3")
+        assert not built and planes.shape == (3, mm.SUPERBLOCK // 128, 128)
+
+
+def test_key_sets_share_their_columns_operands(mk_engines):
+    """However many key sets name a column, its ids are in HBM once, and
+    an expression's planes once for every statement that sums it."""
+    engines, segs = mk_engines
+    e = _engine({"m": segs}, mm_mode="interpret")
+    e.device.partials_cache_enabled = False
+    seen = []
+    for case in ("two_keys_u8_u16", "three_keys", "three_keys_expr",
+                 "one_key_expr"):
+        _rows_, resp = _rows(e, _mk_sql(case, (2, 9)))
+        seen.append((_origins(resp), e.device.groupby_operand_bytes()))
+    sb = mm.SUPERBLOCK  # the batch pads to one superblock
+    assert seen == [
+        (["built"], sb * (1 + 2 + 2)),      # a (u8), b (u16), rev 2 planes
+        (["built"], sb * (1 + 2 + 2 + 1)),  # ... and c
+        (["built"], sb * (6 + 3)),          # the same ids; rev - cost
+        (["prepared"], sb * (6 + 3)),       # nothing new
+    ], seen
+    ctx = e.device.batch_for(segs)
+    assert sorted(ctx._gb_operands) == [
+        "gk::a", "gk::b", "gk::c", "gv::minus(rev,cost)::-89899::3",
+        "gv::rev::100::2"]
+    stats = e.device.hbm_stats()
+    assert stats["groupby_operand_bytes"] == sb * 9
+    assert stats["groupby_operand_launches"]["perLaunch"] == 0
+
+
 def test_second_batch_builds_its_own_and_eviction_frees_them(tables):
     e = _engine(tables, mm_mode="interpret")
     dev = e.device
@@ -223,6 +351,21 @@ def test_second_batch_builds_its_own_and_eviction_frees_them(tables):
     assert stats["groupby_operand_bytes"] == sum(per_batch)
     assert dev.groupby_operand_bytes() == sum(per_batch)
     assert stats["resident_bytes"] > sum(per_batch)
+    host = _engine(tables)
+    # a second and a third key set over the same columns: each column's
+    # ids are in HBM once (disc, one byte a row, is the only new operand)
+    for keys, origin in (("k, disc", "built"), ("disc, k", "prepared"),
+                         ("disc", "prepared")):
+        sql = (f"SELECT {keys}, SUM(rev) FROM t WHERE disc < 9 "
+               f"GROUP BY {keys} ORDER BY {keys} LIMIT 30000")
+        rows, resp = _rows(e, sql)
+        assert _origins(resp) == [origin], keys
+        assert rows == _rows(host, sql)[0]
+    stats = dev.hbm_stats()
+    assert sorted(b["groupby_operand_bytes"] for b in stats["batches"]) \
+        == [mm.SUPERBLOCK * 4, mm.SUPERBLOCK * 5]
+    assert stats["groupby_operand_bytes"] == dev.groupby_operand_bytes() \
+        == mm.SUPERBLOCK * 9
     # the operands die with their batch
     for segs in tables.values():
         assert dev.evict_segment_dir(segs[0].dir) == 1
@@ -230,7 +373,21 @@ def test_second_batch_builds_its_own_and_eviction_frees_them(tables):
     assert dev.groupby_operand_bytes() == 0
 
 
-def test_over_the_byte_budget_the_per_launch_path_answers(tables):
+OVER_BUDGET = {
+    # another value column's planes
+    "planes": "SELECT k, SUM(neg), COUNT(*) FROM t WHERE disc BETWEEN 4 AND 6 "
+              "GROUP BY k ORDER BY k LIMIT 2000",
+    # a second key column's ids
+    "ids": "SELECT k, disc, SUM(rev), COUNT(*) FROM t WHERE disc BETWEEN 4 "
+           "AND 6 GROUP BY k, disc ORDER BY k, disc LIMIT 30000",
+    # an expression's planes
+    "expression": "SELECT k, SUM(rev - p1), COUNT(*) FROM t WHERE disc "
+                  "BETWEEN 4 AND 6 GROUP BY k ORDER BY k LIMIT 2000",
+}
+
+
+@pytest.mark.parametrize("missing", sorted(OVER_BUDGET))
+def test_over_the_byte_budget_the_per_launch_path_answers(tables, missing):
     e = _engine(tables, mm_mode="interpret")
     e.device.partials_cache_enabled = False
     sql = _sql("SUM(rev), COUNT(*)", (4, 6))
@@ -239,7 +396,7 @@ def test_over_the_byte_budget_the_per_launch_path_answers(tables):
     with_operands = e.device.resident_bytes()
     # a cap the batch already fills: the next plan's operands do not fit
     e.device.MAX_CACHED_BYTES = with_operands
-    sql2 = _sql("SUM(neg), COUNT(*)", (4, 6))
+    sql2 = OVER_BUDGET[missing]
     rows2, resp2 = _rows(e, sql2)
     assert _origins(resp2) == ["perLaunch"]
     assert rows2 == _rows(_engine(tables), sql2)[0]
@@ -270,8 +427,8 @@ def test_explain_analyze_and_spans_say_where_operands_came_from(engines):
 
 DECLINED = {
     "float argument": "SUM(fv)",
-    "expression argument": "SUM(rev + 1)",
-    "two group columns": None,
+    # a literal is a launch parameter: the value is not the batch's alone
+    "expression with a literal": "SUM(rev + 1)",
     "an agg the kernel does not take": "SUM(rev), MIN(rev)",
 }
 
@@ -296,10 +453,8 @@ def test_what_the_prepared_form_declines_or_shares(tmp_path, why):
         no_dictionary_columns=["rev", "fv"]))
     build_segment(schema, cols, str(tmp_path / "s0"), cfg, "s0")
     segs = {"t": [ImmutableSegment(str(tmp_path / "s0"))]}
-    sql = ("SELECT k, j, SUM(rev) FROM t WHERE disc < 6 GROUP BY k, j "
-           "ORDER BY k, j LIMIT 300") if DECLINED[why] is None else (
-        f"SELECT k, {DECLINED[why]} FROM t WHERE disc < 6 GROUP BY k "
-        "ORDER BY k LIMIT 60")
+    sql = (f"SELECT k, {DECLINED[why]} FROM t WHERE disc < 6 GROUP BY k "
+           "ORDER BY k LIMIT 60")
     e = _engine(segs, mm_mode="interpret")
     rows, resp = _rows(e, sql)
     assert rows == _rows(_engine(segs), sql)[0]
@@ -311,7 +466,8 @@ def test_plan_follows_the_template():
     """plan_prepared_groupby on hand-built templates: the route, the cols
     keys, and the conditions it declines on."""
     widths = {"v": ("<u2", 0, True, "<i4"), "k": ("<u2", 0, False, ""),
-              "dv::d": ("|u1", 0, False, "<i8")}
+              "j": ("|u1", 0, False, ""), "h": ("|u1", 4, False, ""),
+              "dv::d": ("|u1", 0, False, "<i8"), "f": ("<f4", 0, False, "")}
     sums = (("sum", ("raw", "v"), (2, 256)), ("count", None, None),
             ("avg", ("dictval", "d"), (1, 256)))
 
@@ -321,25 +477,51 @@ def test_plan_follows_the_template():
         return plan_prepared_groupby(template, widths, n, mm_mode, pallas,
                                      {0: 7, 2: -3})
 
-    assert plan() == ("pallas", "gk::k", (
-        (0, "gv::v::7::2", 2), (2, "gv::dv::d::-3::1", 1)))
+    planes = ((0, "gv::v::7::2", 2), (2, "gv::dv::d::-3::1", 1))
+    assert plan() == ("pallas", ("gk::k",), planes)
     assert plan(pallas="off")[0] == "mm"
-    assert plan(aggs=(("count", None, None),)) == ("pallas", "gk::k", ())
+    assert plan(aggs=(("count", None, None),)) == ("pallas", ("gk::k",), ())
     assert plan(mm_mode="off", pallas="off") is None
-    assert plan(group=("k", "j"), cards=(2000, 3)) is None
     assert plan(shape="groupby_sorted") is None
+    # several key columns: one ids operand a COLUMN, in the template's
+    # order, whatever their stored widths (u16, u8, 4-bit)
+    assert plan(group=("k", "j"), cards=(2000, 3)) == (
+        "pallas", ("gk::k", "gk::j"), planes)
+    assert plan(group=("j", "k", "h"), cards=(3, 2000, 5)) == (
+        "pallas", ("gk::j", "gk::k", "gk::h"), planes)
+    # ... on the Pallas route only: the matmul kernel takes one ids operand
+    assert plan(group=("k", "j"), cards=(2000, 3), pallas="off") is None
+    # the narrowed form: both of its passes read the operands
+    narrow = dict(group=("k", "j", "h"), cards=(2000, 200, 5),
+                  shape="groupby_narrow")
+    assert plan(**narrow) == (
+        "pallas", ("gk::k", "gk::j", "gk::h"), planes)
+    assert plan(**narrow, pallas="off") is None
+    # an expression over integer columns alone is the batch's: its planes
+    # are keyed by its canonical form
+    expr = ("minus", ("raw", "v"), ("abs", ("dictval", "d")))
+    assert plan(aggs=(("sum", expr, (3, 256)),)) == (
+        "pallas", ("gk::k",), ((0, "gv::minus(v,abs(dv::d))::7::3", 3),))
+    # ... one with a literal (a launch parameter), a float column, a
+    # function that may leave the integers, or a cast is the launch's
+    for arg in (("plus", ("raw", "v"), ("lit", "pr0")), ("raw", "f"),
+                ("plus", ("raw", "v"), ("raw", "f")),
+                ("divide", ("raw", "v"), ("dictval", "d")),
+                ("cast", ("raw", "v"), "LONG")):
+        assert plan(aggs=(("sum", arg, (3, 256)),)) is None, arg
     # below the kernels' minimum rows the XLA scatter runs: nothing to
     # prepare for (interpret mode has no such minimum)
     assert plan(mm_mode="tpu", pallas="tpu", n=1000) is None
     assert plan(mm_mode="tpu", pallas="tpu") is not None
     # an unknown range leaves that agg to the exact scatter, as per launch
     unknown = (("sum", ("raw", "v"), (None, None)), ("count", None, None))
-    assert plan(aggs=unknown) == ("pallas", "gk::k", ())
+    assert plan(aggs=unknown) == ("pallas", ("gk::k",), ())
     # a group count whose row tile cannot hold whole 8-bit tiles
     big = 3_000_000
     assert ps.sums_blk(big, 3) < mm.BLK
     assert not mm.prepared_tile_ok(2048) and mm.prepared_tile_ok(4096)
     assert plan(cards=(big,)) is None
+    assert plan(group=("k", "j"), cards=(big // 3, 3)) is None
 
 
 @pytest.mark.parametrize("width", [1, 2])
@@ -373,4 +555,4 @@ def test_sub_byte_filter_and_key_columns(tmp_path, monkeypatch, width):
     ids, built = ctx.groupby_operand("gk::k")
     assert not built and ids.dtype == jnp.uint8
     assert {k[6] for k in e.device._pipelines} == {
-        ("pallas", "gk::k", ((0, "gv::rev::100::2", 2),))}
+        ("pallas", ("gk::k",), ((0, "gv::rev::100::2", 2),))}

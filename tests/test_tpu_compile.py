@@ -212,10 +212,11 @@ BANDS_WIDTHS = {"lo_revenue": ("<i4", 0, False, ""),
 N_BATCH = BATCH[0] * BATCH[1]  # a whole number of superblocks: no padding
 
 
-def _big_results(hlo_text: str, op: str = "") -> list:
+def _big_results(hlo_text: str, op: str = "", rows: int = 0) -> list:
     """(dtype, instruction line) of every non-parameter instruction whose
-    result has at least one element a row of the batch; ``op`` keeps one
-    kind of instruction."""
+    result has at least one element a row of the batch (``rows``: of
+    another batch than the 8-segment one); ``op`` keeps one kind of
+    instruction."""
     import re
 
     out = []
@@ -226,7 +227,7 @@ def _big_results(hlo_text: str, op: str = "") -> list:
         n = 1
         for d in m.group(2).split(","):
             n *= int(d)
-        if n >= N_BATCH:
+        if n >= (rows or N_BATCH):
             out.append((m.group(1), line.strip()[:160]))
     return out
 
@@ -255,7 +256,7 @@ def test_pipeline_prepared_groupby_real_batch(spec, width):
     of an 8-bit plane costs this compiler three to four minutes."""
     prepared = dev.plan_prepared_groupby(
         BANDS_TEMPLATE, BANDS_WIDTHS, N_BATCH, "tpu", "tpu", {0: 100})
-    assert prepared == ("pallas", "gk::lo_suppkey",
+    assert prepared == ("pallas", ("gk::lo_suppkey",),
                         ((0, "gv::lo_revenue::100::3", 3),))
     fn = dev.build_pipeline(BANDS_TEMPLATE, mm_mode="tpu",
                             sorted_hll_ok=True, widths=BANDS_WIDTHS,
@@ -322,7 +323,7 @@ NARROWED = {
                 "lo_revenue": ("<i4", 0, False, ""),
                 "s_city": ("|u1", 0, False, ""),
                 "s_nation": ("|u1", 0, False, "")},
-        zones=("c_nation", "d_year", "s_nation"),
+        zones=("c_nation", "d_year", "s_nation"), offsets={0: 81_000},
         params={"pr0": ((), "int32"), "pr1": ((), "int32"),
                 "pr2": ((), "int32"), "pr3": ((), "int32")}),
     "q4_3": dict(
@@ -344,10 +345,29 @@ NARROWED = {
                 "s_city": ("|u1", 0, False, ""),
                 "s_nation": ("|u1", 0, False, "")},
         zones=("c_region", "d_year", "p_category", "s_nation"),
+        offsets={0: -44_941},
         params={"pr0": ((), "int32"), "pr1": ((), "int32"),
                 "pr2": ((2,), "int32"), "pr3": ((), "int32"),
                 "fo::lo_supplycost": ((), "int32")}),
 }
+N_FLAT = FLAT_BATCH[0] * FLAT_BATCH[1]
+N_FLAT_PAD = -(-N_FLAT // mm.SUPERBLOCK) * mm.SUPERBLOCK
+
+
+def _prepared_case(spec, case):
+    """(plan, cols with the plan's operands beside the (S, L) planes)."""
+    template, widths = case["template"], case["widths"]
+    prepared = dev.plan_prepared_groupby(
+        template, widths, N_FLAT, "tpu", "tpu", case["offsets"])
+    assert prepared is not None and prepared[0] == "pallas"
+    cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
+    for key, col in zip(prepared[1], template[2]):
+        cols[key] = spec((N_FLAT_PAD // 128, 128), widths[col][0])
+    for _i, key, nplanes in prepared[2]:
+        cols[key] = spec((nplanes, N_FLAT_PAD // 128, 128), "uint8")
+    return prepared, cols
+
+
 # the first answer of these took the chip's host 304 to 366 s (PR 31): the
 # trim's sort over the whole cartesian table (437,500 and 1,750,000 cells;
 # compiled for the described v5e in this sandbox: 32 s at 7,000 entries,
@@ -359,23 +379,31 @@ NARROWED_COMPILE_LIMIT_S = 120
 
 
 @pytest.mark.parametrize("width", [1, 2])
+@pytest.mark.parametrize("operands", ["perLaunch", "prepared"])
 @pytest.mark.parametrize("name", list(NARROWED))
-def test_pipeline_narrowed_groupby_real_batch(spec, name, width):
+def test_pipeline_narrowed_groupby_real_batch(spec, name, operands, width):
     """The executor's own programs: solo as it launches it (the block-skip
     form, its dense branch under lax.cond, then trim and pack) and the
     cohort of two (the dense form under vmap, trimmed member by member:
     the batched form of the trim's sort took this compiler 398 s at 4,096
     entries). Two kernel calls a branch (blocks, then slots), no table of
-    the cartesian product, and a compile inside the limit."""
+    the cartesian product, and a compile inside the limit. ``prepared``
+    (ISSUE 33): both passes of the dense branch read the batch's operands
+    - pass 1 shifts the combined id to its block in VMEM, pass 2 compares
+    it against the live table; the solo form's gathered branch keeps the
+    per-launch preparation."""
     import time
 
     case = NARROWED[name]
     template, widths = case["template"], case["widths"]
+    cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
+    prepared = None
+    if operands == "prepared":
+        prepared, cols = _prepared_case(spec, case)
     executor = dev.DeviceExecutor(mm_mode="tpu", pallas_mode="tpu")
     entry = executor._pipeline_entry(
         template, template[4], False, True, widths,
-        tuple(sorted(widths.items())), case["trim"], "tpu", None)
-    cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
+        tuple(sorted(widths.items())), case["trim"], "tpu", prepared)
     n_seg = FLAT_BATCH[0]
     params = {"off0": spec((), "int64"), "ps_alive": spec((n_seg,), "bool"),
               "tr_k": spec((), "int32"),
@@ -402,3 +430,102 @@ def test_pipeline_narrowed_groupby_real_batch(spec, name, width):
     assert f"[{cells}]" not in text and f"[{cells + 1}]" not in text
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 << 30, mem
     assert seconds < NARROWED_COMPILE_LIMIT_S, seconds
+
+
+# ---- multi-key and expression group-bys over prepared operands (ISSUE 33) ---
+
+# Q3.1 and Q4.2 of the flat mix as the executor plans them: dense, three key
+# columns at one byte each; Q4.2 sums lo_revenue - lo_supplycost
+PREPARED_DENSE = {
+    "q3_1": dict(
+        template=(
+            "groupby",
+            ("and", ("eq_dict", "c_region", "pr0"),
+             ("eq_dict", "s_region", "pr1"),
+             ("range_dict", "d_year", "pr2", "pr3")),
+            ("c_nation", "s_nation", "d_year"), (25, 25, 7),
+            (("sum", ("raw", "lo_revenue"), (3, None)),), 0, False),
+        widths={"c_nation": ("|u1", 0, False, ""),
+                "c_region": ("|u1", 0, False, ""),
+                "d_year": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "s_nation": ("|u1", 0, False, ""),
+                "s_region": ("|u1", 0, False, "")},
+        offsets={0: 81_000},
+        params={"pr0": ((), "int32"), "pr1": ((), "int32"),
+                "pr2": ((), "int32"), "pr3": ((), "int32")}),
+    "q4_2": dict(
+        template=(
+            "groupby",
+            ("and", ("eq_dict", "c_region", "pr0"),
+             ("eq_dict", "s_region", "pr1"), ("in_dict", "d_year", "pr2", 2),
+             ("in_dict", "p_mfgr", "pr3", 2)),
+            ("d_year", "s_nation", "p_category"), (7, 25, 25),
+            (("sum", ("minus", ("raw", "lo_revenue"),
+                      ("raw", "lo_supplycost")), (3, None)),), 0, False),
+        widths={"c_region": ("|u1", 0, False, ""),
+                "d_year": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "lo_supplycost": ("<u2", 0, True, "<i4"),
+                "p_category": ("|u1", 0, False, ""),
+                "p_mfgr": ("|u1", 0, False, ""),
+                "s_nation": ("|u1", 0, False, ""),
+                "s_region": ("|u1", 0, False, "")},
+        offsets={0: -44_941},
+        params={"pr0": ((), "int32"), "pr1": ((), "int32"),
+                "pr2": ((2,), "int32"), "pr3": ((2,), "int32"),
+                "fo::lo_supplycost": ((), "int32")}),
+}
+@pytest.mark.parametrize("width", [1, 2])
+@pytest.mark.parametrize("name", list(PREPARED_DENSE))
+def test_pipeline_prepared_multikey_real_batch(spec, name, width):
+    """Q3.1 and Q4.2 over prepared operands: one id ref a key column,
+    widened, multiplied and masked in VMEM (mixed widths and the int32
+    multiply lower), the expression's planes read as a column's are.
+    One kernel call, nothing row-scale in bf16 or 64 bits, and the
+    mask's relayout the only one."""
+    case = PREPARED_DENSE[name]
+    prepared, cols = _prepared_case(spec, case)
+    assert len(prepared[1]) == 3 and len(prepared[2]) == 1
+    if name == "q4_2":
+        assert prepared[2][0][1] == \
+            "gv::minus(lo_revenue,lo_supplycost)::-44941::3"
+    fn = dev.build_pipeline(case["template"], mm_mode="tpu",
+                            sorted_hll_ok=True, widths=case["widths"],
+                            pallas_mode="tpu", prepared=prepared)
+    n_seg = FLAT_BATCH[0]
+    params = {"off0": spec((), "int64"), "ps_alive": spec((n_seg,), "bool"),
+              **{k: spec(*v) for k, v in case["params"].items()}}
+    if width > 1:
+        params = {k: spec((width,) + v.shape, v.dtype)
+                  for k, v in params.items()}
+        solo = fn
+
+        def fn(c, nd, pstack):
+            return jax.vmap(lambda p: solo(c, nd, p))(pstack)
+
+    compiled, _mem = _compile(fn, cols, spec((n_seg,), "int32"), params)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    big = _big_results(text, rows=N_FLAT)
+    assert not [b for b in big if b[0] in ("bf16", "s64", "u64", "f64")], big
+    assert not _big_results(text, "reshape", rows=N_FLAT)
+
+
+def test_expression_planes_builder_real_batch(spec):
+    """The once-a-batch builder of lo_revenue - lo_supplycost's planes:
+    the expression over the stored planes, one relayout, the byte split."""
+    case = PREPARED_DENSE["q4_2"]
+    widths = case["widths"]
+    leaves = ("lo_revenue", "lo_supplycost")
+    compiled, _ = _compile(
+        lambda c, fo: dev._expr_planes(
+            c, fo, argt=case["template"][4][0][1],
+            wsig=tuple((k, widths[k]) for k in leaves), off=-44_941,
+            nplanes=3),
+        {k: spec(FLAT_BATCH, widths[k][0]) for k in leaves},
+        {"fo::lo_supplycost": spec((), "int32")})
+    text = compiled.as_text()
+    assert not _big_results(text, "reshape", rows=N_FLAT)
+    copies = _big_results(text, "copy", rows=N_FLAT)
+    assert len(copies) == 1 and "3,97664,128]" in copies[0][1], copies
