@@ -57,6 +57,7 @@ class QueryQuotaManager:
         # three registry reads per query (ISSUE 10 hit-latency budget)
         self._rates: dict = {}
         self._rates_gen = None
+        self._now = time.time  # the buckets' clock (tests freeze it)
 
     @staticmethod
     def _base_name(table: str) -> str:
@@ -96,7 +97,7 @@ class QueryQuotaManager:
         rate = self._rate(base, gen)
         if rate is None:
             return True
-        now = time.time()
+        now = self._now()
         with self._lock:
             tokens, last, _ = self._buckets.get(base, (rate, now, rate))
             tokens = min(rate, tokens + (now - last) * rate)
@@ -672,9 +673,9 @@ class Broker:
         self.hedge_delay_s = conf.get_float(
             "pinot.broker.hedging.delay.ms", 0.0) / 1e3
         # broker result cache (ISSUE 10, broker/result_cache.py): OFF by
-        # default — partial-result and chaos semantics (tests and the
-        # fault bench deliberately repeat queries against faulted
-        # replicas) must stay exact unless the operator opts in via
+        # default — partial-result and chaos semantics (the fault tests
+        # deliberately repeat queries against faulted replicas) must
+        # stay exact unless the operator opts in via
         # pinot.broker.resultcache.enabled / the constructor / SET
         # useResultCache=true
         from pinot_tpu.broker.result_cache import BrokerResultCache
@@ -744,7 +745,7 @@ class Broker:
         if self.result_cache_default:
             # cache-enabled brokers only: the process-global registry keys
             # gauges by (name, broker_id), and a cache-OFF broker sharing
-            # this id (the common probe/bench pattern) would overwrite a
+            # this id (the common probe pattern) would overwrite a
             # live cache's gauges — then delete them on its own close().
             # Two cache-ENABLED brokers in one process still need distinct
             # broker ids, like servers do for the PR-7 leak guard.
@@ -2574,7 +2575,7 @@ class Broker:
         fully_pruned = []  # fallback: keep one segment so reduce sees a shape
         # replica-group attribution (ISSUE 10 satellite): how many groups
         # this query's routing touched + the chosen group's load score, so
-        # the query log and bench can attribute tail latency to routing
+        # the query log can attribute tail latency to routing
         rg_queried = 0
         rg_load_score = None
         rg_name = None

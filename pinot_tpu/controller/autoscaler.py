@@ -27,7 +27,7 @@ survey's missing elasticity leg built on our registry/heartbeat seams.
 
 Deployment wiring: ``spawn_fn() -> instance_id | None`` and
 ``drain_fn(instance_id) -> bool`` abstract HOW servers start/stop —
-in-process ``ServerInstance`` for tests/bench, ``admin start-server``
+in-process ``ServerInstance`` for tests, ``admin start-server``
 subprocesses or a k8s scale call in production. Attach via
 ``Controller.attach_autoscaler``; the controller's periodic loop runs
 ``tick()`` on the global-lead holder only, and every tick publishes the

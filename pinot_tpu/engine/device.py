@@ -785,26 +785,6 @@ def _with_time_partial(name: str, outs: dict, k: str, present):
             "time": t}
 
 
-def amortized_launch_time(timed, base_iters: int = 8,
-                          target_s: float = 0.6, max_iters: int = 256) -> float:
-    """Per-launch device seconds from a ``timed(k)`` closure (k launches +
-    one token fetch). The host<->device link's round-trip jitter
-    contaminates a fixed-iteration estimate for SHORT kernels, so the
-    iteration count adapts until the amortized span dwarfs the jitter."""
-    import time as _time  # noqa: F401 — callers' closures time themselves
-
-    timed(1)  # warm (compile cache hit; steady-state dispatch)
-    t1 = min(timed(1) for _ in range(3))
-    tn = timed(base_iters)
-    per = max(1e-6, (tn - t1) / (base_iters - 1))
-    if (base_iters - 1) * per < target_s:
-        iters = int(min(max_iters, max(base_iters, round(target_s / per))))
-        if iters > base_iters:
-            tn = timed(iters)
-            per = max(0.0, (tn - t1) / (iters - 1))
-    return per
-
-
 def _is_f64(dt) -> bool:
     return np.dtype(dt) == np.float64
 
@@ -1636,7 +1616,7 @@ class DeviceExecutor:
         self.inflight = 0            # launches between dispatch and fetch
         self._launch_ids = itertools.count(1)  # one per device launch
         self.coalescer = LaunchCoalescer()
-        # cumulative host-link observability (bench reads deltas per query)
+        # cumulative host-link observability
         self.fetch_bytes_total = 0
         self.fetch_leaves_total = 0
         # device-resident per-template partials cache (sub-RTT serving): a
@@ -1681,8 +1661,7 @@ class DeviceExecutor:
         _EXECUTORS.add(self)
         # batch-LRU / HBM observability: cache hit/miss/eviction counters
         # plus per-batch resident bytes and bytes the width planning saved
-        # (hbm_stats — surfaced through server /metrics gauges and bench
-        # detail.narrow)
+        # (hbm_stats — surfaced through server /metrics gauges)
         self.batch_hits = 0
         self.batch_misses = 0
         self.batch_evictions = 0
@@ -1714,17 +1693,10 @@ class DeviceExecutor:
         # kernel roofline accounting (ISSUE 11): per-pipeline-label
         # aggregates of the static bytes-moved cost model (ColPlan-width
         # column planes, block-skip gather ratio, trimmed fetch bytes)
-        # against the measured kernel/link wall — achieved GB/s vs the
-        # per-process HBM peak probe (ops/roofline.py), surfaced through
-        # hbm_stats()["roofline"], the deviceKernelGbps histogram, and
-        # per-query IntermediateResult.roofline records
+        # against the measured kernel/link wall — achieved GB/s, surfaced
+        # through hbm_stats()["roofline"], the deviceKernelGbps histogram,
+        # and per-query IntermediateResult.roofline records
         self._roofline: dict = {}
-        # last-launch capture for kernel profiling (bench breakdown):
-        # (pipeline, cols, n_docs, params, bytes_in). OPT-IN: retaining
-        # the launch pins a whole batch's HBM past the batch cache's
-        # eviction budget, so production executes must not capture it.
-        self.profile_enabled = False
-        self._last_launch = None
         self.last_get_wait_s = None
         # device launch/fetch latency histograms ride the server registry
         # (ISSUE 7: the hot timers share ONE histogram-backed truth)
@@ -1741,36 +1713,6 @@ class DeviceExecutor:
         # NOTE: predicate-literal device caching lives in params._slot —
         # keyed on host bytes BEFORE upload (keying device arrays here
         # would cost a blocking device→host read per literal)
-
-    def profile_last_launch(self, iters: int = 8):
-        """Amortized pure-DEVICE time of the last executed pipeline:
-        dispatch the identical launch ``iters`` times and fetch a TINY
-        token that depends on the final launch — on a remote link
-        ``block_until_ready`` may return before the device finishes
-        (completion is only observable through device_get), and async
-        dispatches pipeline, so
-        (T_iters - T_1) / (iters - 1) isolates per-launch kernel time
-        from the round-trip floor. Returns (kernel_seconds, bytes_read)
-        or None when nothing was captured."""
-        import time as _time
-
-        if self._last_launch is None:
-            return None
-        pipeline, cols, n_docs, params, bytes_in = self._last_launch
-        token = jax.jit(
-            lambda o: sum(jnp.sum(v.reshape(-1)[:1].astype(jnp.float32))
-                          for v in o.values()))
-
-        def timed(k):
-            outs = None
-            t0 = _time.perf_counter()
-            for _ in range(k):
-                outs = pipeline(cols, n_docs, params)
-            jax.device_get(token(outs))
-            return _time.perf_counter() - t0
-
-        kernel_s = amortized_launch_time(timed, iters)
-        return kernel_s, bytes_in
 
     # cheap static check (EXPLAIN backend display)
     def supports(self, q: QueryContext) -> bool:
@@ -2202,9 +2144,9 @@ class DeviceExecutor:
         buffer.
 
         ``flight``: the launch's roofline flight dict (None = no
-        accounting, e.g. the bench's profile captures); filled with the
-        per-flight record via _note_flight after the unpack. ``attrs``:
-        what the members' traces say of this launch (``launchId``,
+        accounting: a prebuilt cohort program's first run); filled with
+        the per-flight record via _note_flight after the unpack.
+        ``attrs``: what the members' traces say of this launch (``launchId``,
         ``cohortSize``, ``cohortPadded``; ``partialsCacheHit`` where
         nothing was launched); rides the closure as ``resolve.stamp``
         with the wait's span, so the member that fetched can add its own
@@ -2225,9 +2167,7 @@ class DeviceExecutor:
             _t_kernel = _time.perf_counter()
             with trace_span("executor.link"):
                 bufs = jax.device_get(bufs_dev)
-            # blocking wait = link round trip + kernel; bench subtracts it
-            # from wall time for a MEASURED host_ms (floor-subtraction
-            # overstated host work by the link's RTT variance)
+            # blocking wait = link round trip + kernel
             _t_link = _time.perf_counter()
             wait = _t_link - _t_get
             with trace_span("executor.unpack"):
@@ -2263,7 +2203,7 @@ class DeviceExecutor:
         into one-row buckets per literal-free query shape). The Pallas
         scatter tier and the fused filter+gather+aggregate form carry
         their own suffixes so hbm_stats()["roofline"] and EXPLAIN
-        ANALYZE's %-of-HBM-peak line attribute each kernel correctly."""
+        ANALYZE's KERNEL line attribute each kernel correctly."""
         label = template[0]
         if blockskip:
             label += "+bskip"
@@ -2292,10 +2232,8 @@ class DeviceExecutor:
         modeled bytes (column planes at their ColPlan widths, data planes
         scaled by the block-skip gather ratio the kernel reported, plus
         the packed fetch buffer) over the measured kernel wall → achieved
-        GB/s, compared against the once-probed HBM peak. Cache hits (no
-        kernel ran) count separately and never feed the GB/s histogram."""
-        from pinot_tpu.ops import roofline as rl
-
+        GB/s. Cache hits (no kernel ran) count separately and never feed
+        the GB/s histogram."""
         try:
             cache_hit = bool(flight.get("cache_hit"))
             ratio = 1.0
@@ -2334,13 +2272,6 @@ class DeviceExecutor:
             if not cache_hit and kernel_s > 1e-9:
                 gbps = bytes_moved / kernel_s / 1e9
                 rec["gbps"] = round(gbps, 3)
-                # the probe runs ONCE per process, lazily, on the first
-                # accounted flight (~tens of ms; warm queries never pay)
-                peak = rl.hbm_peak_gbps()
-                pct = rl.pct_of_peak(gbps, peak)
-                if pct is not None:
-                    rec["peakGbps"] = round(peak, 1)
-                    rec["pctOfPeak"] = pct
             flight["record"] = rec
             with self._lock:
                 agg = self._roofline.setdefault(
@@ -2370,14 +2301,9 @@ class DeviceExecutor:
 
     def roofline_stats(self) -> dict:
         """Per-pipeline roofline snapshot: modeled bytes / kernel wall →
-        achieved GB/s per label, against the probed peak (None until the
-        first accounted flight triggers the probe — reading stats never
-        spends device time on the probe itself)."""
-        from pinot_tpu.ops import roofline as rl
-
+        achieved GB/s per label."""
         with self._lock:
             aggs = {k: dict(v) for k, v in self._roofline.items()}
-        peak = rl.peak_if_probed()
         kernels = {}
         for label, agg in aggs.items():
             entry = dict(agg)
@@ -2386,12 +2312,8 @@ class DeviceExecutor:
             if agg["kernel_ms"] > 0:
                 gbps = agg["bytes_moved"] / (agg["kernel_ms"] / 1e3) / 1e9
                 entry["gbps"] = round(gbps, 3)
-                pct = rl.pct_of_peak(gbps, peak)
-                if pct is not None:
-                    entry["pct_of_peak"] = pct
             kernels[label] = entry
-        return {"peak_gbps": round(peak, 1) if peak else None,
-                "kernels": kernels}
+        return {"kernels": kernels}
 
     # ---- template build --------------------------------------------------
     def _agg_template(self, i: int, a: Expression, ctx: BatchContext, params, counter):
@@ -2585,7 +2507,6 @@ class DeviceExecutor:
         # to be thrown away
         opts = q.options_ci()
         cacheable = (self.partials_cache_enabled
-                     and not self.profile_enabled
                      and bool_option(opts, "usepartialscache", None)
                      is not False)
         # feedback-driven plan advisor (engine/advisor.py): keyed by the
@@ -2595,8 +2516,7 @@ class DeviceExecutor:
         # bit-exact against advisor-on by construction.
         adv_key = None
         adv_notes: list = []
-        if self.advisor is not None and not self.profile_enabled \
-                and advisor_enabled(opts):
+        if self.advisor is not None and advisor_enabled(opts):
             from pinot_tpu.broker.querylog import template_key
 
             adv_key = template_key(q)
@@ -2899,14 +2819,12 @@ class DeviceExecutor:
             template, widths, fused, pmode, ctx.S * ctx.pad_to)
         if tpl_box is not None and len(tpl_box) > 1:
             tpl_box[1] = pmode if routes_pallas else "off"
-        # roofline flight (ISSUE 11): always-on except under profile
-        # capture (the bench's amortized kernel probe re-dispatches the
-        # same launch and would pollute the per-query aggregates)
-        flight = None if self.profile_enabled else self._new_flight(
+        # roofline flight (ISSUE 11): always on
+        flight = self._new_flight(
             self._pipeline_label(template, use_bs, trim,
                                  pallas=routes_pallas, fused=fused),
             fused=fused)
-        if flight is not None and adv_key is not None:
+        if adv_key is not None:
             # _note_flight's observation hook: measured skip selectivity
             # and per-rung GB/s feed the template's memo at resolve time
             flight["adv_key"] = adv_key
@@ -2931,8 +2849,7 @@ class DeviceExecutor:
             hit = self._partials_get(cache_key)
             if hit is not None:
                 bufs_dev, clayout = hit
-                if flight is not None:
-                    flight["cache_hit"] = True
+                flight["cache_hit"] = True
                 resolve = self._make_resolve(
                     bufs_dev, clayout, flight,
                     attrs={"partialsCacheHit": True})
@@ -2978,8 +2895,7 @@ class DeviceExecutor:
         if shape == "groupby_narrow":
             with self._lock:
                 self.groupby_narrowed_launches += 1
-        if flight is not None:
-            flight["origin"] = origin
+        flight["origin"] = origin
         if os.environ.get("PINOT_TPU_WIDTH_AUDIT", "") not in ("", "0"):
             _width_audit(ctx, cols, widths)
 
@@ -2990,23 +2906,22 @@ class DeviceExecutor:
             cols, n_docs, params, _ = pad_to_multiple(
                 cols, n_docs, params, self.mesh.devices.size
             )
-        if flight is not None:
-            # static cost-model inputs: plane bytes at their ColPlan
-            # widths (the arrays ARE stored narrow), split data vs zone —
-            # the block-skip form reads zone planes fully but data planes
-            # only for gathered blocks (_note_flight applies the ratio
-            # the kernel reports)
-            # with prepared operands the dense form reads them and the
-            # filter's columns, not the (S, L) value and key planes
-            read = None if prepared is None else \
-                self._needed_columns(filter_tpl)
-            for ck, cv in cols.items():
-                nb = int(getattr(cv, "nbytes", 0))
-                if ck.startswith((bs_ops.ZLO, bs_ops.ZHI)):
-                    flight["zone_bytes"] += nb
-                elif read is None or ck in read \
-                        or ck.startswith(_GB_OPERAND_PREFIXES):
-                    flight["data_bytes"] += nb
+        # static cost-model inputs: plane bytes at their ColPlan widths
+        # (the arrays ARE stored narrow), split data vs zone — the
+        # block-skip form reads zone planes fully but data planes only for
+        # gathered blocks (_note_flight applies the ratio the kernel
+        # reports)
+        # with prepared operands the dense form reads them and the
+        # filter's columns, not the (S, L) value and key planes
+        read = None if prepared is None else \
+            self._needed_columns(filter_tpl)
+        for ck, cv in cols.items():
+            nb = int(getattr(cv, "nbytes", 0))
+            if ck.startswith((bs_ops.ZLO, bs_ops.ZHI)):
+                flight["zone_bytes"] += nb
+            elif read is None or ck in read \
+                    or ck.startswith(_GB_OPERAND_PREFIXES):
+                flight["data_bytes"] += nb
 
         # ONE packed buffer crosses the host link: device_get fetches tree
         # leaves serially, so on a high-RTT link every leaf would be a full
@@ -3162,8 +3077,7 @@ class DeviceExecutor:
                   adv_notes=None, origin=None):
         """Dispatch one query: through the coalescer when concurrency makes
         a cohort partner likely, else solo. Returns the resolve() closure
-        the InflightLaunch fetch phase blocks on. Coalescing is disabled
-        under profile capture (the bench must see per-query launches).
+        the InflightLaunch fetch phase blocks on.
 
         ``tracer`` records this query's own launch-phase spans: the
         leader's window wait (``executor.launch_wait``), its ``stack``
@@ -3174,7 +3088,7 @@ class DeviceExecutor:
         cohort): ``groupbyOperands``, where a dense group-by's kernel
         operands came from; ``groupbyKeySpace`` and ``keySpaceCells``."""
         co = self.coalescer
-        if co is not None and not self.profile_enabled:
+        if co is not None:
             builders = self._prebuild_cohorts(entry, cols, n_docs, params,
                                               lkey)
             if builders:
@@ -3184,8 +3098,7 @@ class DeviceExecutor:
                     entry, batch_key, cols, n_docs, params, lkey, layout,
                     tracer, cache_key, flight, adv_key, adv_notes, origin),
                     builders)
-        if (co is not None and not self.profile_enabled
-                and co.should_window(self.inflight)):
+        if co is not None and co.should_window(self.inflight):
             # cohort key: same pipeline entry + same batch + same column
             # set + same param shapes/dtypes → params stack along a
             # leading axis into one vmapped launch
@@ -3295,13 +3208,6 @@ class DeviceExecutor:
     def _solo_launch(self, entry, cols, n_docs, params, layout, tracer=None,
                      cache_key=None, flight=None, origin=None):
         pipeline = entry["pipeline"]
-        if self.profile_enabled:
-            with self._lock:
-                self._last_launch = (
-                    pipeline, cols, n_docs, params,
-                    sum(int(np.prod(v.shape, dtype=np.int64))
-                        * v.dtype.itemsize for v in cols.values()),
-                )
         launch_id = next(self._launch_ids)
         origin = origin or {}
         dispatch = trace_span("executor.dispatch", tracer)

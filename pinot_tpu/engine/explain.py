@@ -160,9 +160,8 @@ def _fmt_ms(v) -> str:
 
 
 def _kernel_line(rec: dict) -> str:
-    """One roofline flight → the per-kernel ``GB/s (x% of HBM peak)``
-    line EXPLAIN ANALYZE renders (ISSUE 11 / ROADMAP 1: the SNIPPETS.md
-    "GB/s vs HBM peak reported per query" target)."""
+    """One roofline flight → the per-kernel ``KERNEL(<label>: x GB/s,
+    …)`` line EXPLAIN ANALYZE renders (ISSUE 11)."""
     label = rec.get("kernel", "kernel")
     inst = rec.get("instance")
     where = f"@{inst}" if inst else ""
@@ -170,14 +169,7 @@ def _kernel_line(rec: dict) -> str:
         return (f"    KERNEL({label}{where}: CACHED_PARTIALS, "
                 f"linkMs={rec.get('linkMs')})")
     gbps = rec.get("gbps")
-    pct = rec.get("pctOfPeak")
-    peak = rec.get("peakGbps")
-    if gbps is None:
-        perf = "n/a"
-    elif pct is not None:
-        perf = f"{gbps} GB/s ({pct}% of HBM peak {peak} GB/s)"
-    else:
-        perf = f"{gbps} GB/s"
+    perf = "n/a" if gbps is None else f"{gbps} GB/s"
     # a dense group-by says where its kernel's operands came from
     operands = "".join(
         f", {k}={rec[k]}" for k in ("groupbyOperands", "groupbyKeySpace",
@@ -196,7 +188,7 @@ def annotate_analyze(plan: dict, resp: dict) -> dict:
     combine / join / scan nodes, matched rows + blocks pruned on the
     filter root — followed by an ANALYZE subtree carrying the segment
     counters, the per-phase ms waterfall (merged traceInfo), one KERNEL
-    line per roofline flight (achieved GB/s vs the HBM peak), and the
+    line per roofline flight (achieved GB/s), and the
     cache-hit provenance (device partials / broker result cache)."""
     from pinot_tpu.tools.querylog import phase_breakdown
 

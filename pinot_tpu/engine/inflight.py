@@ -326,7 +326,7 @@ class LaunchCoalescer:
         # keeps the fixed micro-batch window (an idle link should not
         # wait).
         self.stream_cap_s = stream_cap_s
-        self.force = False            # tests/bench: window regardless of load
+        self.force = False            # tests: window regardless of load
         self.pressure_fn = None       # server wires scheduler.pressure here
         self._lock = threading.Lock()
         self._pending: dict = {}      # cohort key -> open _Cohort
@@ -336,7 +336,7 @@ class LaunchCoalescer:
         # buffer, and retaining it here would pin those past the batch
         # LRU's eviction decisions
         self._last_dispatched: dict = {}
-        # observability (bench concurrency sweep reads deltas)
+        # observability
         self.cohorts_launched = 0
         self.queries_coalesced = 0    # members that joined past the leader
         self.stream_windows = 0       # windows that keyed off a predecessor

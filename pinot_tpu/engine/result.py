@@ -65,8 +65,7 @@ class ExecutionStats:
     # pipeline moved (ColPlan-width column planes scaled by the block-skip
     # gather ratio, plus the trimmed fetch buffer) and the measured
     # kernel/link wall — achieved GB/s = bytes / kernel time, computed at
-    # export against the per-process HBM peak (ops/roofline.py). Summed
-    # across partials on merge; per-flight detail rides
+    # export. Summed across partials on merge; per-flight detail rides
     # IntermediateResult.roofline.
     device_bytes_moved: int = 0
     device_kernel_ms: float = 0.0
@@ -144,9 +143,9 @@ class IntermediateResult:
     stats: ExecutionStats = dataclasses.field(default_factory=ExecutionStats)
     trace: Optional[list] = None  # phase spans when SET trace = true
     # per-flight roofline records (ISSUE 11): one dict per device launch
-    # this partial folded in ({kernel, bytesMoved, kernelMs, linkMs,
-    # gbps, peakGbps, pctOfPeak, cacheHit}) — concatenated across
-    # partials, shipped in DataTable metadata like ``trace``
+    # this partial folded in ({kernel, bytesMoved, bytesFetched, kernelMs,
+    # linkMs, gbps, cacheHit}) — concatenated across partials, shipped in
+    # DataTable metadata like ``trace``
     roofline: Optional[list] = None
 
 

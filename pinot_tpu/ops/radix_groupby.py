@@ -42,9 +42,8 @@ happens so almost all comparator passes run over VMEM-resident operands:
 
 The radix histogram (``bucket_histogram``) rides the factored one-hot
 matmul kernel (ops/groupby_mm.py) over the key's high bits — the
-bandwidth-shaped occupancy probe for the partition structure (bench
-``micro.radix_bucket_histogram`` pins its rate; tests pin it against
-np.bincount).
+bandwidth-shaped occupancy probe for the partition structure (tests pin
+it against np.bincount).
 
 Everything here is trace-time static in shapes: chunk plans derive from
 array lengths and the template's K, so jit caches stay keyed on the same
@@ -409,7 +408,7 @@ def hll_chunked_sorted_keys(packed, n_slots: int,
 
 
 # ---------------------------------------------------------------------------
-# radix histogram (occupancy probe; micro-bench + test-pinned primitive)
+# radix histogram (occupancy probe; test-pinned primitive)
 # ---------------------------------------------------------------------------
 
 
@@ -417,7 +416,7 @@ def bucket_histogram(key, keyspace: int, n_buckets: int, *,
                      interpret: bool = False):
     """(n_buckets,) int64 row counts per radix partition (the key's high
     bits), via the factored one-hot matmul kernel — the histogram half of
-    the radix scheme, measured standalone by ``micro`` in bench.py.
+    the radix scheme.
     Sentinel/masked keys land in the kernel's overflow slot. n_buckets
     must be a power of two; the bucket shift derives from ``keyspace``."""
     from pinot_tpu.ops import groupby_mm as mm

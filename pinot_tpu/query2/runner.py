@@ -884,7 +884,7 @@ def run_plan(plan: MultiStagePlan, table_rows: dict, device=None,
         # wall), but it makes EXPLAIN ANALYZE on a join render the same
         # per-kernel GB/s line the single-stage path gets
         roofline_recs.append(_join_roofline_record(
-            step, strat, bytes_in, left_cols, join_ms, device))
+            step, strat, bytes_in, left_cols, join_ms))
 
     if plan.post_filter is not None and n:
         m = _expr_mask(left_cols, plan.post_filter, None, n)
@@ -918,12 +918,8 @@ def run_plan(plan: MultiStagePlan, table_rows: dict, device=None,
 
 
 def _join_roofline_record(step, strat: str, bytes_in: int, out_cols: dict,
-                          join_ms: float, device) -> dict:
+                          join_ms: float) -> dict:
     """Roofline flight record for one executed join step."""
-    import sys
-
-    from pinot_tpu.ops import roofline as rl
-
     bytes_out = sum(int(v.nbytes) for v in out_cols.values())
     bytes_moved = bytes_in + bytes_out
     rec = {"kernel": f"join_{step.kind.lower()}+{strat.lower()}",
@@ -931,17 +927,7 @@ def _join_roofline_record(step, strat: str, bytes_in: int, out_cols: dict,
            "kernelMs": round(join_ms, 3), "linkMs": 0.0,
            "cacheHit": False}
     if join_ms > 0:
-        gbps = bytes_moved / (join_ms / 1e3) / 1e9
-        rec["gbps"] = round(gbps, 3)
-        # only probe when a device executor is attached or jax is already
-        # resident — a jax-free broker process must stay jax-free
-        peak = rl.hbm_peak_gbps() \
-            if (device is not None or "jax" in sys.modules) \
-            else (rl.peak_if_probed() or 0.0)
-        pct = rl.pct_of_peak(gbps, peak)
-        if pct is not None:
-            rec["peakGbps"] = round(peak, 1)
-            rec["pctOfPeak"] = pct
+        rec["gbps"] = round(bytes_moved / (join_ms / 1e3) / 1e9, 3)
     return rec
 
 

@@ -71,14 +71,6 @@ def _apply_request_overrides(q, req: dict):
     return q
 
 
-def _hbm_peak_if_probed():
-    """Scrape-safe HBM-peak gauge (ops/roofline.py): the cached probe
-    value or None — never triggers the measurement from a metrics poll."""
-    from pinot_tpu.ops import roofline
-
-    return roofline.peak_if_probed()
-
-
 class ServerInstance:
     def __init__(self, instance_id: str, registry: ClusterRegistry,
                  data_dir: str, host: str = "127.0.0.1", port: int = 0,
@@ -215,12 +207,9 @@ class ServerInstance:
             len(t.segments) for t in self.engine.tables.values()))
         self._register_gauge("schedulerRejected",
                              lambda: self.scheduler.num_rejected)
-        # temperature + roofline gauges (ISSUE 11): tracked segments and
-        # the per-process HBM peak (None until the first accounted device
-        # flight probes it — a metrics scrape never spends device time)
+        # temperature gauge (ISSUE 11): tracked segments
         self._register_gauge("heatTrackedSegments",
                              lambda: self.heat.size())
-        self._register_gauge("hbmPeakGbps", _hbm_peak_if_probed)
         if self.tiers.enabled:
             # tier lifecycle visibility (registered only on tiering
             # servers — same no-churn rule as the result-cache gauges)

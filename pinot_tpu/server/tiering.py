@@ -338,7 +338,7 @@ class TierManager:
         self._hydrating: set = set()
         self._hydrator: Optional[threading.Thread] = None
         self._stop = threading.Event()
-        # lifecycle counters (bench detail.tiering + tests)
+        # lifecycle counters
         self.demotions_warm = 0
         self.demotions_cold = 0
         self.promotions_hot = 0
@@ -419,7 +419,7 @@ class TierManager:
 
     def tick(self, now: Optional[float] = None) -> dict:
         """One full promotion/demotion pass; returns {edge: [names]} of
-        the transitions applied (bench/test visibility)."""
+        the transitions applied."""
         now = time.time() if now is None else now
         heat = {}
         for t, s, rec in self.server.heat.iter_all(now=now):
@@ -710,7 +710,7 @@ class TierManager:
         log.info("segment %s/%s hydrated cold->warm", table, name)
 
     def wait_hydrated(self, table: str, name: str, timeout_s: float = 10.0) -> bool:
-        """Test/bench helper: block until a requested hydration lands."""
+        """Test helper: block until a requested hydration lands."""
         t0 = time.monotonic()
         while time.monotonic() - t0 < timeout_s:
             with self._lock:

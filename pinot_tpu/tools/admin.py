@@ -208,8 +208,7 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--port", type=int, default=0)
     sp.add_argument("--no-device", action="store_true",
                     help="host-only executor (skip jax/XLA entirely: "
-                         "fast startup for CPU-bound cluster tiers and "
-                         "the bench's multi-process scaling phase)")
+                         "fast startup for CPU-bound cluster tiers)")
     sp.add_argument("--max-concurrent", type=int, default=8,
                     help="scheduler admission width (concurrent queries "
                          "per server; excess queues). Size to the cores "

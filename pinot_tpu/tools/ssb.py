@@ -1,6 +1,6 @@
 """The SSB-shaped ``lineorder`` table: schema, table config, seeded column
-generator and the suite's statements — the one definition ``bench.py`` and
-``chip_smoke.py`` both import.
+generator and the suite's statements — the one definition ``chip_smoke.py``
+and the benchmark's ``ssbproxy_…`` configurations both import.
 
 Nine columns, two star-tree configs (the 3-dim revenue cube and the
 ``lo_suppkey`` cube carrying COUNT/SUM/HLL planes) and an inverted index

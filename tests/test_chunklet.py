@@ -303,8 +303,8 @@ class TestMixedBackendDifferential:
 
 class TestProcessHarness:
     def test_ingest_worker_subprocess(self):
-        """The per-partition OS-process consume loop (the multi-partition
-        bench harness) runs standalone and reports its rows/s."""
+        """The per-partition OS-process consume loop runs standalone and
+        reports its rows/s."""
         import json
         import os
         import subprocess

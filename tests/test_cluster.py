@@ -613,14 +613,22 @@ class TestLoadAwareRouting:
             assert set(routing) == {"b"}
 
 
+def _every_replica_online(registry, n_segments, replication=2):
+    """Every replica of every segment serves. A segment is in the external
+    view with its FIRST replica; each later one's report moves the routing
+    generation, and a result-cache entry filled before it is stale after."""
+    view = registry.external_view(TABLE_OFF)
+    return len(view) == n_segments and all(
+        len(instances) == replication for instances in view.values())
+
+
 class TestBrokerResultCache:
     def test_hit_miss_parity_and_invalidation(self, cluster, tmp_path):
         from pinot_tpu.common import freshness
 
         registry, controller, servers, broker = cluster
         _offline_table(tmp_path, controller, n_segments=3, rows=500)
-        assert wait_until(
-            lambda: len(registry.external_view(TABLE_OFF)) == 3)
+        assert wait_until(lambda: _every_replica_online(registry, 3))
         cbroker = Broker(registry, broker_id="cache_broker",
                          timeout_s=10.0, result_cache=True)
         try:
@@ -650,8 +658,7 @@ class TestBrokerResultCache:
             build_segment(schema, cols, d,
                           TableConfig(table_name="sales"), "sales_late")
             controller.upload_segment("sales", d)
-            assert wait_until(
-                lambda: len(registry.external_view(TABLE_OFF)) == 4)
+            assert wait_until(lambda: _every_replica_online(registry, 4))
             r2 = cbroker.execute(sql)
             assert r2["resultCacheHit"] is False
             assert r2["resultTable"]["rows"] != hit["resultTable"]["rows"]
@@ -677,8 +684,7 @@ class TestBrokerResultCache:
     def test_opt_out_and_uncacheable_queries(self, cluster, tmp_path):
         registry, controller, servers, broker = cluster
         _offline_table(tmp_path, controller, n_segments=1, rows=100)
-        assert wait_until(
-            lambda: len(registry.external_view(TABLE_OFF)) == 1)
+        assert wait_until(lambda: _every_replica_online(registry, 1))
         cbroker = Broker(registry, broker_id="cache_broker2",
                          timeout_s=10.0, result_cache=True)
         try:

@@ -369,10 +369,9 @@ class TestMetricsLifecycle:
             # the device gauge family (PR-5/PR-6) registers too
             assert any("deviceResidentBytes" in k for k in keys)
             assert any("deviceQuarantinedPipelines" in k for k in keys)
-            # ISSUE 11: the roofline + temperature gauges join the
-            # tracked family — a restart must not leak them either
+            # ISSUE 11: the temperature gauge joins the tracked family —
+            # a restart must not leak it either
             assert any("heatTrackedSegments" in k for k in keys)
-            assert any("hbmPeakGbps" in k for k in keys)
             server.stop(drain_timeout_s=0.2)
             assert m.gauge_keys("leakguard_0") == [], \
                 "stop() leaked callable gauges into the global registry"

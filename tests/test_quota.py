@@ -43,13 +43,17 @@ def test_quota_rejects_above_rate_and_refills(tmp_path):
         assert wait_until(
             lambda: len(registry.external_view("limited_OFFLINE")) == 1)
 
+        # the bucket's clock stands still: how long a query takes on this
+        # machine must not refill a 2-a-second bucket between the three
+        clock = [1_000.0]
+        broker.quota._now = lambda: clock[0]
         sql = "SELECT COUNT(*) FROM limited"
         ok = [broker.execute(sql) for _ in range(2)]
         assert all(not r.get("exceptions") for r in ok), ok
         rejected = broker.execute(sql)
         assert rejected["exceptions"][0]["errorCode"] == 429
 
-        time.sleep(1.1)  # bucket refills at 2 tokens/s
+        clock[0] += 1.1  # bucket refills at 2 tokens/s
         again = broker.execute(sql)
         assert not again.get("exceptions"), again
     finally:
@@ -78,6 +82,9 @@ def test_typed_table_name_shares_bucket(tmp_path):
         controller.upload_segment("limited", str(tmp_path / "up"))
         assert wait_until(
             lambda: len(registry.external_view("limited_OFFLINE")) == 1)
+        # a frozen clock: the third query is refused however long the
+        # first two took (the bucket holds 2 and refills 2 a second)
+        broker.quota._now = lambda: 1_000.0
         assert not broker.execute(
             "SELECT COUNT(*) FROM limited").get("exceptions")
         assert not broker.execute(

@@ -24,29 +24,32 @@ def _percentile(xs, p):
     return float(np.percentile(np.asarray(xs), p))
 
 
-def _light_latencies(sched, n=30, work_s=0.004):
+def _light_waits(sched, n=30, work_s=0.004):
     """Submit light-tenant queries at a steady trickle, one at a time
-    (closed loop), returning end-to-end latencies."""
-    lats = []
+    (closed loop), returning how long the SCHEDULER held each before it
+    ran (its own ``scheduler_wait_ms``): the query's sleep and the
+    trickle's are the test's, and overshoot on a busy machine."""
+    waits = []
     for _ in range(n):
-        t0 = time.perf_counter()
-        sched.run(lambda: time.sleep(work_s), group="light")
-        lats.append(time.perf_counter() - t0)
+        out = {}
+        sched.run(lambda: time.sleep(work_s), group="light", stats_out=out)
+        waits.append(out["scheduler_wait_ms"] / 1e3)
         time.sleep(0.002)
-    return lats
+    return waits
 
 
 class TestTokenBucketFairness:
     def test_heavy_tenant_cannot_starve_light(self):
         """VERDICT round-3 acceptance: heavy tenant at saturation QPS must
-        not push the light tenant's p99 past 2x its solo p99 (+ a fixed
-        5ms scheduling epsilon for CI jitter)."""
+        not push the light tenant's p99 wait for a slot past 2x its solo
+        p99 (+ a fixed 5ms scheduling epsilon for CI jitter; a heavy job
+        holds a slot for 50)."""
         def solo_sched():
             return TokenBucketScheduler(
                 max_concurrent=2, max_queued=64,
                 rate_ms_per_s=50.0, burst_ms=100.0)
 
-        solo = _light_latencies(solo_sched())
+        solo = _light_waits(solo_sched())
         solo_p99 = _percentile(solo, 99)
 
         sched = solo_sched()
@@ -64,9 +67,13 @@ class TestTokenBucketFairness:
                    for _ in range(8)]
         for t in threads:
             t.start()
-        time.sleep(0.15)  # let the heavy tenant overdraw its bucket
         try:
-            contended = _light_latencies(sched)
+            # the heavy tenant overdraws its bucket (burst 100 ms, 50 a job)
+            deadline = time.monotonic() + 10
+            while sched.group_stats().get("heavy", {}).get(
+                    "tokens_ms", 0.0) >= 0 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            contended = _light_waits(sched)
         finally:
             stop.set()
             for t in threads:
