@@ -5,11 +5,11 @@ The engine *measures* everything — per-kernel achieved GB/s, block-skip
 pruning ratios, build-side row counts, cache-hit rates, observed group
 counts — but used to *decide* almost everything by static constant:
 join strategy by ``BROADCAST_MAX_BUILD_ROWS``, block-skip by a fixed
-``ceil(total/16)`` candidate bound, trim by a fixed ``group_trim_size``,
-cohort windows by scheduler pressure alone. The reference makes these
-calls with ``InstancePlanMakerImplV2``'s hand-tuned heuristics; the
-advisor replaces the hand-tuning with the measurements the system
-already collects (PAPER.md layer 5, ROADMAP item 2).
+``ceil(total/16)`` candidate bound, trim by a fixed ``group_trim_size``.
+The reference makes these calls with ``InstancePlanMakerImplV2``'s
+hand-tuned heuristics; the advisor replaces the hand-tuning with the
+measurements the system already collects (PAPER.md layer 5, ROADMAP
+item 2).
 
 Design:
 
@@ -17,7 +17,7 @@ Design:
   holding EWMA'd measurements — build-side rows per alias, effective
   join strategy, block-skip selectivity (``blocks_scanned /
   blocks_total``), per-rung kernel GB/s (Pallas vs XLA roofline
-  labels), observed group counts, cohort sizes, cache-hit counts.
+  labels), observed group counts, cache-hit counts.
 - **Bounded LRU + decay**: memos live per server/broker process (no
   persistence across restarts in v1); the map is LRU-bounded, and a
   measurement that *drifts* (a table's shape changed) halves the
@@ -107,7 +107,7 @@ class PlanMemo:
 
     __slots__ = ("key", "build_rows", "strategies", "demotions",
                  "skip_ratio", "gbps", "groups", "groups_hi",
-                 "trim_overflows", "cohort", "partials_hits",
+                 "trim_overflows", "partials_hits",
                  "result_hits", "executions", "decisions", "overrides",
                  "drift_cooldown", "_probe_tick")
 
@@ -121,7 +121,6 @@ class PlanMemo:
         self.groups = _Ewma(alpha)      # observed group count
         self.groups_hi = 0              # decaying max (trim safety bound)
         self.trim_overflows = 0         # advised keep < observed groups
-        self.cohort = _Ewma(alpha)      # coalescer cohort sizes
         self.partials_hits = [0, 0]     # [hits, total]
         self.result_hits = [0, 0]
         self.executions = 0
@@ -136,7 +135,7 @@ class PlanMemo:
         state tools/querylog.py renders."""
         if self.drift_cooldown > 0:
             return "drifting"
-        signals = [self.skip_ratio, self.groups, self.cohort,
+        signals = [self.skip_ratio, self.groups,
                    *self.build_rows.values(), *self.gbps.values()]
         if any(s.ready(min_samples) for s in signals):
             return "converged"
@@ -153,8 +152,6 @@ class PlanMemo:
             if self.skip_ratio.n else None,
             "groupsHi": self.groups_hi,
             "trimOverflows": self.trim_overflows,
-            "cohortMean": round(self.cohort.mean, 2)
-            if self.cohort.n else None,
         }
 
 
@@ -224,7 +221,7 @@ class PlanAdvisor:
     # ---- observation -----------------------------------------------------
     def observe(self, key: str, *, build_rows=None, join_strategy=None,
                 demoted: bool = False, skip_ratio=None, label=None,
-                gbps=None, groups=None, trim_keep=None, cohort=None,
+                gbps=None, groups=None, trim_keep=None,
                 partials_hit=None, result_hit=None) -> None:
         """Fold one execution's measurements into the template's memo.
         Any subset of signals may be supplied; unknown templates create
@@ -269,8 +266,6 @@ class PlanAdvisor:
                         # overflow and stand the advice down
                         m.trim_overflows += 1
                         m.groups.n = 0
-                if cohort is not None:
-                    m.cohort.add(cohort)
                 if partials_hit is not None:
                     m.partials_hits[1] += 1
                     m.partials_hits[0] += bool(partials_hit)
@@ -418,30 +413,6 @@ class PlanAdvisor:
             return tightened, (f"ADVISOR(groupTrim={tightened}: "
                                f"measured={m.groups_hi} "
                                f"default={default_trim})")
-
-    def advise_cohort_window(self, key: str, default_s: float):
-        """Cohort window sizing from observed arrival cohesion: a
-        template whose cohorts stay solo shrinks its window (the wait
-        buys nothing), one that reliably finds partners holds it open
-        longer. Bounded to [0.5x, 2x] of the configured window."""
-        with self._lock:
-            m = self._memos.get(key)
-            if m is None or m.drift_cooldown > 0 \
-                    or not m.cohort.ready(self.min_samples):
-                return default_s, None
-            mean = m.cohort.mean
-            if mean <= 1.25:
-                w = default_s * 0.5
-            elif mean >= 4.0:
-                # cohorts fill fast — the full.wait exits early anyway;
-                # keep the configured window
-                self._decide(m, False)
-                return default_s, None
-            else:
-                w = default_s * 2.0
-            self._decide(m, True)
-            return w, (f"ADVISOR(cohortWindow={w * 1e3:.1f}ms: "
-                       f"measured={mean:.1f} default={default_s * 1e3:.1f}ms)")
 
     # ---- introspection ---------------------------------------------------
     def convergence(self, key: str) -> str:

@@ -922,13 +922,12 @@ def _unpack_outs(bufs: dict, layout) -> dict:
     return outs
 
 
-def _then_join(resolve, threads):
-    """``resolve`` that also waits for ``threads``, with the attributes
-    the fetch phase reads off it (stamp, abandon, cohort, index)."""
+def _then_join(resolve, thread):
+    """``resolve`` that also waits for ``thread``, with the attributes
+    the fetch phase reads off it (stamp, cohort, index)."""
     def joined():
         outs = resolve()
-        for thread in threads:
-            thread.join()
+        thread.join()
         return outs
 
     joined.__dict__.update(resolve.__dict__)
@@ -1580,8 +1579,6 @@ class DeviceExecutor:
     # byte-aware cap: column blocks are materialized lazily, so the byte
     # check runs again as each in-flight launch drains (_release_launch)
     MAX_CACHED_BYTES = int(os.environ.get("PINOT_TPU_BATCH_CACHE_BYTES", 6 << 30))
-    # cohort widths built with a template's first launch (_prebuild_cohorts)
-    PREBUILD_WIDTHS = (2, 4)
 
     def __init__(self, mesh=None, mm_mode: str = "auto",
                  num_groups_limit: int = 100_000,
@@ -1597,11 +1594,11 @@ class DeviceExecutor:
         PINOT_TPU_PALLAS=0 and per-query SET usePallas=false force the
         XLA scatter path end to end."""
         self.mesh = mesh
-        # build a template's cohort programs with its first launch
-        # (_prebuild_cohorts); None: on a TPU, where a program takes
+        # build a block-skip template's dense twin with its first launch
+        # (_prebuild_dense); None: on a TPU, where a program takes
         # seconds to build, and not elsewhere (asked at the first launch:
         # constructing an executor does not touch the backend)
-        self.prebuild_cohorts = None
+        self.prebuild_dense = None
         self.mm_mode = mm_mode
         self.pallas_mode = pallas_mode
         self.num_groups_limit = max(1, num_groups_limit)
@@ -2948,8 +2945,7 @@ class DeviceExecutor:
                                   lambda: synth)
         resolve = self._dispatch(
             entry, batch_key, cols, n_docs, params, lkey, layout, tracer,
-            cache_key, flight, adv_key=adv_key, adv_notes=adv_notes,
-            origin=origin)
+            cache_key, flight, origin=origin)
         handle = InflightLaunch(self, q, ctx, template, aggs, batch_key,
                                 resolve)
         handle.flight = flight
@@ -3066,144 +3062,106 @@ class DeviceExecutor:
                 "agg_tpls": agg_tpls, "final": final,
                 "template": template, "trim": trim, "pallas": pallas,
                 "layouts": {}, "cohort": None, "cohort_layouts": {},
-                "prebuilt": set(),  # batch shapes whose cohorts are built
+                "prebuilt": set(),  # batch shapes whose dense twin is built
                 "dense": dense,     # a block-skip entry's dense twin
             }
             self._pipelines[pkey] = entry
             return entry
 
     def _dispatch(self, entry, batch_key, cols, n_docs, params, lkey, layout,
-                  tracer=None, cache_key=None, flight=None, adv_key=None,
-                  adv_notes=None, origin=None):
-        """Dispatch one query: through the coalescer when concurrency makes
-        a cohort partner likely, else solo. Returns the resolve() closure
-        the InflightLaunch fetch phase blocks on.
+                  tracer=None, cache_key=None, flight=None, origin=None):
+        """Dispatch one query, at once and as a program of its own: a
+        served launch waits for no other request and for no other
+        launch's fetch (PERF.md, PR 35). Returns the resolve() closure
+        the InflightLaunch fetch phase blocks on. Only under the
+        coalescer's ``force``, the tests' switch, does it go through a
+        cohort's window.
 
-        ``tracer`` records this query's own launch-phase spans: the
-        leader's window wait (``executor.launch_wait``), its ``stack``
-        and ``dispatch``; a solo launch's ``dispatch``. A member's join
-        returns at once — it records its waits in its fetch phase
+        ``tracer`` records this query's own launch-phase spans: its
+        ``dispatch``; under ``force`` the leader's window wait
+        (``executor.launch_wait``) and its ``stack`` as well. A member's
+        join returns at once — it records its waits in its fetch phase
         (InflightLaunch._traced_resolve). ``origin``: what the dispatch
         and device_wait spans say of a group-by (the leader's, for a
         cohort): ``groupbyOperands``, where a dense group-by's kernel
         operands came from; ``groupbyKeySpace`` and ``keySpaceCells``."""
+        builder = self._prebuild_dense(entry, cols, n_docs, params, lkey)
+        if builder is not None:
+            # the template's first answer waits for its dense twin: after
+            # it no launch of the template builds a program
+            return _then_join(self._dispatch(
+                entry, batch_key, cols, n_docs, params, lkey, layout,
+                tracer, cache_key, flight, origin), builder)
         co = self.coalescer
-        if co is not None:
-            builders = self._prebuild_cohorts(entry, cols, n_docs, params,
-                                              lkey)
-            if builders:
-                # the template's first answer waits for its cohorts: after
-                # it no launch of the template builds a program
-                return _then_join(self._dispatch(
-                    entry, batch_key, cols, n_docs, params, lkey, layout,
-                    tracer, cache_key, flight, adv_key, adv_notes, origin),
-                    builders)
-        if co is not None and co.should_window(self.inflight):
+        if co.should_window():
             # cohort key: same pipeline entry + same batch + same column
             # set + same param shapes/dtypes → params stack along a
             # leading axis into one vmapped launch
             sig = tuple(sorted(
                 (k, tuple(v.shape), str(v.dtype)) for k, v in params.items()))
             ckey = (id(entry), batch_key, lkey, tuple(sorted(cols)), sig)
-            # advisor: cohort window sized from the template's OBSERVED
-            # arrival cohesion (templates whose cohorts stay solo stop
-            # paying the window wait; ones that reliably stack hold it
-            # open longer), and every dispatched cohort's size feeds the
-            # memo back via the launch closure
-            window_s = None
-            if adv_key is not None:
-                w, note = self.advisor.advise_cohort_window(
-                    adv_key, co.window_s)
-                if note:
-                    window_s = w
-                    if adv_notes is not None:
-                        adv_notes.append(note)
 
             # the leader's window: a wait for OTHER requests, so it is
             # written to the profiler; closed when the window does
             window = trace_span("executor.launch_wait", tracer)
 
-            def _launch(members, _ak=adv_key):
+            def _launch(members):
                 window.close()
-                if _ak is not None and self.advisor is not None:
-                    self.advisor.observe(_ak, cohort=len(members))
                 return self._cohort_launch(
                     entry, cols, n_docs, members, lkey, tracer, flight,
                     origin)
 
             window.__enter__()
             try:
-                cohort, idx = co.join(ckey, params, _launch,
-                                      window_s=window_s)
+                cohort, idx = co.join(ckey, params, _launch)
             finally:
                 window.cancel()  # a member: the window was not its own
 
             def resolve(_c=cohort, _i=idx):
                 return _c.resolve_member(_i)
 
-            # abandoned-handle hook (InflightLaunch.release): an
-            # all-abandoned cohort still signals fetch_done so the next
-            # stream window doesn't poll out its cap
-            resolve.abandon = cohort.note_abandoned
             resolve.cohort, resolve.index = cohort, idx
             return resolve
         return self._solo_launch(entry, cols, n_docs, params, layout, tracer,
                                  cache_key, flight, origin)
 
-    def _prebuild_cohorts(self, entry, cols, n_docs, params, lkey):
-        """With a template's first launch on a batch shape, build the
-        other programs it will meet: its cohorts (the statement is
-        launched PREBUILD_WIDTHS times over in one cohort) and, for a
-        block-skip entry, its dense twin — each on a thread of its own,
-        beside the solo program's build, so that the first answer takes
-        the slowest build and not their sum. A cohort forms when callers
-        send one template together, which the first requests for it seldom
-        do, and the advisor switches a template to the dense form at its
-        fourth launch: either, first met under load, stalled its callers
-        for the seconds the program takes to build (PERF.md, PR 31 and
-        PR 32). Returns the building threads; none where built or being
-        built."""
-        on = self.prebuild_cohorts
-        if on is None:
-            on = self.prebuild_cohorts = jax.default_backend() == "tpu"
-        if not on:
-            return []
+    def _prebuild_dense(self, entry, cols, n_docs, params, lkey):
+        """With a block-skip entry's first launch on a batch shape, build
+        its dense twin on a thread of its own, beside the block-skip
+        program's build, so that the first answer takes the slower build
+        and not their sum. The advisor switches a template to the dense
+        form at its fourth launch: first met under load, that stalled
+        its callers for the seconds the program takes to build (PERF.md,
+        PR 31 and PR 32). Returns the building thread; None where built
+        or being built, or where there is no twin."""
         dense = entry["dense"]
+        if dense is None:
+            return None
+        on = self.prebuild_dense
+        if on is None:
+            on = self.prebuild_dense = jax.default_backend() == "tpu"
+        if not on:
+            return None
         with self._lock:
             if lkey in entry["prebuilt"]:
-                return []
+                return None
             entry["prebuilt"].add(lkey)
-            if dense is not None:
-                dense["prebuilt"].add(lkey)
 
-        def dense_twin():
+        def build():
             # the program the advisor switches the template to once it
             # has seen that block skip prunes nothing
-            jax.block_until_ready(dense["pipeline"](
-                {k: v for k, v in cols.items()
-                 if not k.startswith((bs_ops.ZLO, bs_ops.ZHI))},
-                n_docs, params))
-
-        def cohort_of(width):
-            return lambda: self._cohort_launch(
-                entry, cols, n_docs, [params] * width, lkey)()
-
-        def build(program):
             try:
-                program()
+                jax.block_until_ready(dense["pipeline"](
+                    {k: v for k, v in cols.items()
+                     if not k.startswith((bs_ops.ZLO, bs_ops.ZHI))},
+                    n_docs, params))
             except Exception:  # noqa: BLE001 — its first launch builds it
-                log.exception("prebuild of a template's programs failed")
+                log.exception("prebuild of a template's dense twin failed")
 
-        programs = [dense_twin] if dense is not None else []
-        programs += [cohort_of(w) for w in self.PREBUILD_WIDTHS
-                     if w <= self.coalescer.max_cohort]
-        builders = [threading.Thread(target=build, args=(program,),
-                                     daemon=True,
-                                     name="pinot-cohort-prebuild")
-                    for program in programs]
-        for builder in builders:
-            builder.start()
-        return builders
+        builder = threading.Thread(target=build, daemon=True,
+                                   name="pinot-dense-prebuild")
+        builder.start()
+        return builder
 
     def _solo_launch(self, entry, cols, n_docs, params, layout, tracer=None,
                      cache_key=None, flight=None, origin=None):

@@ -520,7 +520,7 @@ class QueryEngine:
                 # ANY failure below must drop every remaining in-flight
                 # launch's batch pin (handle.release is idempotent after
                 # fetch), or the batches stay unevictable and the
-                # coalescer's pressure signal never drains — the guard
+                # executor's in-flight count never drains — the guard
                 # covers QueryTimeout, fallback-gate rejections, AND
                 # unexpected errors alike
                 pending = list(device_handles)

@@ -11,14 +11,20 @@ many-requests-in-flight posture of the reference's scatter-gather model —
 a Pinot server keeps many segment queries in flight to hide exactly this
 latency).
 
-``LaunchCoalescer`` rides on top: concurrent queries sharing one
-(batch, template, param-shape) cohort key — the dashboard fan-out case,
-same SQL shape with different literals — stack their params along a
-leading axis and execute as ONE vmapped launch whose result crosses the
-link as ONE packed buffer, amortizing a single RTT over the whole cohort.
-The micro-batch window only opens under pressure (another query already in
-flight on the executor, or the server scheduler reporting contention): an
-idle server dispatches immediately and pays no window latency.
+On the served path every request dispatches its own program at once
+(``DeviceExecutor._solo_launch``): a launch waits for no other request to
+arrive and for no other launch's fetch. A shared launch is not cheaper on
+the device than its members apart, and it costs its leader the stacking
+of the members' params and the hold (PERF.md, PR 35).
+
+``LaunchCoalescer`` is what is left of the hold: under ``force``, the
+tests' switch, concurrent queries sharing one (batch, template,
+param-shape) cohort key — same SQL shape with different literals — stack
+their params along a leading axis and execute as ONE vmapped launch whose
+result comes back as ONE packed buffer. The leader holds a fixed
+micro-batch window for its members. Nothing the load can set opens a
+window. If a cell ever shows a device with a backlog of short launches,
+the hold to write keys off that backlog (ROADMAP D7).
 """
 
 from __future__ import annotations
@@ -84,15 +90,7 @@ class InflightLaunch:
         self._done = True
         try:
             if self.deadline is not None:
-                try:
-                    self.deadline.check("device fetch")
-                except BaseException:
-                    # this member will never run the shared resolve: it
-                    # counts as abandoned, or an all-timed-out cohort
-                    # leaves fetch_done unset and the next stream window
-                    # polls out its whole cap
-                    self._note_abandoned()
-                    raise
+                self.deadline.check("device fetch")
             try:
                 outs = self._resolve() if self.tracer is None \
                     else self._traced_resolve(self.tracer)
@@ -152,7 +150,8 @@ class InflightLaunch:
         (``executor.launch_wait``), then for the device. Exactly one
         span of a launched request — its ``executor.device_wait`` —
         carries ``launchId``, ``cohortSize``, ``cohortPadded``, ``role``
-        and ``windowKind``."""
+        and ``windowKind`` (``fixed`` for a cohort, ``none`` for a launch
+        of its own: every served one)."""
         from pinot_tpu.common import trace
 
         r = self._resolve
@@ -169,7 +168,7 @@ class InflightLaunch:
             return outs  # nothing was launched (a fully pruned batch)
         mine = {"role": "solo", "windowKind": "none"} if cohort is None \
             else {"role": "member" if r.index else "leader",
-                  "windowKind": cohort.window_kind}
+                  "windowKind": "fixed"}
         t_dispatched = t_enter
         if mine["role"] == "member":
             t_dispatched = min(max(cohort.t_dispatched, t_enter), t_exit)
@@ -182,29 +181,15 @@ class InflightLaunch:
                           attrs={**stamp["attrs"], **mine})
         return outs
 
-    def _note_abandoned(self):
-        """Tell a cohort this member will never fetch (resolve closures
-        carry the ``abandon`` hook; solo resolves don't — no-op)."""
-        abandon = getattr(self._resolve, "abandon", None)
-        if abandon is not None:
-            try:
-                abandon()
-            except Exception:  # noqa: BLE001 — bookkeeping must not mask
-                pass
-
     def release(self):
         """Abandon without fetching: drop the batch pin. Callers that fail
         BETWEEN launch and fetch (e.g. a host-segment partial raising
         while the device batch is in flight) must call this, or the pin
         leaks — the batch would stay unevictable and the executor's
-        inflight count (the coalescer's pressure signal) never drains.
-        Idempotent with fetch(); safe to call on an already-fetched handle."""
+        inflight count never drains. Idempotent with fetch(); safe to
+        call on an already-fetched handle."""
         if not self._done:
             self._done = True
-            # cohort members tell their cohort: an all-abandoned cohort
-            # must still set fetch_done or the next stream window stalls
-            # to its cap
-            self._note_abandoned()
             self._executor._release_launch(self._batch_key)
 
 
@@ -221,12 +206,9 @@ class _Cohort:
 
     def __init__(self, launch_fn):
         self._launch_fn = launch_fn
-        # what the members' traces say of this launch: whether the
-        # leader's window was the fixed micro-batch or held open for the
-        # predecessor's fetch ("stream"), the instant the one stacked
-        # launch was dispatched, and the shared resolve's launch stamp
-        # (DeviceExecutor._make_resolve: launchId, sizes)
-        self.window_kind = "fixed"
+        # what the members' traces say of this launch: the instant the
+        # one stacked launch was dispatched, and the shared resolve's
+        # launch stamp (DeviceExecutor._make_resolve: launchId, sizes)
         self.t_dispatched = None
         self.stamp = None
         self.leader_thread = threading.current_thread()  # creator leads
@@ -234,18 +216,12 @@ class _Cohort:
         self.open = True           # False once the window closed
         self.full = threading.Event()  # hit max_cohort: leader stops waiting
         self.ready = threading.Event()
-        # set once the shared buffer crossed the link (or the cohort
-        # failed): the SUCCESSOR cohort's launch window keys off it — the
-        # double-buffer handoff that keeps the link continuously busy
-        # (LaunchCoalescer stream windows)
-        self.fetch_done = threading.Event()
         self.error = None          # leader's dispatch failure, if any
         self._shared_resolve = None
         self._fetch_lock = threading.Lock()
         self._outs = None
         self._exc = None
         self._fetched = False
-        self._abandoned = 0        # members released without fetching
 
     def dispatch(self):
         """Leader only: one stacked launch for the whole cohort."""
@@ -255,31 +231,8 @@ class _Cohort:
             self.t_dispatched = time.perf_counter()
         except BaseException as e:  # noqa: BLE001 — members must observe it
             self.error = e
-            self.fetch_done.set()  # nothing will ever fetch; unblock successor
         finally:
             self.ready.set()
-            # members that abandoned BEFORE dispatch finished couldn't
-            # conclude the all-abandoned check; settle it now
-            with self._fetch_lock:
-                self._check_all_abandoned()
-
-    def note_abandoned(self):
-        """A member released its handle without fetching
-        (InflightLaunch.release — deadline expiry, upstream failure).
-        When EVERY member abandons, nothing will ever run the shared
-        fetch: fetch_done must still fire or the next same-key stream
-        window polls out its whole cap for a link that is already
-        free."""
-        with self._fetch_lock:
-            self._abandoned += 1
-            self._check_all_abandoned()
-
-    def _check_all_abandoned(self):
-        """Caller holds _fetch_lock. Membership is final once ready is
-        set (the window closed before dispatch ran)."""
-        if (self.ready.is_set() and not self._fetched
-                and self._abandoned >= len(self.members)):
-            self.fetch_done.set()
 
     def resolve_member(self, idx: int) -> dict:
         """Member ``idx``'s unpacked outputs. The shared buffer crosses
@@ -299,7 +252,6 @@ class _Cohort:
                 except BaseException as e:  # noqa: BLE001 — shared failure
                     self._exc = e
                 self._fetched = True
-                self.fetch_done.set()  # link free: successor may dispatch
         if self._exc is not None:
             raise self._exc
         return {k: v[idx] for k, v in self._outs.items()}
@@ -307,61 +259,26 @@ class _Cohort:
 
 class LaunchCoalescer:
     """Micro-batches concurrent same-template launches into one vmapped
-    dispatch. Pure synchronization — the executor supplies the actual
-    stacked-launch closure (``DeviceExecutor._cohort_launch``)."""
+    dispatch, under ``force`` only. Pure synchronization — the executor
+    supplies the actual stacked-launch closure
+    (``DeviceExecutor._cohort_launch``)."""
 
-    def __init__(self, window_s: float = 0.003, max_cohort: int = 8,
-                 stream_cap_s: float = 0.25):
-        self.enabled = True
+    def __init__(self, window_s: float = 0.003, max_cohort: int = 8):
         self.window_s = window_s      # leader's micro-batch window
         self.max_cohort = max_cohort  # vmap width cap (bounds recompiles)
-        # double-buffered launch/fetch streams: while cohort N's shared
-        # buffer is in its link flight, cohort N+1's leader holds its
-        # window open until N's fetch completes (capped at stream_cap_s
-        # for the abandoned-handle case where nobody ever fetches) — so
-        # arrivals during the RTT accumulate into ONE launch that
-        # dispatches the moment the link frees. Steady-state QPS becomes
-        # cohort_size / RTT, bounded by kernel time rather than by one
-        # round trip per query. A leader with no in-flight predecessor
-        # keeps the fixed micro-batch window (an idle link should not
-        # wait).
-        self.stream_cap_s = stream_cap_s
-        self.force = False            # tests: window regardless of load
-        self.pressure_fn = None       # server wires scheduler.pressure here
+        self.force = False            # tests: every launch opens a window
         self._lock = threading.Lock()
         self._pending: dict = {}      # cohort key -> open _Cohort
-        # cohort key -> the last dispatched cohort's fetch_done EVENT —
-        # only the event, never the _Cohort: the cohort object closes
-        # over the batch's gathered device columns and the packed output
-        # buffer, and retaining it here would pin those past the batch
-        # LRU's eviction decisions
-        self._last_dispatched: dict = {}
         # observability
         self.cohorts_launched = 0
         self.queries_coalesced = 0    # members that joined past the leader
-        self.stream_windows = 0       # windows that keyed off a predecessor
 
-    def should_window(self, executor_inflight: int) -> bool:
-        """Gate: open a window only when concurrency makes a partner
-        likely — an idle server must run its one query immediately.
-        ``executor_inflight`` counts launches between dispatch and fetch
-        (INCLUDING the asking query, hence > 1); the scheduler's pressure
-        covers queries still queued for admission."""
-        if not self.enabled:
-            return False
-        if self.force:
-            return True
-        if executor_inflight > 1:
-            return True
-        fn = self.pressure_fn
-        if fn is not None:
-            try:
-                return fn() > 1
-            except Exception:  # noqa: BLE001 — gating must never fail a query
-                return False
-        return False
+    def should_window(self) -> bool:
+        """Gate: no served launch waits for another request, whatever the
+        load — a window opens under ``force`` and never otherwise."""
+        return self.force
 
-    def join(self, key, params: dict, launch_fn, window_s=None):
+    def join(self, key, params: dict, launch_fn):
         """Join (or open) the cohort for ``key`` → (cohort, member index).
 
         The FIRST arrival becomes leader: it holds the window open for
@@ -370,10 +287,6 @@ class LaunchCoalescer:
         their params and return immediately — they block only inside
         ``resolve_member`` (their fetch phase), so a member's scheduler
         slot is released while the leader's launch is still in flight.
-
-        ``window_s``: per-join override of the leader's micro-batch
-        window (the plan advisor sizes it from the template's observed
-        arrival cohesion); None keeps the configured default.
         """
         with self._lock:
             c = self._pending.get(key)
@@ -389,43 +302,14 @@ class LaunchCoalescer:
             c = _Cohort(launch_fn)
             c.members.append(params)
             self._pending[key] = c
-            pred_done = self._last_dispatched.get(key)
-            if pred_done is not None and pred_done.is_set():
-                self._last_dispatched.pop(key, None)  # link already free
-                pred_done = None
         # leader: hold the micro-batch window open — but a cohort that
         # fills to max_cohort early dispatches immediately (the remaining
-        # window would be pure added latency for everyone in it). A window
-        # that finds NO partner costs window_s on top of the link round trip;
-        # the pressure gate keeps that bounded to genuinely-concurrent load.
-        #
-        # STREAM window (double-buffered launch/fetch): when the previous
-        # cohort of this key is still in its link flight, the window
-        # extends until that fetch completes — every arrival during the
-        # predecessor's RTT buffers into THIS cohort, and it dispatches
-        # the instant the link frees (capped so an abandoned predecessor
-        # can't stall the stream).
-        if pred_done is not None:
-            self.stream_windows += 1
-            c.window_kind = "stream"
-            deadline = time.monotonic() + self.stream_cap_s
-            while not c.full.is_set() and not pred_done.is_set():
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                c.full.wait(min(0.002, left))
-        else:
-            c.full.wait(self.window_s if window_s is None else window_s)
+        # window would be pure added latency for everyone in it)
+        c.full.wait(self.window_s)
         with self._lock:
             c.open = False
             if self._pending.get(key) is c:
                 self._pending.pop(key, None)
             self.cohorts_launched += 1
-            # LRU order: re-insert so the 64-key bound purges genuinely
-            # stale keys, never the hot template that just dispatched
-            self._last_dispatched.pop(key, None)
-            self._last_dispatched[key] = c.fetch_done
-            while len(self._last_dispatched) > 64:  # bound stale keys
-                self._last_dispatched.pop(next(iter(self._last_dispatched)))
         c.dispatch()
         return c, 0

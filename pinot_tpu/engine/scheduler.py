@@ -55,9 +55,9 @@ class QueryScheduler:
         self.num_executed = 0
 
     def pressure(self) -> int:
-        """Admitted + queued query count — the device launch coalescer's
-        gate (engine/inflight.py): a micro-batch window only opens when
-        concurrent demand makes a cohort partner likely."""
+        """Admitted + queued query count: what the heartbeat and every
+        answer's ``server_pressure`` stat report, for the brokers'
+        load-aware routing."""
         with self._lock:
             return self._running + self._waiting
 

@@ -188,11 +188,7 @@ class ServerInstance:
             compile_concurrency if compile_concurrency is not None
             else max(2, max_concurrent_queries))
         self._compile_timeout_s = 5.0
-        # launch coalescer gate: micro-batch windows open only under real
-        # scheduler pressure (engine/inflight.py LaunchCoalescer)
         dev = getattr(self.engine, "device", None)
-        if dev is not None and getattr(dev, "coalescer", None) is not None:
-            dev.coalescer.pressure_fn = self.scheduler.pressure
         self.group_trim_size = group_trim_size
         from pinot_tpu.common.metrics import get_metrics
 

@@ -131,7 +131,6 @@ def test_observe_thread_safety():
             for i in range(n_iter):
                 key = f"tpl{(t + i) % 12}"
                 adv.observe(key, skip_ratio=0.3, groups=50 + i % 7,
-                            cohort=1 + i % 3,
                             build_rows={"d": 1000 + i})
                 adv.advise_blockskip(key, 16)
                 adv.advise_trim(key, 5000)

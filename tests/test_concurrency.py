@@ -318,24 +318,46 @@ class TestLaunchCoalescing:
                 co.force = False
             assert got == expected, ("mesh" if mesh else "single")
 
-    def test_idle_executor_skips_window(self, tables):
-        """No pressure ⇒ no micro-batch window: a lone query must not pay
-        window latency nor mint a cohort."""
+    @pytest.mark.parametrize("unfetched", [0, 1, 4])
+    def test_idle_executor_skips_window(self, tables, unfetched):
+        """No load opens a micro-batch window: with nothing, one or four
+        launches of the template dispatched and unfetched
+        (``executor.inflight`` that many), a query pays no window nor
+        mints a cohort. Only ``force``, the tests' switch, opens one."""
+        from pinot_tpu.query.optimizer import optimize_query
+        from pinot_tpu.sql.compiler import compile_query
+
+        t_segs, _ = tables
         eng = make_engine(*tables)
-        co = eng.device.coalescer
-        assert co.should_window(executor_inflight=1) is False
-        c0 = co.cohorts_launched
-        r = eng.execute(self.COHORT_SQLS[0])
-        assert not r.get("exceptions")
-        assert co.cohorts_launched == c0
+        dev = eng.device
+        dev.partials_cache_enabled = False  # every request must launch
+        co = dev.coalescer
+        expected = canonical(eng.execute(self.COHORT_SQLS[0]))
+        c0 = (co.cohorts_launched, co.queries_coalesced)
+        held = []
+        try:
+            for sql in self.COHORT_SQLS[1:1 + unfetched]:
+                q = eng._expand_star(optimize_query(compile_query(sql)),
+                                     t_segs[0])
+                held.append(dev.launch(q, t_segs))
+            assert dev.inflight == unfetched
+            assert co.should_window() is False
+            assert canonical(eng.execute(self.COHORT_SQLS[0])) == expected
+            assert (co.cohorts_launched, co.queries_coalesced) == c0
+            co.force = True
+            assert co.should_window() is True
+        finally:
+            co.force = False
+            for handle in held:
+                handle.fetch()
+        assert dev.inflight == 0
 
 
 class TestAbandonedLaunchRelease:
     def test_host_partial_failure_releases_pin(self, tables):
         """A host-segment failure between device launch and fetch must
         release the in-flight handle: otherwise the batch stays
-        unevictable forever and executor.inflight (the coalescer's
-        pressure signal) never drains."""
+        unevictable forever and executor.inflight never drains."""
         from pinot_tpu.query.optimizer import optimize_query
         from pinot_tpu.sql.compiler import compile_query
 
@@ -407,11 +429,11 @@ class TestFetchTimeFallbackGate:
 class TestObservabilityCounters:
     def test_counters_consistent_under_parallel_executes(self, tables):
         """CI guard: fetch_bytes_total / fetch_leaves_total / last_get_wait_s
-        stay consistent under parallel executes — with coalescing off, K
-        device queries of one shape account exactly K× the solo deltas."""
+        stay consistent under parallel executes — every request its own
+        launch, K device queries of one shape account exactly K× the
+        solo deltas."""
         eng = make_engine(*tables)
         dev = eng.device
-        dev.coalescer.enabled = False
         sql = "SELECT dim1, COUNT(*), SUM(ivalue) FROM t GROUP BY dim1"
         eng.execute(sql)  # warm: compile + batch caches
         b0, l0 = dev.fetch_bytes_total, dev.fetch_leaves_total
@@ -425,7 +447,6 @@ class TestObservabilityCounters:
         assert dev.fetch_bytes_total - b1 == 20 * per_bytes
         assert dev.fetch_leaves_total - l1 == 20 * per_leaves
         assert dev.last_get_wait_s is not None and dev.last_get_wait_s >= 0
-        dev.coalescer.enabled = True
 
 
 class TestSchedulerPressure:

@@ -333,7 +333,7 @@ def test_a_cohort_member_that_overflows_is_answered_by_the_host(engines):
     assert sorted(g[1].get("numSegmentsOnHost", 0) for g in got) == [0, 2]
 
 
-# ---- a template's cohort programs are built with its first answer ----------
+# ---- a template's programs are built with its first answer -----------------
 
 _BUILT = []
 
@@ -356,31 +356,54 @@ def _count_builds():
 
 
 @pytest.mark.parametrize("name", ["three_keys", "small_two_keys"])
-def test_cohorts_are_built_with_the_first_answer(data, name):
-    """After a template's first answer a cohort of two and of four of it
-    builds nothing: a width first met under load would stall its members
-    for the seconds the program takes to build."""
+def test_the_dense_twin_is_built_with_the_first_answer(data, name):
+    """A template's first answer builds its program and, beside it, the
+    dense twin of its block-skip form; after it nothing is built: not by
+    callers sending the template together (each launches a program of its
+    own: no cohort program exists), nor by the advisor's switch to the
+    dense form. A program first met under load would stall its callers
+    for the seconds it takes to build."""
     engine = _engine(data[0], mm_mode="interpret")
-    engine.device.prebuild_cohorts = True  # as on a TPU
+    device = engine.device
+    device.prebuild_dense = True  # as on a TPU
     sql = T_STATEMENTS[name][0]
     others = [sql.replace("ASINATION2", n).replace("B11", c)
               for n, c in (("EURNATION3", "B12"), ("AFRNATION1", "B13"),
                            ("AMENATION0", "B14"))]
-    want = [_rows(engines_host(data), s)[0] for s in [sql] + others]
+    sqls = [sql] + others
+    want = [_rows(engines_host(data), s)[0] for s in sqls]
     assert _rows(engine, sql)[0] == want[0]
+    skipping = [e for e in device._pipelines.values() if e["dense"]]
+    assert len(skipping) == 1 and skipping[0]["prebuilt"]
+    assert skipping[0]["dense"] in device._pipelines.values()
     built = _count_builds()
-    for width in (2, 4):
-        got = _cohort(engine, ([sql] + others)[:width])
-        assert [g[0] for g in got] == want[:width]
+    got = [None] * len(sqls)
+    barrier = threading.Barrier(len(sqls))
+
+    def caller(i):
+        barrier.wait()
+        got[i] = _rows(engine, sqls[i])[0]
+
+    callers = [threading.Thread(target=caller, args=(i,))
+               for i in range(len(sqls))]
+    for t in callers:
+        t.start()
+    for t in callers:
+        t.join()
+    assert got == want
     # ... nor does the advisor's switch to the dense form, once it has seen
     # that block skip prunes nothing of this table (its fourth launch on)
     advice = []
     for turn in range(6):
-        rows, resp = _rows(engine, ([sql] + others)[turn % 4])
+        rows, resp = _rows(engine, sqls[turn % 4])
         assert rows == want[turn % 4]
         advice += resp.get("advisorDecisions") or []
     assert any("blockSkip=dense" in a for a in advice), advice
     assert built() == 0
+    coalescer = device.coalescer
+    assert (coalescer.cohorts_launched, coalescer.queries_coalesced) == (0, 0)
+    assert all(e["cohort"] is None and not e["cohort_layouts"]
+               for e in device._pipelines.values())
 
 
 def engines_host(data, _memo=[]):

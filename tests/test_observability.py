@@ -1002,6 +1002,71 @@ class TestSpanTree:
                  if any(s["phase"] == "executor.link" for s in spans)]
         assert len(links) == 1
 
+    def test_callers_together_each_launch_their_own(self, span_cluster):
+        """Four callers send one template under different literals while
+        a launch of the template is dispatched and unfetched (the
+        executor's ``inflight`` is above 1 for every one of them): each
+        gets a launch of its own, at once — no window, no stacking —
+        and the host executor's answer."""
+        from pinot_tpu.engine.engine import QueryEngine
+        from pinot_tpu.query.optimizer import optimize_query
+        from pinot_tpu.sql.compiler import compile_query
+
+        _broker, http, server = span_cluster
+        engine = server.engine
+        dev = engine.device
+        segs = list(engine.tables["st_OFFLINE"].segments.values())
+        host = QueryEngine(device_executor=None)
+        for seg in segs:
+            host.add_segment("st", seg)
+        pre = "SET trace = true; SET useResultCache = false; "
+        literals = [11, 22, 33, 44]
+        q = engine._expand_star(optimize_query(compile_query(
+            SPAN_SQL.format(77).replace("FROM st", "FROM st_OFFLINE"))),
+            segs[0])
+        seen = []
+        dispatch = dev._dispatch
+
+        def counting(*args, **kwargs):
+            seen.append(dev.inflight)
+            return dispatch(*args, **kwargs)
+
+        answers = [None] * len(literals)
+        barrier = threading.Barrier(len(literals))
+
+        def caller(i):
+            barrier.wait(10)
+            answers[i] = _post(http.url, pre + SPAN_SQL.format(literals[i]))
+
+        threads = [threading.Thread(target=caller, args=(i,))
+                   for i in range(len(literals))]
+        held = dev.launch(q, segs)
+        dev._dispatch = counting
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+            assert not held._done  # they answered with it still out
+        finally:
+            del dev._dispatch
+            held.fetch()
+        assert all(a and not a.get("exceptions") for a in answers), answers
+        assert len(seen) == len(literals) and min(seen) > 1, seen
+        waits = []
+        for a, literal in zip(answers, literals):
+            assert a["resultTable"]["rows"] == host.execute(
+                SPAN_SQL.format(literal))["resultTable"]["rows"]
+            spans = _joined(a["traceId"])
+            names = {s["phase"] for s in spans}
+            assert "executor.dispatch" in names
+            assert not {"executor.launch_wait", "executor.stack"} & names
+            waits.append(next(s for s in spans if s["phase"]
+                              == "executor.device_wait")["attrs"])
+        assert len({w["launchId"] for w in waits}) == len(literals)
+        assert {(w["role"], w["cohortSize"], w["cohortPadded"],
+                 w["windowKind"]) for w in waits} == {("solo", 1, 1, "none")}
+
     def test_untraced_request_leaves_nothing(self, span_cluster,
                                              monkeypatch):
         """No Tracer, no kept trace, no profiler annotation — and the

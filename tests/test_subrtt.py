@@ -10,13 +10,13 @@
    response), literal changes miss, and every invalidation edge —
    chunklet promotion, upsert-mask change, seal, batch-LRU eviction
    churn, entry-cap churn — stays bit-identical to a cold cache.
-3. COALESCER STREAM WINDOWS: while cohort N is in its link flight,
-   cohort N+1 buffers arrivals and dispatches when N's fetch completes
-   (the double-buffered launch/fetch stream).
+3. A LAUNCH WAITS FOR NO OTHER (PR 35): with a predecessor's launch
+   dispatched and unfetched, the next request of the template dispatches
+   a program of its own and answers; handles released unfetched drain
+   the executor's in-flight count.
 """
 
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -412,104 +412,83 @@ class TestPartialsCache:
         assert eng.device.partials_bytes == 0
 
 
-class TestStreamWindows:
-    def test_successor_buffers_until_predecessor_fetch(self):
-        """Double-buffered launch/fetch: arrivals during cohort N's link
-        flight accumulate into ONE successor cohort that dispatches when
-        N's fetch completes."""
-        from pinot_tpu.engine.inflight import LaunchCoalescer
+def _compiled(eng, segs, sql):
+    from pinot_tpu.query.optimizer import optimize_query
+    from pinot_tpu.sql.compiler import compile_query
 
-        co = LaunchCoalescer(window_s=0.001, stream_cap_s=5.0)
-        co.force = True
-        release_fetch = threading.Event()
-        dispatched = []
+    return eng._expand_star(optimize_query(compile_query(sql)), segs[0])
 
-        def launch_fn(members):
-            dispatched.append(list(members))
 
-            def resolve():
-                release_fetch.wait(10)
-                return {"x": np.zeros((len(members), 1))}
+class TestLaunchWaitsForNoOther:
+    SQL = ("SELECT zone, COUNT(*), SUM(fare) FROM t WHERE hour < {} "
+           "GROUP BY zone ORDER BY zone LIMIT 200")
 
-            return resolve
+    def test_answers_with_the_predecessor_unfetched(self, segs):
+        """A predecessor of the same template and batch is dispatched and
+        NOT fetched: the next request dispatches at once, as a launch of
+        its own, and answers while the predecessor is still out."""
+        eng, host = make_engine(segs), make_engine(segs, device=None)
+        dev = eng.device
+        dev.partials_cache_enabled = False  # every request must launch
+        rows_of(eng, self.SQL.format(3))    # builds the program
+        co = dev.coalescer
+        windows = (co.cohorts_launched, co.queries_coalesced)
+        first = dev.launch(_compiled(eng, segs, self.SQL.format(5)),
+                           list(segs))
+        try:
+            assert dev.inflight == 1
+            answered = threading.Event()
+            got = []
 
-        # cohort 1: leader dispatches, fetch blocks on release_fetch
-        c1, _ = co.join("k", {"p": 1}, launch_fn)
-        t1 = threading.Thread(target=lambda: c1.resolve_member(0))
-        t1.start()
-        time.sleep(0.05)
-        # cohort 2: two arrivals during cohort 1's flight
-        out = [None, None]
+            def second():
+                got.append(rows_of(eng, self.SQL.format(9)))
+                answered.set()
 
-        def second(i):
-            c, idx = co.join("k", {"p": 10 + i}, launch_fn)
-            out[i] = (c, idx)
+            t = threading.Thread(target=second, daemon=True)
+            t.start()
+            assert answered.wait(120), \
+                "the second request waited for the first one's fetch"
+            t.join(10)
+            # ... which is still out: nothing but the test fetches it
+            assert dev.inflight == 1 and not first._done
+            assert got[0] == rows_of(host, self.SQL.format(9))
+            assert (co.cohorts_launched, co.queries_coalesced) == windows
+        finally:
+            first.fetch()
+        assert dev.inflight == 0 and not dev._inflight_launches
 
-        w0 = threading.Thread(target=second, args=(0,))
-        w0.start()
-        time.sleep(0.1)
-        w1 = threading.Thread(target=second, args=(1,))
-        w1.start()
-        time.sleep(0.2)
-        # predecessor still fetching: the successor must NOT have
-        # dispatched yet (its window keys off c1.fetch_done)
-        assert len(dispatched) == 1
-        assert co.stream_windows == 1
-        release_fetch.set()
-        t1.join(10)
-        w0.join(10)
-        w1.join(10)
-        assert len(dispatched) == 2
-        # BOTH second-wave arrivals buffered into one cohort
-        assert len(dispatched[1]) == 2
-        c2a, _ = out[0]
-        c2b, _ = out[1]
-        assert c2a is c2b
-        # cohort 2 resolves normally
-        c2a.resolve_member(0)
-
-    def test_all_abandoned_cohort_signals_fetch_done(self, segs):
+    def test_a_forced_cohort_released_unfetched_drains(self, segs):
         """Members that release() without fetching (deadline expiry,
-        upstream failure) must still conclude the cohort: once every
-        member abandons, fetch_done fires and the next stream window
-        dispatches immediately instead of polling out its cap."""
+        upstream failure) leave no pin and no in-flight count behind,
+        the whole cohort of them included."""
         eng = make_engine(segs)
         dev = eng.device
         dev.partials_cache_enabled = False  # handles must reach the cohort
+        rows_of(eng, self.SQL.format(3))
         co = dev.coalescer
-        co.force = True
-        from pinot_tpu.query.optimizer import optimize_query
-        from pinot_tpu.sql.compiler import compile_query
-
-        q = optimize_query(compile_query(
-            "SELECT zone, COUNT(*) FROM t GROUP BY zone"))
-        q = eng._expand_star(q, segs[0])
+        launched = co.cohorts_launched
+        co.force, co.window_s = True, 0.2
         try:
-            handle = dev.launch(q, list(segs))
-            handle.release()  # abandoned, never fetched
+            barrier = threading.Barrier(2)
+
+            def abandon(i):
+                q = _compiled(eng, segs, self.SQL.format(5 + i))
+                barrier.wait(10)
+                dev.launch(q, list(segs)).release()  # never fetched
+
+            threads = [threading.Thread(target=abandon, args=(i,))
+                       for i in range(2)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
         finally:
-            co.force = False
-        done = co._last_dispatched.get(next(iter(co._last_dispatched)))
-        assert done is not None and done.is_set()
-        assert dev.inflight == 0
-
-    def test_stream_cap_bounds_abandoned_predecessor(self):
-        """A predecessor nobody ever fetches must not stall the stream
-        past stream_cap_s."""
-        from pinot_tpu.engine.inflight import LaunchCoalescer
-
-        co = LaunchCoalescer(window_s=0.001, stream_cap_s=0.05)
-        co.force = True
-
-        def launch_fn(members):
-            return lambda: {"x": np.zeros((len(members), 1))}
-
-        c1, _ = co.join("k", {"p": 1}, launch_fn)  # never fetched
-        t0 = time.monotonic()
-        c2, _ = co.join("k", {"p": 2}, launch_fn)
-        took = time.monotonic() - t0
-        assert took < 2.0  # bounded by the cap, not the 10s member wait
-        assert c2.ready.is_set()
+            co.force, co.window_s = False, 0.003
+        assert co.cohorts_launched > launched
+        assert dev.inflight == 0 and not dev._inflight_launches
+        # and the executor still answers
+        assert rows_of(eng, self.SQL.format(7)) == rows_of(
+            make_engine(segs, device=None), self.SQL.format(7))
 
 
 class TestExplainAndLog:
