@@ -43,6 +43,7 @@ from pinot_tpu.engine.inflight import InflightLaunch, LaunchCoalescer
 from pinot_tpu.engine.params import (
     BatchContext,
     DeviceUnsupported,
+    KeySpaceFull,
     build_expr,
     build_filter,
     expr_bounds,
@@ -52,6 +53,7 @@ from pinot_tpu.ops import agg as agg_ops
 from pinot_tpu.ops import blockskip as bs_ops
 from pinot_tpu.ops import device_reduce as dr_ops
 from pinot_tpu.ops import hll as hll_ops
+from pinot_tpu.ops import keysorted as ks_ops
 from pinot_tpu.ops import masks as mask_ops
 from pinot_tpu.ops import radix_groupby as radix_ops
 from pinot_tpu.ops.transform import get_function
@@ -100,10 +102,32 @@ NARROW_BLOCKS = 128      # live blocks a launch keeps (hi one-hot rows)
 NARROW_GROUPS = 1 << 12  # live cells a launch hands on
 NARROW_AGGS = ("count", "sum", "avg")
 _COUNT_ONLY = (("count", None, None),)
+# FULL key space: the same large key space (past NARROW_MIN_CELLS, within
+# MAX_DENSE_GROUPS) where the filter does NOT leave few keys - a ranking
+# over whole hierarchies or an entity key, no slice: pass 1 finds more
+# live blocks than the narrowed table holds. Its rows are summed in KEY
+# ORDER (ops/keysorted.py): the batch keeps, a set of key columns, the
+# rows' order by cartesian key, each cell's first row in it, and the
+# statement's filter and value columns projected into it; a launch masks,
+# runs one cumulative sum a channel and reads it at the cells' boundaries.
+# The table is the key space itself (the dense regime's output form), and
+# the trim's selection (ops/device_reduce.py select_top) takes its top
+# rows without a sort at table length. Which of the two regimes a
+# template takes on a batch is what the executor OBSERVED and remembers
+# (DeviceExecutor._key_spaces): its first launch there counts the live
+# blocks by a program of its own (_live_blocks: pass 1 alone), and a
+# narrowed launch that overflows all the same (few blocks, over 4,096
+# live cells; other literals) is launched again full and the template
+# marked full. One key column has no hierarchy to slice by: past the
+# floor it is full from the first.
+# The filter's mask over the projected rows needs each row's segment for
+# the launch's ``ps_alive``: compared against, segment by segment.
+FULL_MAX_SEGMENTS = 16
+FULL_MAX_PLANES = 4      # a value's projected plane is one uint32 a row
 # what executor.dispatch / device_wait, the flight record and EXPLAIN
 # ANALYZE call a group-by's key space (``groupbyKeySpace``)
 KEY_SPACES = {"groupby": "dense", "groupby_narrow": "narrowed",
-              "groupby_sorted": "sorted"}
+              "groupby_sorted": "sorted", "groupby_full": "full"}
 
 log = logging.getLogger("pinot_tpu.engine.device")
 
@@ -415,6 +439,68 @@ def plan_prepared_groupby(template, widths, n_total: int, mm_mode: str,
     if route is None or not all(mm.prepared_tile_ok(b) for b in blks):
         return None
     return (route, tuple("gk::" + c for c in group_cols), tuple(planes))
+
+
+def plan_full_groupby(filter_tpl, group_cols, aggs, widths, offsets: dict,
+                      n_segments: int):
+    """Template-build plan of the FULL regime's operands (KEY_SPACES has
+    the why), short of what only the built key order can say: ``(key
+    columns' joined name, ((filter cols key, its projected cols key), ...),
+    ((agg index, projected value cols key, nplanes), ...))``, or None
+    where the rows cannot be summed in key order: a SUM/AVG argument that
+    is no integer column or column-only integer expression of known range
+    within FULL_MAX_PLANES bytes, a filter column that is no plain (S, L)
+    plane (multi-value, packed below a byte), more segments than the mask
+    compares against. The launch then sums by the XLA scatter. The byte
+    budget and the mesh are the executor's to check."""
+    if n_segments > FULL_MAX_SEGMENTS:
+        return None
+    name = ",".join(group_cols)
+    planes = []
+    for i, (agg, argt, extra) in enumerate(aggs):
+        if agg not in ("sum", "avg"):
+            continue
+        ek = _column_expr_key(argt, widths) \
+            if isinstance(extra, tuple) else None
+        if ek is None or not extra[0] or extra[0] > FULL_MAX_PLANES:
+            return None
+        planes.append((i, f"gp::{name}::" + BatchContext.groupby_planes_key(
+            ek, offsets[i], extra[0]), extra[0]))
+    fcols = []
+    for key in sorted(DeviceExecutor._needed_columns(filter_tpl)):
+        w = _col_width(widths, key)
+        if key.startswith("mv::") or w is None or w[1]:
+            return None
+        fcols.append((key, f"gp::{name}::{key}"))
+    return (name, tuple(fcols), tuple(planes))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "filter_tpl", "group_cols", "group_cards", "wsig"))
+def _live_blocks(cols, n_docs, params, *, filter_tpl, group_cols,
+                 group_cards, wsig):
+    """How many 128-cell blocks of the cartesian key space hold a row the
+    filter keeps: the narrowed regime's pass 1 as a program of its own
+    (the filter, the combined id, one XLA scatter-add of the rows into
+    their blocks), run ONCE a template and batch, before either regime's
+    program is built - a template that is full builds no narrowed program
+    and waits for no overflow. Seconds to build and a third of a second
+    to run at 37.5M rows, where the narrowed pipeline is 20-35 s to build
+    (PERF.md, PR 36)."""
+    widths = dict(wsig)
+    per_col = [_ids_col(cols, c, widths) for c in group_cols]
+    shape = per_col[0].shape
+    mask = _eval_filter(filter_tpl, cols, params, shape, widths) \
+        & mask_ops.valid_mask(n_docs, shape[1], batched=True)
+    num_groups = 1
+    for c in group_cards:
+        num_groups *= c
+    n_blocks = -(-num_groups // NARROW_BLOCK)
+    gid = agg_ops.group_ids_combine(per_col, group_cards, mask,
+                                    n_blocks * NARROW_BLOCK)
+    rows_in = agg_ops.group_count(
+        gid >> (NARROW_BLOCK.bit_length() - 1), n_blocks)
+    return jnp.sum(rows_in > 0, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -945,10 +1031,15 @@ def _table_len(template) -> int:
     return n
 
 
-def _narrow_outcome(outs: dict) -> dict:
-    """What a narrowed group-by's result says of its key space: the
-    cells of the blocks it kept (the fullest member's, for a cohort),
-    and ``overflow`` where a member's did not fit."""
+def _key_space_outcome(outs: dict, space=None) -> dict:
+    """What only a group-by's result says of its key space. Narrowed:
+    the cells of the blocks it kept (the fullest member's, for a
+    cohort), and ``overflow`` where a member's did not fit. Full
+    (``space``: what the launch said it was): the cells that hold a row."""
+    if space == "full":
+        live = outs["n_present_total"] if "n_present_total" in outs \
+            else (np.asarray(outs["gcount"]) > 0).sum(axis=-1)
+        return {"keySpaceLive": int(np.max(live))}
     live = outs.get("narrow_live")
     if live is None:
         return {}
@@ -960,9 +1051,12 @@ def _narrow_outcome(outs: dict) -> dict:
     return read
 
 
-# cols keys of the dense group-by's prepared kernel operands
-# (BatchContext.groupby_operand): lane-major, not (S, L)
-_GB_OPERAND_PREFIXES = ("gk::", "gv::")
+# cols keys of a group-by's prepared operands (BatchContext.
+# groupby_operand), none of them (S, L): the dense kernel's lane-major ids
+# and byte planes; the full regime's key order ("go::<key columns>": the
+# permutation, "gs::<key columns>": the cells' first rows) and the planes
+# projected into it ("gp::<key columns>::<what>")
+_GB_OPERAND_PREFIXES = ("gk::", "gv::", "go::", "gs::", "gp::")
 
 
 def build_pipeline(template, mm_mode: str = "auto",
@@ -1322,11 +1416,20 @@ def build_pipeline(template, mm_mode: str = "auto",
         if shape == "groupby_narrow":
             return _aggregate_narrowed(cols, params, mask, outs, prep)
 
-        if shape == "groupby":
+        if shape == "groupby_full" and prep is not None:
+            return _aggregate_full(cols, params, outs, prep)
+
+        if shape in ("groupby", "groupby_full"):
             # columns are already global ids: the group key IS the column
             per_col = [_ids_col(cols, c, widths) for c in group_cols]
             gid = agg_ops.group_ids_combine(per_col, group_cards, mask, num_groups)
-            if prep is not None:
+            if shape == "groupby_full":
+                # no key order to sum in (plan_full_groupby declined, or
+                # the gathered rows of the block-skip form): the exact XLA
+                # scatter into the key space, not a hi one-hot of
+                # cells / 128 rows
+                mm_done = set()
+            elif prep is not None:
                 mm_done = _prepared_groupby(
                     prep, cols, params, _mask_lanes(mask), group_cards,
                     num_groups, outs, mm_mode, pallas_mode)
@@ -1566,6 +1669,58 @@ def build_pipeline(template, mm_mode: str = "auto",
             outs["narrow_live"] = n_live
         return outs
 
+    def _aggregate_full(cols, params, outs, prep):
+        """COUNT/SUM/AVG over a FULL key space, from the batch's rows in
+        key order (KEY_SPACES and ops/keysorted.py have the why). The
+        launch's own work: the filter over the projected columns, each
+        row's segment against ``ps_alive`` and its position against the
+        number of real rows; a count channel and each value split into
+        planes narrow enough that the fullest cell's sum stays under
+        2^32; one cumulative sum a channel, read at the cells'
+        boundaries. The table is the key space, as the dense form's."""
+        _name, starts_key, seg_key, fcols, planes, plane_bits = prep[3]
+        starts = cols[starts_key]
+        seg = cols[seg_key]
+        with jax.named_scope("pinot.mask"):
+            at = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) \
+                * seg.shape[1] \
+                + jax.lax.broadcasted_iota(jnp.int32, seg.shape, 1)
+            keep = at < starts[num_groups]
+            alive = params.get("ps_alive")
+            if alive is not None:
+                of_alive = jnp.zeros(seg.shape, dtype=bool)
+                for s_i in range(alive.shape[0]):
+                    of_alive |= (seg == s_i) & alive[s_i].astype(bool)
+                keep &= of_alive
+            keep &= _eval_filter(
+                filter_tpl, {key: cols[pkey] for key, pkey in fcols},
+                params, seg.shape, widths)
+        with jax.named_scope("pinot.full_sums"):
+            channels = [keep.astype(jnp.uint32)]
+            split = []
+            for i, pkey, nplanes in planes:
+                v = jnp.where(keep, cols[pkey], jnp.uint32(0))
+                n_split = -(-8 * nplanes // plane_bits)
+                first = len(channels)
+                if n_split == 1:
+                    channels.append(v)
+                else:
+                    channels.extend(
+                        (v >> (plane_bits * k)) & ((1 << plane_bits) - 1)
+                        for k in range(n_split))
+                split.append((i, first, n_split))
+            sums = ks_ops.segment_sums(starts, channels)
+        with jax.named_scope("pinot.recombine"):
+            gcount = sums[0].astype(jnp.int64)
+            outs["gcount"] = gcount
+            for i, first, n_split in split:
+                tot = jnp.zeros_like(gcount)
+                for k in range(n_split):
+                    tot = tot + (sums[first + k].astype(jnp.int64)
+                                 << (plane_bits * k))
+                outs[f"a{i}_sum"] = tot + gcount * params[f"off{i}"]
+        return outs
+
     return pipeline  # caller jits (single-device) or shard_maps (mesh)
 
 
@@ -1670,7 +1825,17 @@ class DeviceExecutor:
         # launches of the narrowed key space, and queries whose live keys
         # did not fit it (answered by the host)
         self.groupby_narrowed_launches = 0
+        # narrowed launches whose overflow the HOST answered (one that is
+        # launched again in the full regime is not among them)
         self.groupby_narrow_overflows = 0
+        self.groupby_full_launches = 0
+        self.groupby_full_table_bytes = 0  # the largest key-space table
+        # (filter template, key columns, aggregates, batch) -> is its
+        # large key space full? What the first launch's count of live
+        # blocks said, or a narrowed launch's overflow since. Insertion-
+        # ordered, the oldest dropped past MAX_FAILURE_KEYS
+        self._key_spaces: dict = {}
+        self.groupby_key_space_probes = 0
         # device-error recovery (failure-domain hardening): per-(template,
         # batch) failure counts feed a quarantine circuit breaker — a
         # pipeline that keeps failing on device routes to the host path
@@ -1893,6 +2058,9 @@ class DeviceExecutor:
                     dict(self.groupby_operand_launches),
                 "groupby_narrowed_launches": self.groupby_narrowed_launches,
                 "groupby_narrow_overflows": self.groupby_narrow_overflows,
+                "groupby_full_launches": self.groupby_full_launches,
+                "groupby_full_table_bytes": self.groupby_full_table_bytes,
+                "groupby_key_space_probes": self.groupby_key_space_probes,
             }
         per_batch = [
             {
@@ -2177,8 +2345,9 @@ class DeviceExecutor:
                     self.fetch_leaves_total += len(bufs)
                 self.metrics.time_ms("deviceFetchMs", wait * 1e3)
                 outs = _unpack_outs(bufs, layout)
-                # what only the result can say of a narrowed key space
-                read = _narrow_outcome(outs)
+                # what only the result can say of the key space
+                read = _key_space_outcome(
+                    outs, stamp["attrs"].get("groupbyKeySpace"))
                 stamp["attrs"].update(read)
                 wait_span.set(**read)
                 if flight is not None:
@@ -2264,7 +2433,7 @@ class DeviceExecutor:
             if gather_bytes:
                 rec["gatherBytes"] = gather_bytes
             rec.update(flight.get("origin") or {})
-            rec.update(_narrow_outcome(outs))
+            rec.update(_key_space_outcome(outs, rec.get("groupbyKeySpace")))
             gbps = None
             if not cache_hit and kernel_s > 1e-9:
                 gbps = bytes_moved / kernel_s / 1e9
@@ -2313,6 +2482,64 @@ class DeviceExecutor:
         return {"kernels": kernels}
 
     # ---- template build --------------------------------------------------
+    def _note_key_space(self, key, full: bool) -> None:
+        with self._lock:
+            self._key_spaces[key] = full
+            while len(self._key_spaces) > self.MAX_FAILURE_KEYS:
+                self._key_spaces.pop(next(iter(self._key_spaces)))
+
+    def _key_space_is_full(self, ctx, batch_key, filter_tpl, group_cols,
+                           group_cards, agg_tpls, params) -> bool:
+        """Is this template's large key space full on this batch? What
+        was observed of it before; else its live 128-cell blocks are
+        counted now (``_live_blocks``), once, and the answer kept: more
+        than the narrowed table holds is full. A filter over planes the
+        count cannot read (multi-value blocks) is taken as narrowed, as
+        before the full regime."""
+        key = (filter_tpl, group_cols, agg_tpls, batch_key)
+        with self._lock:
+            known = self._key_spaces.get(key)
+        if known is not None:
+            return known
+        needed = self._needed_columns(filter_tpl) | set(group_cols)
+        widths = self._plain_widths(ctx, needed)
+        full = False
+        if set(widths) == needed:
+            cols = {c: ctx.decoded_column(c[4:]) if c.startswith("dv::")
+                    else ctx.column(c) for c in needed}
+            lits = {k: v for k, v in params.items()
+                    if isinstance(v, jax.Array)}
+            for c in needed:
+                offset = ctx.width_plan(c).offset
+                if offset is not None:
+                    lits["fo::" + c] = jnp.asarray(np.asarray(
+                        offset, dtype=np.dtype(ctx.width_plan(c).wide)))
+            with self._lock:
+                self.groupby_key_space_probes += 1
+            full = int(_live_blocks(
+                cols, ctx.n_docs_dev, lits, filter_tpl=filter_tpl,
+                group_cols=group_cols, group_cards=group_cards,
+                wsig=tuple(sorted(widths.items())))) > NARROW_BLOCKS
+        self._note_key_space(key, full)
+        return full
+
+    @staticmethod
+    def _plain_widths(ctx, keys) -> dict:
+        """{cols key: its width plan's signature} for the stored and
+        decoded planes among ``keys`` (zone maps, hashes, byte planes,
+        multi-value blocks and prepared operands have none)."""
+        return {c: ctx.width_plan(c).sig() for c in sorted(keys)
+                if c.startswith("dv::") or not c.startswith(
+                    (bs_ops.ZLO, bs_ops.ZHI, "sk::", "hh::", "bp::", "mv::")
+                    + _GB_OPERAND_PREFIXES)}
+
+    def _groups_limit(self, opts: dict) -> int:
+        """numGroupsLimit as the statement has it: its SET, else the
+        engine's default."""
+        if "numgroupslimit" in opts:
+            return max(1, int(opts["numgroupslimit"]))
+        return self.num_groups_limit
+
     def _agg_template(self, i: int, a: Expression, ctx: BatchContext, params, counter):
         name = a.name
         if name in ("distinctcountbitmap", "segmentpartitioneddistinctcount"):
@@ -2443,6 +2670,12 @@ class DeviceExecutor:
                                              aggs, final, alive, tpl_box,
                                              tracer, reduce_mode, tpl_span)
                 handle.tracer = tracer
+                if tpl_box and tpl_box[0][0] == "groupby_narrow":
+                    # one that finds its key space full is launched
+                    # again, full, by its own fetch
+                    handle.relaunch = functools.partial(
+                        self.launch, q, segments, final, alive, tracer,
+                        reduce_mode)
                 self.metrics.time_ms(
                     "deviceLaunchMs",
                     (time.perf_counter() - t_launch) * 1e3)
@@ -2547,6 +2780,7 @@ class DeviceExecutor:
         )
         offsets = params.pop("__offsets__", {})
         shape = "groupby" if group_cols else "agg"
+        full_plan = None  # plan_full_groupby's, for shape "groupby_full"
         if group_cols and total > MAX_DENSE_GROUPS:
             # sort-based high-cardinality regime (MAP_BASED analog): no
             # dense accumulators, so only the additive/extremal aggs fit
@@ -2563,9 +2797,24 @@ class DeviceExecutor:
                     raise DeviceUnsupported(
                         f"agg {a.name} not on the sorted group-by path")
             shape = "groupby_sorted"
-        elif len(group_cols) > 1 and total > NARROW_MIN_CELLS and all(
+        elif group_cols and total > NARROW_MIN_CELLS and all(
                 a.name in NARROW_AGGS for a in aggs):
-            shape = "groupby_narrow"
+            # large key space: narrowed until a launch has seen it full
+            # (one key column: full from the first, where its rows can
+            # be summed in key order - decided below, with the plan)
+            if len(group_cols) > 1:
+                shape = "groupby_narrow"
+            if self.mesh is None and (
+                    len(group_cols) == 1 or self._key_space_is_full(
+                        ctx, batch_key, filter_tpl, group_cols, group_cards,
+                        agg_tpls, params)):
+                full_plan = plan_full_groupby(
+                    filter_tpl, group_cols, agg_tpls, self._plain_widths(
+                        ctx, self._needed_columns(("of", filter_tpl) + tuple(
+                            t[1] for t in agg_tpls))),
+                    offsets, ctx.S)
+                if full_plan is not None or len(group_cols) > 1:
+                    shape = "groupby_full"
         for name, argt, extra in agg_tpls:
             if shape == "groupby" and name in (
                     "distinctcount", "distinctcounthll", "hllmerge"):
@@ -2574,8 +2823,10 @@ class DeviceExecutor:
                     cells *= c
                 if cells > MAX_PRESENCE_CELLS:
                     raise DeviceUnsupported(f"{name} per-group state too large ({cells})")
-        # the keyed table's length (0: the table is the key space itself)
-        sorted_k = {"groupby_sorted": min(self.num_groups_limit,
+        # the keyed table's length (0: the table is the key space itself);
+        # the sorted regime's is the statement's numGroupsLimit, as
+        # _to_intermediate reads it
+        sorted_k = {"groupby_sorted": min(self._groups_limit(opts),
                                           MAX_SORTED_GROUPS),
                     "groupby_narrow": NARROW_GROUPS}.get(shape, 0)
         # final only changes sketch outputs; don't fork the jit cache for
@@ -2626,7 +2877,10 @@ class DeviceExecutor:
                 and bool_option(opts, "useblockskip", None) is not False \
                 and ctx.pad_to % bs_ops.BLOCK_ROWS == 0:
             prunable, zone_cols = bs_ops.prunable_columns(filter_tpl)
-            use_bs = prunable and bool(zone_cols)
+            # a full key space is no slice of the table: its rows are read
+            # in key order, where a zone block is no run of rows
+            use_bs = prunable and bool(zone_cols) \
+                and shape != "groupby_full"
         # advisor: skip-vs-dense and candidate-bound selection from the
         # template's MEASURED selectivity. ``use_bs`` carries the choice
         # as its truthiness: False = dense, True = static CAND_FRACTION,
@@ -2702,18 +2956,15 @@ class DeviceExecutor:
         # so same-plan queries still stack. FOR offsets ride as per-batch
         # "fo::<key>" params (replicated on the mesh, stacked per cohort
         # member) — the offset VALUE stays out of the compiled template.
-        widths = {}
+        widths = self._plain_widths(ctx, needed)
         host_sigs = params.pop("__hostsig__", [])
-        for c in sorted(needed):
-            if c.startswith(("dv::",)) or not c.startswith(
-                    (bs_ops.ZLO, bs_ops.ZHI, "sk::", "hh::", "bp::", "mv::")):
-                plan = ctx.width_plan(c)
-                widths[c] = plan.sig()
-                if plan.offset is not None:
-                    fo = np.asarray(plan.offset, dtype=np.dtype(plan.wide))
-                    params["fo::" + c] = jnp.asarray(fo)
-                    host_sigs.append(("fo::" + c, fo.dtype.str, (),
-                                      fo.tobytes()))
+        for c in sorted(widths):
+            plan = ctx.width_plan(c)
+            if plan.offset is not None:
+                fo = np.asarray(plan.offset, dtype=np.dtype(plan.wide))
+                params["fo::" + c] = jnp.asarray(fo)
+                host_sigs.append(("fo::" + c, fo.dtype.str, (),
+                                  fo.tobytes()))
         wsig = tuple(sorted(widths.items()))
 
         # on-device final reduce (ops/device_reduce.py): plan the ORDER
@@ -2734,9 +2985,18 @@ class DeviceExecutor:
                 if note:
                     gts = gts2
                     adv_notes.append(note)
-            table_len = total if shape == "groupby" else sorted_k
+            table_len = sorted_k or total
+            # the SUMs whose int64 leaf the selection may order by: an
+            # integer argument of known range whose sum over every row
+            # stays where float64 holds each whole number
+            rows = ctx.S * ctx.pad_to
+            exact_int = frozenset(
+                i for i, (name, _argt, extra) in enumerate(agg_tpls)
+                if name == "sum" and isinstance(extra, tuple) and extra[0]
+                and i in offsets and rows * (abs(offsets[i]) + (
+                    1 << (8 * extra[0]))) < dr_ops.EXACT_INT_ORDER)
             trim = dr_ops.plan_trim(q, group_exprs, aggs, shape, table_len,
-                                    reduce_mode, gts)
+                                    reduce_mode, gts, exact_int)
             if trim is not None:
                 tr_k = np.int32(dr_ops.trim_keep_count(
                     q, reduce_mode, gts))
@@ -2785,14 +3045,47 @@ class DeviceExecutor:
                             gb_exprs[key] = functools.partial(
                                 self._build_expr_planes, ctx, argt, widths,
                                 params, offsets[i], nplanes)
+        if full_plan is not None:
+            # the full regime's operands: the key order first (the fullest
+            # cell's rows say how wide a value plane may be), then what
+            # the statement reads, projected into it
+            name, fcols, vplanes = full_plan
+            gb_keys = ("gs::" + name, f"gp::{name}::seg") \
+                + tuple(k for _c, k in fcols) \
+                + tuple(k for _i, k, _n in vplanes)
+            # with what they are built from: the order itself, the key
+            # columns' ids, the values' byte planes
+            cost = ctx.groupby_operand_cost(
+                gb_keys + ("go::" + name,)
+                + tuple("gk::" + c for c in group_cols)
+                + tuple(k.split("::", 2)[2] for _i, k, _n in vplanes))
+            plane_bits = 0
+            if not cost \
+                    or ctx.device_bytes() + cost <= self.MAX_CACHED_BYTES:
+                plane_bits = ks_ops.plane_bits_for(
+                    ctx.key_order_rows(group_cols))
+            if plane_bits:
+                needed.update(gb_keys)
+                for i, key, nplanes in vplanes:
+                    argt = agg_tpls[i][1]
+                    if argt[0] not in ("raw", "dictval"):
+                        gb_exprs[key] = functools.partial(
+                            self._build_expr_planes, ctx, argt, widths,
+                            params, offsets[i], nplanes)
+                prepared = ("keysorted", (), (), (
+                    name, "gs::" + name, f"gp::{name}::seg", fcols, vplanes,
+                    plane_bits))
         # what the launch's spans, its flight record and EXPLAIN ANALYZE
         # say of it: prepared | built (this launch built them) | perLaunch
-        gb_operands = None if shape not in ("groupby", "groupby_narrow") \
+        gb_operands = None if shape not in (
+            "groupby", "groupby_narrow", "groupby_full") \
             else "perLaunch" if prepared is None else "prepared"
-        # and of its key space: dense | narrowed | sorted (| overflow,
-        # which only the result can say: _make_resolve)
+        # and of its key space: dense | narrowed | sorted | full (|
+        # overflow, which only the result can say: _make_resolve)
         key_space = {"groupbyKeySpace": KEY_SPACES[shape],
                      "keySpaceCells": total} if shape in KEY_SPACES else {}
+        if shape == "groupby_full" and trim is not None:
+            key_space["trimSelect"] = dr_ops.trim_select(trim)
 
         pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode,
                                   prepared)
@@ -2892,6 +3185,15 @@ class DeviceExecutor:
         if shape == "groupby_narrow":
             with self._lock:
                 self.groupby_narrowed_launches += 1
+        elif shape == "groupby_full":
+            # the key-space table a launch builds on the device: a count
+            # and each SUM/AVG's total, 8 bytes a cell each
+            table_bytes = 8 * total * (1 + sum(
+                t[0] in ("sum", "avg") for t in agg_tpls))
+            with self._lock:
+                self.groupby_full_launches += 1
+                self.groupby_full_table_bytes = max(
+                    self.groupby_full_table_bytes, table_bytes)
         flight["origin"] = origin
         if os.environ.get("PINOT_TPU_WIDTH_AUDIT", "") not in ("", "0"):
             _width_audit(ctx, cols, widths)
@@ -3366,20 +3668,25 @@ class DeviceExecutor:
             # the capped table dropped groups: re-run on the host so device
             # truncation policy never shapes results (host applies its own
             # numGroupsLimit semantics)
+            why = (f"{KEY_SPACES[shape]} group table overflow "
+                   f"({int(outs['n_groups_total'])} > {sorted_k})")
+            if shape == "groupby_narrow" and self.mesh is None:
+                # the key space is full: remember it of this template on
+                # this batch; the launch's fetch launches it again
+                self._note_key_space(
+                    (template[1], group_cols, agg_tpls,
+                     self._batch_key(ctx.segments)), True)
+                raise KeySpaceFull(why)
             if shape == "groupby_narrow":
                 with self._lock:
                     self.groupby_narrow_overflows += 1
-            raise DeviceUnsupported(
-                f"{KEY_SPACES[shape]} group table overflow "
-                f"({int(outs['n_groups_total'])} > {sorted_k})")
+            raise DeviceUnsupported(why)
         opts = q.options_ci()
         # numGroupsLimit applies on the device path too (engine default or
         # per-query SET override): excess groups drop arbitrarily-but-
         # deterministically (gid order), like the reference's hash-order
         # drops, and the stats flag marks the result plan-dependent-partial
-        limit = self.num_groups_limit
-        if "numgroupslimit" in opts:
-            limit = max(1, int(opts["numgroupslimit"]))
+        limit = self._groups_limit(opts)
         trimmed = "trim_keys" in outs
         t_reduce = time.perf_counter()
         # plan-advisor group-count feedback: the template's OBSERVED

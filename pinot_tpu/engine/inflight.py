@@ -33,6 +33,7 @@ import threading
 import time
 
 from pinot_tpu.common.trace import span as trace_span
+from pinot_tpu.engine.params import KeySpaceFull
 
 
 class InflightLaunch:
@@ -109,11 +110,25 @@ class InflightLaunch:
             self._executor._note_device_success(
                 self._template, self._batch_key)
             adv_key = getattr(self, "adv_key", None)
-            with trace_span("executor.unpack", self.tracer):
-                result = self._executor._to_intermediate(
-                    self._q, self._ctx, self._template, outs, self._aggs,
-                    cache_hit=self.cache_hit, adv_key=adv_key,
-                    adv_trim_keep=getattr(self, "adv_trim_keep", None))
+            try:
+                with trace_span("executor.unpack", self.tracer):
+                    result = self._executor._to_intermediate(
+                        self._q, self._ctx, self._template, outs,
+                        self._aggs, cache_hit=self.cache_hit,
+                        adv_key=adv_key,
+                        adv_trim_keep=getattr(self, "adv_trim_keep", None))
+            except KeySpaceFull:
+                # the narrowed table overflowed and the executor now knows
+                # the template full on this batch: the same statement,
+                # launched again, sums over the whole key space on the
+                # device (this launch's pin drops in the finally below;
+                # the new launch holds its own)
+                again = getattr(self, "relaunch", None)
+                if again is None:
+                    raise
+                handle = again()
+                handle.deadline = self.deadline
+                return handle.fetch()
             result.stats.partials_cache_hit = self.cache_hit
             # plan-advisor stamps + cache-hit feedback (ISSUE 17): the
             # decisions this launch ran with ride the result's stats to

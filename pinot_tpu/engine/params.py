@@ -56,6 +56,13 @@ class DeviceUnsupported(Exception):
     """Query shape not handled by the device pipeline → host fallback."""
 
 
+class KeySpaceFull(DeviceUnsupported):
+    """A narrowed group-by launch found its key space full. The executor
+    has remembered the template full on its batch; the launch's fetch
+    launches it again in the full regime (engine/inflight.py), and only
+    where it cannot does this reach the engine as a host fallback."""
+
+
 _NUMERIC_KINDS = ("i", "u", "f")
 
 # ---------------------------------------------------------------------------
@@ -181,6 +188,8 @@ class BatchContext:
         # live and die with this batch
         self._gb_operands: dict = {}
         self._gb_operand_bytes = 0
+        # the full regime's key orders: "<cols>" -> rows of the fullest cell
+        self._key_order_rows: dict = {}
         # col key -> ((S, NB) lo, (S, NB) hi) device zone maps in the
         # column's device value space (global ids / decoded / raw); built
         # eagerly alongside the column block (the host data is in hand
@@ -675,7 +684,9 @@ class BatchContext:
                 return arr, False
             from pinot_tpu.ops import groupby_mm as mm
 
-            if build is not None:
+            if key.startswith(("go::", "gs::", "gp::")):
+                arr = self._key_ordered_locked(key, build)
+            elif build is not None:
                 arr = build()
             elif key.startswith("gk::"):
                 name = key[4:]
@@ -695,6 +706,50 @@ class BatchContext:
             self._note_resident(arr)
             return arr, True
 
+    def _key_ordered_locked(self, key: str, build=None):
+        """The FULL key-space regime's operands (ops/keysorted.py), a set
+        of key columns ``<cols>`` (joined by commas): ``go::<cols>`` the
+        rows' order by cartesian key and ``gs::<cols>`` each cell's first
+        row in it (one program builds both, from the columns' ``gk::``
+        ids); ``gp::<cols>::<what>`` a plane projected into that order -
+        ``seg`` each row's segment, a ``gv::`` key that value less its
+        offset as one uint32 a row (from the byte planes, ``build`` making
+        an expression's), any other cols key the stored plane itself."""
+        from pinot_tpu.ops import keysorted as ks
+
+        kind, rest = key[:2], key[4:]
+        if kind in ("go", "gs"):
+            names = tuple(rest.split(","))
+            ids = tuple(self.groupby_operand("gk::" + c)[0] for c in names)
+            perm, starts, fullest = ks.key_order(ids, cards=tuple(
+                len(self._global_dict_locked(c)) for c in names))
+            self._key_order_rows[rest] = int(fullest)
+            other = ("gs::" if kind == "go" else "go::") + rest
+            self._gb_operands[other] = starts if kind == "go" else perm
+            self._gb_operand_bytes += int(self._gb_operands[other].nbytes)
+            self._note_resident(self._gb_operands[other])
+            return perm if kind == "go" else starts
+        names, what = rest.split("::", 1)
+        perm = self.groupby_operand("go::" + names)[0]
+        if what.startswith("gv::"):
+            return ks.project_value(self.groupby_operand(what, build)[0],
+                                    perm)
+        if what == "seg":
+            stored = jnp.broadcast_to(
+                jnp.arange(self.S, dtype=jnp.uint8)[:, None],
+                (self.S, self.pad_to))
+        else:
+            stored = self._decoded_column_locked(what[4:]) \
+                if what.startswith("dv::") else self._column_locked(what)
+        return ks.project_plane(stored, perm)
+
+    def key_order_rows(self, group_cols) -> int:
+        """Rows of the fullest cell of the key order over ``group_cols``,
+        building the order where it is not built yet."""
+        name = ",".join(group_cols)
+        self.groupby_operand("go::" + name)
+        return self._key_order_rows[name]
+
     def groupby_operand_cost(self, keys) -> int:
         """HBM bytes that building the not-yet-built operands among
         ``keys`` would add (lock-free: the byte budget's check)."""
@@ -708,6 +763,18 @@ class BatchContext:
             if key.startswith("gk::"):
                 dt = np.dtype(self.width_plan(key[4:]).dtype)
                 cost += n_pad * (dt.itemsize if dt.kind == "u" else 4)
+            elif key.startswith("go::"):
+                cost += n_pad * 4
+            elif key.startswith("gs::"):
+                cells = 1
+                for c in key[4:].split(","):
+                    cells *= self.cardinality(c)
+                cost += 4 * (cells + 1)
+            elif key.startswith("gp::"):
+                what = key[4:].split("::", 1)[1]
+                cost += n_pad * (
+                    1 if what == "seg" else 4 if what.startswith("gv::")
+                    else np.dtype(self.width_plan(what).dtype).itemsize)
             else:
                 cost += n_pad * int(key.rsplit("::", 1)[1])
         return cost

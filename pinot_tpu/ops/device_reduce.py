@@ -74,6 +74,17 @@ STAT_KEYS = frozenset((
 # are trimmed member by member (engine/device.py _cohort_pipeline)
 VMAP_SORT_MAX = 2048
 
+# the SELECTION (``select_top``): an ORDER BY of one exact-integer
+# aggregate over a table longer than SELECT_MIN_TABLE keeps its ``T`` rows
+# (at most SELECT_MAX_T: the survivors are ranked pair by pair) without the
+# sort at table length, which the TPU's compiler takes 32 s to build at
+# 7,000 entries and 22 minutes at 437,500 (PERF.md, PR 32)
+SELECT_MIN_TABLE = 1 << 13
+SELECT_MAX_T = 1 << 13
+SELECT_FIELDS = ("sum", "count")
+# an int64 sum orders as its float64 does (the host's comparison) below this
+EXACT_INT_ORDER = 1 << 53
+
 # aggregations whose finalized value the device can order by; the field
 # names the finalize produces (engine/aggspec.py → engine/reduce.py env)
 ORDER_AGG_FIELDS = {
@@ -120,8 +131,10 @@ def trim_keep_count(q, mode: str, group_trim_size: int = 5000) -> int:
 
 
 def plan_trim(q, group_exprs, aggs, shape: str, table_len: int,
-              mode, group_trim_size: int = 5000):
-    """Host-side static analysis → trim spec ``(T, order_sig)`` or None.
+              mode, group_trim_size: int = 5000, exact_int=frozenset()):
+    """Host-side static analysis → trim spec ``(T, order_sig)`` or None;
+    ``(T, order_sig, "select")`` where the selection takes the trim and not
+    the sort at table length (``trim_select`` says which of a spec).
 
     ``group_exprs`` / ``aggs`` are the template-build enumerations (the
     order_sig indexes into them); ``table_len`` is the full table the
@@ -132,10 +145,16 @@ def plan_trim(q, group_exprs, aggs, shape: str, table_len: int,
 
     The spec is hashable and literal-free: LIMIT/OFFSET ride as the
     ``tr_k`` param, only their pow2 ceiling ``T`` shapes the template.
+
+    ``exact_int``: indexes into ``aggs`` of the SUMs whose leaf is an
+    int64 the caller knows to stay under ``EXACT_INT_ORDER`` in size (an
+    integer argument of known range): the selection orders by the
+    integer, the host by its float64, and below that bound they agree.
     """
     if mode not in ("terminal", "partial"):
         return None
-    if shape not in ("groupby", "groupby_sorted", "groupby_narrow"):
+    if shape not in ("groupby", "groupby_sorted", "groupby_narrow",
+                     "groupby_full"):
         return None
     if q.distinct or q.having is not None:
         return None
@@ -174,7 +193,54 @@ def plan_trim(q, group_exprs, aggs, shape: str, table_len: int,
     T = next_pow2(k)
     if T >= table_len:
         return None  # nothing to shrink; the full table is the answer
+    if (len(order) == 1 and order[0][0] == "agg"
+            and order[0][2] in SELECT_FIELDS
+            and (order[0][2] == "count" or order[0][1] in exact_int)
+            and table_len > SELECT_MIN_TABLE and T <= SELECT_MAX_T):
+        return (T, tuple(order), "select")
     return (T, tuple(order))
+
+
+def trim_select(spec) -> str:
+    """Which selection a trim spec runs, as the spans say it
+    (``trimSelect``): ``select:<T>`` or ``sort:<T>``."""
+    return f"{'select' if len(spec) > 2 else 'sort'}:{spec[0]}"
+
+
+def select_top(rank, T: int):
+    """The table slots of the ``T`` largest ``rank`` (int64, one a slot;
+    more slots than ``T``), in order: rank descending, a tie to the lower
+    slot - what the sort by (rank descending, slot) has in its first
+    ``T`` places, exactly, without sorting the table. The ``T``-th
+    largest rank is found by bisection over the values (64 counting
+    passes over the table); slots above it are kept, and of the slots at
+    it the lowest as far as ``T`` reaches; the ``T`` kept are compacted
+    in slot order and ranked against each other pair by pair."""
+    G = rank.shape[0]
+
+    def halve(_, lo_hi):
+        lo, hi = lo_hi
+        # the upper middle, in a form that cannot pass int64's range
+        mid = lo + ((hi - lo) >> 1) + ((hi - lo) & 1)
+        enough = jnp.sum(rank >= mid, dtype=jnp.int32) >= T
+        return (jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1))
+
+    edge, _ = jax.lax.fori_loop(
+        0, 64, halve, (jnp.min(rank), jnp.max(rank)))
+    above = rank > edge
+    at_edge = rank == edge
+    room = T - jnp.sum(above, dtype=jnp.int32)
+    keep = above | (at_edge & (jnp.cumsum(at_edge, dtype=jnp.int32) <= room))
+    slots = jnp.searchsorted(
+        jnp.cumsum(keep, dtype=jnp.int32),
+        jnp.arange(1, T + 1, dtype=jnp.int32), side="left").astype(jnp.int32)
+    slots = jnp.minimum(slots, G - 1)
+    kept = rank[slots]
+    j = jnp.arange(T, dtype=jnp.int32)
+    ahead = (kept[None, :] > kept[:, None]) | (
+        (kept[None, :] == kept[:, None]) & (j[None, :] < j[:, None]))
+    place = jnp.sum(ahead, axis=1, dtype=jnp.int32)
+    return jnp.zeros(T, jnp.int32).at[place].set(slots)
 
 
 def _desc(v):
@@ -208,7 +274,7 @@ def apply_trim(outs: dict, params: dict, template, spec) -> dict:
       beyond ``trim_n``.
     """
     shape, _f, _gcols, group_cards, _aggs, _k, _final = template[:7]
-    T, order = spec
+    T, order = spec[:2]
     tr_k = params["tr_k"].astype(jnp.int64)
     gcount = outs["gcount"]
     G = gcount.shape[0]
@@ -224,6 +290,17 @@ def apply_trim(outs: dict, params: dict, template, spec) -> dict:
         for c in group_cards[j + 1:]:
             stride *= c
         return (keys64 // stride) % group_cards[j]
+
+    if len(spec) > 2:
+        # the selection: one exact-integer aggregate orders the table
+        # (plan_trim), empties below every present cell
+        _tag, i, field, asc = order[0]
+        v = gcount.astype(jnp.int64) if field == "count" \
+            else outs[f"a{i}_sum"].astype(jnp.int64)
+        with jax.named_scope("pinot.full_select"):
+            perm = select_top(jnp.where(
+                present, _desc(v) if asc else v, -(1 << 62)), T)
+        return _gather_trimmed(outs, perm, keys64, n_present, tr_k, T)
 
     # sort operands: empties last, then the ORDER BY keys, then the slot
     # index — the host's stable lexsort tie-break (present order) made
@@ -251,7 +328,13 @@ def apply_trim(outs: dict, params: dict, template, spec) -> dict:
             operands.append(v if asc else _desc(v))
     operands.append(jnp.arange(G, dtype=jnp.int64))
     sorted_ops = jax.lax.sort(tuple(operands), num_keys=len(operands))
-    perm = sorted_ops[-1][:T]
+    return _gather_trimmed(outs, sorted_ops[-1][:T], keys64, n_present,
+                           tr_k, T)
+
+
+def _gather_trimmed(outs, perm, keys64, n_present, tr_k, T: int) -> dict:
+    """The ``T`` table rows ``perm`` names, in its order, as the trimmed
+    outs ``apply_trim`` documents."""
     valid = jnp.arange(T, dtype=jnp.int64) < jnp.minimum(n_present, tr_k)
 
     trimmed = {}

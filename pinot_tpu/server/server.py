@@ -258,12 +258,15 @@ class ServerInstance:
                 self._register_gauge(
                     gname, (lambda _o=origin, _d=dev:
                             _d.groupby_operand_launches[_o]))
-            # the narrowed key space: its launches, and the queries whose
-            # live keys did not fit it (the host answered)
+            # the large key spaces: the narrowed regime's launches, those
+            # whose live keys did not fit it and the HOST answered (one
+            # launched again in the full regime is not among them), and
+            # the full regime's launches
             for gname, attr in (
                     ("deviceGroupbyNarrowed", "groupby_narrowed_launches"),
                     ("deviceGroupbyNarrowOverflow",
-                     "groupby_narrow_overflows")):
+                     "groupby_narrow_overflows"),
+                    ("deviceGroupbyFull", "groupby_full_launches")):
                 self._register_gauge(
                     gname, (lambda _a=attr, _d=dev: getattr(_d, _a)))
             self._register_gauge(

@@ -273,13 +273,18 @@ def test_live_keys_counted_out(engines, data, tier, where, live, fits):
         assert overflowed == 0 and resp.get("numSegmentsOnHost", 0) == 0
         assert _key_spaces(resp) == ["narrowed"]
     else:
-        # exact, by the host (whose answer carries no KERNEL record), and
-        # the launch's wait says why
-        # (a segment the statistics pruned is not scanned again)
-        assert overflowed == 1 and resp["numSegmentsOnHost"] in (1, 2)
-        assert spans["executor.dispatch"]["groupbyKeySpace"] == "narrowed"
-        assert spans["executor.device_wait"]["groupbyKeySpace"] == "overflow"
-        assert "engine.host_fallback" in spans
+        # exact, and still the device's (ISSUE 36): the launch that
+        # overflowed is launched again over the whole key space, and the
+        # host answers nothing. The trace holds both launches; the later
+        # one's spans are the full regime's
+        assert overflowed == 0 and resp.get("numSegmentsOnHost", 0) == 0
+        assert after["groupby_full_launches"] \
+            == before["groupby_full_launches"] + 1
+        assert spans["executor.dispatch"]["groupbyKeySpace"] == "full"
+        assert spans["executor.device_wait"]["groupbyKeySpace"] == "full"
+        assert spans["executor.device_wait"]["keySpaceLive"] == live
+        assert "engine.host_fallback" not in spans
+        assert _key_spaces(resp) == ["full"]
 
 
 # ---- a cohort: one template, two literals, each narrows by its own mask ---
@@ -323,14 +328,20 @@ def test_cohort_of_two_literals(engines, tier):
     assert all(resp.get("numSegmentsOnHost", 0) == 0 for _r, resp in got)
 
 
-def test_a_cohort_member_that_overflows_is_answered_by_the_host(engines):
+def test_a_cohort_member_that_overflows_is_launched_again_full(engines, data):
     """One member's live keys fit and the other's do not: the first keeps
-    the device's answer, the second gets the host's, both exact."""
+    the cohort's answer, the second is launched again over the whole key
+    space (ISSUE 36), both exact and neither the host's. An executor of
+    its own: the shared one has seen this template full already."""
     sqls = [U_SQL.format(where=w) for w in ("sel <= 1", "sel <= 2")]
     want = [_rows(engines["host"], s)[0] for s in sqls]
-    got = _cohort(engines["pallas"], sqls)
+    engine = _engine(data[0], mm_mode="interpret")
+    got = _cohort(engine, sqls)
     assert [g[0] for g in got] == want
-    assert sorted(g[1].get("numSegmentsOnHost", 0) for g in got) == [0, 2]
+    assert [g[1].get("numSegmentsOnHost", 0) for g in got] == [0, 0]
+    stats = engine.device.hbm_stats()
+    assert stats["groupby_full_launches"] == 1
+    assert stats["groupby_narrow_overflows"] == 0
 
 
 # ---- a template's programs are built with its first answer -----------------
@@ -623,24 +634,37 @@ def test_prepared_narrowed_is_per_launch_is_host(flat_engines, name):
         assert (rec["keySpaceLive"] > 0) == bool(want)
 
 
-def test_prepared_narrowed_overflow_is_the_hosts_and_counted(flat_engines):
+def test_a_key_space_counted_full_is_the_devices(flat_engines):
     """Q3.2's key space with no nation named: more than 128 live blocks.
-    The host answers, exactly, whichever form the launch took."""
+    The template's first launch counts them and takes the full regime
+    (ISSUE 36; the host answered until then): the device answers,
+    exactly, from the batch's rows in key order or, under a byte budget
+    no operand fits, by the XLA scatter."""
     engines, sqls, _tables = flat_engines
     sql = sqls["q3_2"].replace(
         "c_nation = 'UNITED STATES' AND s_nation = 'UNITED STATES' AND ", "")
     assert sql != sqls["q3_2"]
+    # over 100,000 groups are alive: under the default numGroupsLimit the
+    # trimmed table falls to the host, as it did before the regime
+    sql = "SET numGroupsLimit = 4194304; " + sql
     want, _ = _rows(engines["host"], sql)
     assert len(want) == 1000
     for form in ("perLaunch", "prepared"):
         executor = engines[form].device
-        before = executor.hbm_stats()["groupby_narrow_overflows"]
+        before = executor.hbm_stats()
         got, resp, spans = _traced(engines[form], sql)
         assert got == want, form
-        assert resp["numSegmentsOnHost"] == 8
-        assert executor.hbm_stats()["groupby_narrow_overflows"] == before + 1
-        assert spans["executor.dispatch"]["groupbyKeySpace"] == "narrowed"
-        assert spans["executor.device_wait"]["groupbyKeySpace"] == "overflow"
+        assert resp.get("numSegmentsOnHost", 0) == 0
+        after = executor.hbm_stats()
+        assert after["groupby_narrow_overflows"] == 0
+        assert after["groupby_narrowed_launches"] \
+            == before["groupby_narrowed_launches"]
+        assert after["groupby_key_space_probes"] \
+            == before["groupby_key_space_probes"] + 1
+        assert after["groupby_full_launches"] \
+            == before["groupby_full_launches"] + 1
+        assert spans["executor.dispatch"]["groupbyKeySpace"] == "full"
+        assert spans["executor.device_wait"]["groupbyKeySpace"] == "full"
         assert spans["executor.dispatch"]["groupbyOperands"] in (
             ("perLaunch",) if form == "perLaunch" else ("prepared", "built"))
 

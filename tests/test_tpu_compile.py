@@ -529,3 +529,137 @@ def test_expression_planes_builder_real_batch(spec):
     assert not _big_results(text, "reshape", rows=N_FLAT)
     copies = _big_results(text, "copy", rows=N_FLAT)
     assert len(copies) == 1 and "3,97664,128]" in copies[0][1], copies
+
+
+# ---- the full key space (ISSUE 36): the ranking mix's two largest ----------
+
+# supp_shipmode_disc (1.4M cells, a filter, SUM and COUNT) and
+# year_city_brand_profit (1.75M cells, SUM of a - b) of
+# benchmark/traffic/ssb_fullkeys_6q_c4.json as the executor plans them on
+# ssb_sf100_fullkeys's batch (captured from a served run at the tiny size;
+# the cardinalities are SF100's), trimmed as a server's partial is: 8,192
+# rows kept by the selection.
+FULL = {
+    "supp_shipmode_disc": dict(
+        template=(
+            "groupby_full", ("range_dict", "lo_discount", "pr0", "pr1"),
+            ("lo_suppkey", "lo_shipmode"), (200_000, 7),
+            (("sum", ("raw", "lo_revenue"), (3, None)),
+             ("count", None, None)), 0, False),
+        widths={"lo_discount": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "lo_shipmode": ("|u1", 0, False, ""),
+                "lo_suppkey": ("<i4", 0, False, "")},
+        fcols=("lo_discount",), value="gv::lo_revenue::81000::3",
+        params={"pr0": ((), "int32"), "pr1": ((), "int32")}),
+    "year_city_brand_profit": dict(
+        template=(
+            "groupby_full", ("true",),
+            ("d_year", "s_city", "p_brand1"), (7, 250, 1000),
+            (("sum", ("minus", ("raw", "lo_revenue"),
+                      ("raw", "lo_supplycost")), (3, None)),), 0, False),
+        widths={"d_year": ("|u1", 0, False, ""),
+                "lo_revenue": ("<i4", 0, False, ""),
+                "lo_supplycost": ("<u2", 0, True, "<i4"),
+                "p_brand1": ("<u2", 0, False, ""),
+                "s_city": ("|u1", 0, False, "")},
+        fcols=(), value="gv::minus(lo_revenue,lo_supplycost)::-44941::3",
+        params={"fo::lo_supplycost": ((), "int32")}),
+}
+FULL_TRIM = (8192, (("agg", 0, "sum", False),), "select")
+# either program compiles here in 6 to 8 s alone: a cumulative sum a
+# channel, the boundary reads, the selection's 64 counting passes and its
+# pairwise ranking of 8,192 survivors. The sort at table length that the
+# selection replaces took this compiler 22 minutes at 437,500 entries; the
+# limit leaves no room for its return.
+FULL_COMPILE_LIMIT_S = 90
+
+
+@pytest.mark.parametrize("plane_bits", [24, 8])
+@pytest.mark.parametrize("name", list(FULL))
+def test_pipeline_full_groupby_real_batch(spec, name, plane_bits):
+    """The executor's own program for a full key space: the filter over the
+    projected planes, the channels' cumulative sums read at the cells'
+    boundaries, the table over the key space, the selection, the pack. No
+    kernel call, no sort, no scatter at the rows' length, and a compile
+    inside the limit. ``plane_bits`` 24: the mix's own (no cell holds 256
+    rows); 8: a batch whose fullest cell holds 65,536 or more."""
+    import time
+
+    case = FULL[name]
+    template, widths = case["template"], case["widths"]
+    cells = 1
+    for c in template[3]:
+        cells *= c
+    keys = ",".join(template[2])
+    fcols = tuple((c, f"gp::{keys}::{c}") for c in case["fcols"])
+    planes = ((0, f"gp::{keys}::" + case["value"], 3),)
+    prepared = ("keysorted", (), (), (
+        keys, "gs::" + keys, f"gp::{keys}::seg", fcols, planes, plane_bits))
+    cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
+    cols["gs::" + keys] = spec((cells + 1,), "int32")
+    cols[f"gp::{keys}::seg"] = spec((N_FLAT_PAD // 128, 128), "uint8")
+    for c, key in fcols:
+        cols[key] = spec((N_FLAT_PAD // 128, 128), widths[c][0])
+    cols[planes[0][1]] = spec((N_FLAT_PAD // 128, 128), "uint32")
+    executor = dev.DeviceExecutor(mm_mode="tpu", pallas_mode="tpu")
+    entry = executor._pipeline_entry(
+        template, template[4], False, False, widths,
+        tuple(sorted(widths.items())), FULL_TRIM, "tpu", prepared)
+    n_seg = FLAT_BATCH[0]
+    params = {"off0": spec((), "int64"), "ps_alive": spec((n_seg,), "bool"),
+              "tr_k": spec((), "int32"),
+              **{k: spec(*v) for k, v in case["params"].items()}}
+    t0 = time.perf_counter()
+    compiled = entry["pipeline"].lower(
+        cols, spec((n_seg,), "int32"), params).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not _big_results(text, "sort", rows=cells)
+    assert not _big_results(text, "scatter", rows=N_FLAT)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 << 30, mem
+    assert seconds < FULL_COMPILE_LIMIT_S, seconds
+
+
+def test_key_order_projections_real_batch(spec):
+    """The once-a-batch projections of the full regime's operands into the
+    key order: one relayout (ops/groupby_mm.py _to_lanes's split of L:
+    0.2 s here, never the one-step flatten's minutes: PR 30) and one
+    gather each. The order itself is numpy's, on the host: the device's
+    sort of 37.5M rows took this compiler 25 s to build, which beside the
+    regime's own program did not fit a statement's 60 s (PERF.md, PR 36)."""
+    import time
+
+    from pinot_tpu.ops import keysorted as ks
+
+    lanes = (N_FLAT_PAD // 128, 128)
+    t0 = time.perf_counter()
+    _compile(ks.project_plane, spec(FLAT_BATCH, "uint8"),
+             spec((N_FLAT_PAD,), "int32"))
+    _compile(ks.project_value, spec((3,) + lanes, "uint8"),
+             spec((N_FLAT_PAD,), "int32"))
+    _compile(lambda a, b: ks._cartesian((a, b), cards=(200_000, 7)),
+             spec(lanes, "int32"), spec(lanes, "uint8"))
+    assert time.perf_counter() - t0 < 60
+
+
+def test_live_block_count_real_batch(spec):
+    """A template's one count of its live 128-cell blocks (pass 1 alone,
+    by the XLA scatter): supp_shipmode_disc's, 10,938 blocks. Seconds to
+    build, where the narrowed pipeline it spares a full template is tens."""
+    import time
+
+    case = FULL["supp_shipmode_disc"]
+    template, widths = case["template"], case["widths"]
+    keys = ("lo_discount",) + template[2]
+    t0 = time.perf_counter()
+    _compile(lambda c, nd, p: dev._live_blocks(
+        c, nd, p, filter_tpl=template[1], group_cols=template[2],
+        group_cards=template[3],
+        wsig=tuple(sorted((k, widths[k]) for k in keys))),
+        {k: spec(FLAT_BATCH, widths[k][0]) for k in keys},
+        spec((FLAT_BATCH[0],), "int32"),
+        {k: spec(*v) for k, v in case["params"].items()})
+    assert time.perf_counter() - t0 < 30
