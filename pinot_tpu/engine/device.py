@@ -108,8 +108,15 @@ _COUNT_ONLY = (("count", None, None),)
 # live blocks than the narrowed table holds. Its rows are summed in KEY
 # ORDER (ops/keysorted.py): the batch keeps, a set of key columns, the
 # rows' order by cartesian key, each cell's first row in it, and the
-# statement's filter and value columns projected into it; a launch masks,
-# runs one cumulative sum a channel and reads it at the cells' boundaries.
+# statement's filter and value columns projected into it. The projected
+# planes are laid out cell by slot (SLOTTED: every cell the fullest cell's
+# K slots, a plane (K, cells) with the cells along the lanes) and a launch
+# masks and sums down the slot axis - no cumulative sum, no gather - where
+# the key order found K x cells within FULL_SLOT_PADDING times the batch's
+# rows and the planes pass the batch's byte budget; a skewed key (one cell
+# many times the mean) keeps them one row a row (ORDERED) and a launch runs
+# one cumulative sum a channel and reads it at the cells' boundaries. What
+# a launch took is its ``groupbyKeyLayout``; no option chooses it.
 # The table is the key space itself (the dense regime's output form), and
 # the trim's selection (ops/device_reduce.py select_top) takes its top
 # rows without a sort at table length. Which of the two regimes a
@@ -124,6 +131,10 @@ _COUNT_ONLY = (("count", None, None),)
 # the launch's ``ps_alive``: compared against, segment by segment.
 FULL_MAX_SEGMENTS = 16
 FULL_MAX_PLANES = 4      # a value's projected plane is one uint32 a row
+# slotted planes may hold this many slots a row of the batch: dbgen's
+# uniform keys at 37.5M rows need x1.2 (62,500 cells) to x2.2 (1.75M) and
+# x3.2 at a 3M-cell key (PERF.md, PR 37); past it the key is skewed
+FULL_SLOT_PADDING = 4
 # what executor.dispatch / device_wait, the flight record and EXPLAIN
 # ANALYZE call a group-by's key space (``groupbyKeySpace``)
 KEY_SPACES = {"groupby": "dense", "groupby_narrow": "narrowed",
@@ -1673,19 +1684,25 @@ def build_pipeline(template, mm_mode: str = "auto",
         """COUNT/SUM/AVG over a FULL key space, from the batch's rows in
         key order (KEY_SPACES and ops/keysorted.py have the why). The
         launch's own work: the filter over the projected columns, each
-        row's segment against ``ps_alive`` and its position against the
-        number of real rows; a count channel and each value split into
+        row's segment against ``ps_alive`` and which positions hold a row
+        (slotted: a cell's first ``rows[cell]`` slots; ordered: up to the
+        number of real rows); a count channel and each value split into
         planes narrow enough that the fullest cell's sum stays under
-        2^32; one cumulative sum a channel, read at the cells'
-        boundaries. The table is the key space, as the dense form's."""
-        _name, starts_key, seg_key, fcols, planes, plane_bits = prep[3]
+        2^32; slotted, the sum down the slot axis, ordered, one
+        cumulative sum a channel read at the cells' boundaries. The table
+        is the key space, as the dense form's."""
+        _name, starts_key, seg_key, fcols, planes, plane_bits, slotted = \
+            prep[3]  # slotted: a cell's slots K, 0 in the ordered layout
         starts = cols[starts_key]
         seg = cols[seg_key]
         with jax.named_scope("pinot.mask"):
-            at = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) \
-                * seg.shape[1] \
-                + jax.lax.broadcasted_iota(jnp.int32, seg.shape, 1)
-            keep = at < starts[num_groups]
+            if slotted:
+                keep = ks_ops.slot_mask(starts, seg.shape)
+            else:
+                at = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) \
+                    * seg.shape[1] \
+                    + jax.lax.broadcasted_iota(jnp.int32, seg.shape, 1)
+                keep = at < starts[num_groups]
             alive = params.get("ps_alive")
             if alive is not None:
                 of_alive = jnp.zeros(seg.shape, dtype=bool)
@@ -1709,7 +1726,8 @@ def build_pipeline(template, mm_mode: str = "auto",
                         (v >> (plane_bits * k)) & ((1 << plane_bits) - 1)
                         for k in range(n_split))
                 split.append((i, first, n_split))
-            sums = ks_ops.segment_sums(starts, channels)
+            sums = ks_ops.slot_sums(channels, num_groups) if slotted \
+                else ks_ops.segment_sums(starts, channels)
         with jax.named_scope("pinot.recombine"):
             gcount = sums[0].astype(jnp.int64)
             outs["gcount"] = gcount
@@ -1830,6 +1848,10 @@ class DeviceExecutor:
         self.groupby_narrow_overflows = 0
         self.groupby_full_launches = 0
         self.groupby_full_table_bytes = 0  # the largest key-space table
+        # of them, those that summed planes laid out cell by slot, and the
+        # most such planes a launch read
+        self.groupby_slotted_launches = 0
+        self.groupby_slotted_bytes = 0
         # (filter template, key columns, aggregates, batch) -> is its
         # large key space full? What the first launch's count of live
         # blocks said, or a narrowed launch's overflow since. Insertion-
@@ -2060,6 +2082,8 @@ class DeviceExecutor:
                 "groupby_narrow_overflows": self.groupby_narrow_overflows,
                 "groupby_full_launches": self.groupby_full_launches,
                 "groupby_full_table_bytes": self.groupby_full_table_bytes,
+                "groupby_slotted_launches": self.groupby_slotted_launches,
+                "groupby_slotted_bytes": self.groupby_slotted_bytes,
                 "groupby_key_space_probes": self.groupby_key_space_probes,
             }
         per_batch = [
@@ -3045,27 +3069,44 @@ class DeviceExecutor:
                             gb_exprs[key] = functools.partial(
                                 self._build_expr_planes, ctx, argt, widths,
                                 params, offsets[i], nplanes)
+        key_layout = {}
         if full_plan is not None:
             # the full regime's operands: the key order first (the fullest
-            # cell's rows say how wide a value plane may be), then what
-            # the statement reads, projected into it
+            # cell's rows say how wide a value plane may be, and whether
+            # the planes are laid out cell by slot), then what the
+            # statement reads, projected into it
             name, fcols, vplanes = full_plan
-            gb_keys = ("gs::" + name, f"gp::{name}::seg") \
-                + tuple(k for _c, k in fcols) \
-                + tuple(k for _i, k, _n in vplanes)
-            # with what they are built from: the order itself, the key
+            # what the order and the projections are built from: the key
             # columns' ids, the values' byte planes
-            cost = ctx.groupby_operand_cost(
-                gb_keys + ("go::" + name,)
-                + tuple("gk::" + c for c in group_cols)
-                + tuple(k.split("::", 2)[2] for _i, k, _n in vplanes))
-            plane_bits = 0
-            if not cost \
-                    or ctx.device_bytes() + cost <= self.MAX_CACHED_BYTES:
-                plane_bits = ks_ops.plane_bits_for(
-                    ctx.key_order_rows(group_cols))
+            sources = ("go::" + name, "gs::" + name) \
+                + tuple("gk::" + c for c in group_cols) \
+                + tuple(k.split("::", 2)[2] for _i, k, _n in vplanes)
+            ordered = (f"gp::{name}::seg",) + tuple(k for _c, k in fcols) \
+                + tuple(k for _i, k, _n in vplanes)
+
+            def fits(keys):
+                # what is built already costs nothing more
+                cost = ctx.groupby_operand_cost(keys)
+                return not cost \
+                    or ctx.device_bytes() + cost <= self.MAX_CACHED_BYTES
+
+            plane_bits = slot_rows = 0
+            if fits(sources + ordered):
+                fullest = ctx.key_order_rows(group_cols)
+                plane_bits = ks_ops.plane_bits_for(fullest)
+                slot_rows = ks_ops.slot_rows(fullest)
+                if slot_rows * ks_ops.slot_lanes(total) \
+                        > FULL_SLOT_PADDING * ctx.lane_rows() or not fits(
+                            sources + tuple(map(ctx.slotted_key, ordered))):
+                    slot_rows = 0  # a skewed key, or the budget: ordered
             if plane_bits:
-                needed.update(gb_keys)
+                as_built = ctx.slotted_key if slot_rows else str
+                seg_key = as_built(f"gp::{name}::seg")
+                fcols = tuple((c, as_built(k)) for c, k in fcols)
+                vplanes = tuple((i, as_built(k), n) for i, k, n in vplanes)
+                needed.update(
+                    ("gs::" + name, seg_key) + tuple(k for _c, k in fcols)
+                    + tuple(k for _i, k, _n in vplanes))
                 for i, key, nplanes in vplanes:
                     argt = agg_tpls[i][1]
                     if argt[0] not in ("raw", "dictval"):
@@ -3073,8 +3114,11 @@ class DeviceExecutor:
                             self._build_expr_planes, ctx, argt, widths,
                             params, offsets[i], nplanes)
                 prepared = ("keysorted", (), (), (
-                    name, "gs::" + name, f"gp::{name}::seg", fcols, vplanes,
-                    plane_bits))
+                    name, "gs::" + name, seg_key, fcols, vplanes,
+                    plane_bits, slot_rows))
+                key_layout = {"groupbyKeyLayout": "slotted", "slotRows":
+                              slot_rows} if slot_rows else \
+                    {"groupbyKeyLayout": "ordered"}
         # what the launch's spans, its flight record and EXPLAIN ANALYZE
         # say of it: prepared | built (this launch built them) | perLaunch
         gb_operands = None if shape not in (
@@ -3086,6 +3130,8 @@ class DeviceExecutor:
                      "keySpaceCells": total} if shape in KEY_SPACES else {}
         if shape == "groupby_full" and trim is not None:
             key_space["trimSelect"] = dr_ops.trim_select(trim)
+        # a full launch's layout of the projected planes: slotted | ordered
+        key_space.update(key_layout)
 
         pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode,
                                   prepared)
@@ -3190,10 +3236,17 @@ class DeviceExecutor:
             # and each SUM/AVG's total, 8 bytes a cell each
             table_bytes = 8 * total * (1 + sum(
                 t[0] in ("sum", "avg") for t in agg_tpls))
+            # and the slotted planes it streams (the largest launch's)
+            slotted_bytes = sum(
+                int(v.nbytes) for k, v in cols.items() if "::slot::" in k)
             with self._lock:
                 self.groupby_full_launches += 1
                 self.groupby_full_table_bytes = max(
                     self.groupby_full_table_bytes, table_bytes)
+                if slotted_bytes:
+                    self.groupby_slotted_launches += 1
+                    self.groupby_slotted_bytes = max(
+                        self.groupby_slotted_bytes, slotted_bytes)
         flight["origin"] = origin
         if os.environ.get("PINOT_TPU_WIDTH_AUDIT", "") not in ("", "0"):
             _width_audit(ctx, cols, widths)

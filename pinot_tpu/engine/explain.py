@@ -173,7 +173,8 @@ def _kernel_line(rec: dict) -> str:
     # a dense group-by says where its kernel's operands came from
     operands = "".join(
         f", {k}={rec[k]}" for k in ("groupbyOperands", "groupbyKeySpace",
-                                    "keySpaceCells", "keySpaceLive")
+                                    "keySpaceCells", "keySpaceLive",
+                                    "groupbyKeyLayout", "slotRows")
         if rec.get(k))
     return (f"    KERNEL({label}{where}: {perf}, "
             f"bytes={rec.get('bytesMoved')}, "

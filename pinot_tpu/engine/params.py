@@ -714,7 +714,9 @@ class BatchContext:
         ids); ``gp::<cols>::<what>`` a plane projected into that order -
         ``seg`` each row's segment, a ``gv::`` key that value less its
         offset as one uint32 a row (from the byte planes, ``build`` making
-        an expression's), any other cols key the stored plane itself."""
+        an expression's), any other cols key the stored plane itself;
+        ``gp::<cols>::slot::<what>`` (``slotted_key``) the same plane laid
+        out cell by slot, the ordered one a temporary of its build."""
         from pinot_tpu.ops import keysorted as ks
 
         kind, rest = key[:2], key[4:]
@@ -729,19 +731,38 @@ class BatchContext:
             self._gb_operand_bytes += int(self._gb_operands[other].nbytes)
             self._note_resident(self._gb_operands[other])
             return perm if kind == "go" else starts
-        names, what = rest.split("::", 1)
+        names, what, slotted = self._projected(key)
         perm = self.groupby_operand("go::" + names)[0]
         if what.startswith("gv::"):
-            return ks.project_value(self.groupby_operand(what, build)[0],
-                                    perm)
-        if what == "seg":
-            stored = jnp.broadcast_to(
-                jnp.arange(self.S, dtype=jnp.uint8)[:, None],
-                (self.S, self.pad_to))
+            plane = ks.project_value(self.groupby_operand(what, build)[0],
+                                     perm)
         else:
-            stored = self._decoded_column_locked(what[4:]) \
-                if what.startswith("dv::") else self._column_locked(what)
-        return ks.project_plane(stored, perm)
+            if what == "seg":
+                stored = jnp.broadcast_to(
+                    jnp.arange(self.S, dtype=jnp.uint8)[:, None],
+                    (self.S, self.pad_to))
+            else:
+                stored = self._decoded_column_locked(what[4:]) \
+                    if what.startswith("dv::") else self._column_locked(what)
+            plane = ks.project_plane(stored, perm)
+        if slotted:
+            plane = ks.slot_plane(
+                plane, self.groupby_operand("gs::" + names)[0],
+                k=ks.slot_rows(self._key_order_rows[names]))
+        return plane
+
+    @staticmethod
+    def slotted_key(key: str) -> str:
+        """A ``gp::<cols>::<what>`` key's twin in the slotted layout."""
+        names, what = key[4:].split("::", 1)
+        return f"gp::{names}::slot::{what}"
+
+    @staticmethod
+    def _projected(key: str):
+        """A ``gp::`` key's (key columns, what it projects, slotted?)."""
+        names, what = key[4:].split("::", 1)
+        slotted = what.startswith("slot::")
+        return names, what[6:] if slotted else what, slotted
 
     def key_order_rows(self, group_cols) -> int:
         """Rows of the fullest cell of the key order over ``group_cols``,
@@ -750,12 +771,21 @@ class BatchContext:
         self.groupby_operand("go::" + name)
         return self._key_order_rows[name]
 
-    def groupby_operand_cost(self, keys) -> int:
-        """HBM bytes that building the not-yet-built operands among
-        ``keys`` would add (lock-free: the byte budget's check)."""
+    def lane_rows(self) -> int:
+        """The batch's rows as the lane-major operands hold them: padded
+        to whole superblocks (ops/groupby_mm.py _to_lanes)."""
         from pinot_tpu.ops.groupby_mm import SUPERBLOCK
 
-        n_pad = -(-self.S * self.pad_to // SUPERBLOCK) * SUPERBLOCK
+        return -(-self.S * self.pad_to // SUPERBLOCK) * SUPERBLOCK
+
+    def groupby_operand_cost(self, keys) -> int:
+        """HBM bytes that building the not-yet-built operands among
+        ``keys`` would add (lock-free: the byte budget's check). A slotted
+        plane is reckoned at its own K x cells slots, which the built key
+        order says: ask after ``key_order_rows``."""
+        from pinot_tpu.ops import keysorted as ks
+
+        n_pad = self.lane_rows()
         cost = 0
         for key in keys:
             if key in self._gb_operands:
@@ -766,18 +796,25 @@ class BatchContext:
             elif key.startswith("go::"):
                 cost += n_pad * 4
             elif key.startswith("gs::"):
-                cells = 1
-                for c in key[4:].split(","):
-                    cells *= self.cardinality(c)
-                cost += 4 * (cells + 1)
+                cost += 4 * (self._key_cells(key[4:]) + 1)
             elif key.startswith("gp::"):
-                what = key[4:].split("::", 1)[1]
-                cost += n_pad * (
+                names, what, slotted = self._projected(key)
+                slots = n_pad if not slotted else ks.slot_rows(
+                    self._key_order_rows[names]) * ks.slot_lanes(
+                        self._key_cells(names))
+                cost += slots * (
                     1 if what == "seg" else 4 if what.startswith("gv::")
                     else np.dtype(self.width_plan(what).dtype).itemsize)
             else:
                 cost += n_pad * int(key.rsplit("::", 1)[1])
         return cost
+
+    def _key_cells(self, names: str) -> int:
+        """Cells of the cartesian key space over ``names`` (comma-joined)."""
+        cells = 1
+        for c in names.split(","):
+            cells *= self.cardinality(c)
+        return cells
 
     def groupby_operand_bytes(self) -> int:
         """Resident bytes of the operands (part of ``device_bytes``)."""

@@ -260,13 +260,15 @@ class ServerInstance:
                             _d.groupby_operand_launches[_o]))
             # the large key spaces: the narrowed regime's launches, those
             # whose live keys did not fit it and the HOST answered (one
-            # launched again in the full regime is not among them), and
-            # the full regime's launches
+            # launched again in the full regime is not among them), the
+            # full regime's launches, and those of them that summed planes
+            # laid out cell by slot
             for gname, attr in (
                     ("deviceGroupbyNarrowed", "groupby_narrowed_launches"),
                     ("deviceGroupbyNarrowOverflow",
                      "groupby_narrow_overflows"),
-                    ("deviceGroupbyFull", "groupby_full_launches")):
+                    ("deviceGroupbyFull", "groupby_full_launches"),
+                    ("deviceGroupbySlotted", "groupby_slotted_launches")):
                 self._register_gauge(
                     gname, (lambda _a=attr, _d=dev: getattr(_d, _a)))
             self._register_gauge(

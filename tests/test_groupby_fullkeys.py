@@ -34,6 +34,14 @@ NAMES = ["citypair_1997", "city_brand", "city_day", "supp_year",
 CELLS = {"citypair_1997": 62_500, "city_brand": 250_000,
          "city_day": 601_500, "supp_year": 35_000,
          "supp_shipmode_disc": 35_000, "year_city_brand_profit": 1_750_000}
+# the fullest cell's rows, to the sublane tile, where the projected planes
+# are laid out cell by slot. The tiny table's 600,000 rows (655,360 as the
+# lanes hold them) fill 62,500 and 35,000 cells 38, 39 and 36 deep: 40
+# slots a cell are x3.8 and x2.1 of the rows, within FULL_SLOT_PADDING.
+# Its larger key spaces are mostly EMPTY here (at 37.5M rows they pad
+# x1.4 to x2.2: PERF.md, PR 37): 24 x 250,000 slots are x9.2, and those
+# three statements keep the ordered planes and the cumulative sums.
+SLOT_ROWS = {"citypair_1997": 40, "supp_year": 40, "supp_shipmode_disc": 40}
 
 
 def _bench(*parts):
@@ -128,6 +136,11 @@ def test_statement_served_is_the_reference(served, name):
     after = cluster.executor.hbm_stats()
     assert after["groupby_full_launches"] \
         == before["groupby_full_launches"] + 2
+    assert after["groupby_slotted_launches"] \
+        == before["groupby_slotted_launches"] + 2 * (name in SLOT_ROWS)
+    if name in SLOT_ROWS:  # a value, a segment and maybe a filter plane
+        assert after["groupby_slotted_bytes"] \
+            >= 5 * SLOT_ROWS[name] * CELLS[name]
     assert after["groupby_narrowed_launches"] \
         == before["groupby_narrowed_launches"]
     assert after["groupby_key_space_probes"] \
@@ -163,11 +176,16 @@ def test_full_is_the_host_and_says_so(engines, built, name):
             assert attrs["trimSelect"] == \
                 f"select:{1 << (limit - 1).bit_length()}"
             assert attrs["groupbyOperands"] in ("prepared", "built")
+            assert (attrs["groupbyKeyLayout"], attrs.get("slotRows")) == (
+                ("slotted", SLOT_ROWS[name]) if name in SLOT_ROWS
+                else ("ordered", None)), (name, attrs)
         assert 4096 < wait["keySpaceLive"] <= CELLS[name]
         rec = resp["roofline"][0]
         assert rec["kernel"] == "groupby_full+trim"
         assert (rec["groupbyKeySpace"], rec["keySpaceCells"]) \
             == ("full", CELLS[name])
+        assert rec["groupbyKeyLayout"] == dispatch["groupbyKeyLayout"]
+        assert rec.get("slotRows") == SLOT_ROWS.get(name)
         assert rec["keySpaceLive"] == wait["keySpaceLive"]
         assert rec["trimSelect"].startswith("select:")
 
@@ -356,6 +374,275 @@ def test_an_order_the_selection_declines_keeps_the_sort(tie_engines):
         assert got == _rows(tie_engines["host"], sql)[0], tail
         assert spans["executor.device_wait"]["groupbyKeySpace"] == "full"
         assert spans["executor.device_wait"]["trimSelect"] == "sort:64"
+
+
+# ---- the two layouts of the projected planes -------------------------------
+
+LAYOUT_CELLS = (6, 50)           # 300 cells: the slotted lane axis pads to 384
+LAYOUT_CASES = {
+    # rows of each cell
+    "uniform": lambda rng: rng.integers(20, 41, 300),
+    "skewed": lambda rng: np.where(np.arange(300) == 123, 3_000,
+                                   rng.integers(0, 6, 300)),
+    "empty_cells": lambda rng: np.where(np.arange(300) % 3 == 0, 0,
+                                        rng.integers(1, 30, 300)),
+    # the fullest cell fills every one of its K slots; so does cell 0, and
+    # the last cell, whose slots the batch's padding rows follow
+    "a_cell_of_exactly_k_rows": lambda rng: np.where(
+        np.isin(np.arange(300), (0, 77, 299)), 40, rng.integers(0, 40, 300)),
+}
+
+
+@pytest.mark.parametrize("bits", [8, 16, 24])
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_slotted_sums_are_the_ordered_sums_are_numpy(case, bits):
+    """ops/keysorted.py, both layouts from one key order: a count and a
+    24-bit value in planes of ``bits``, under a band filter, a dead
+    segment (``ps_alive``) and the batch's padding rows, cell by cell
+    against numpy."""
+    import jax.numpy as jnp
+
+    from pinot_tpu.ops import keysorted as ks
+
+    rng = np.random.default_rng(len(case) * 100 + bits)
+    per_cell = LAYOUT_CASES[case](rng)
+    cells = len(per_cell)
+    gid = rng.permutation(np.repeat(np.arange(cells), per_cell))
+    n = len(gid)
+    n_pad = -(-(n + 77) // 128) * 128      # 77 or more padding rows
+    ids = []
+    for div, card in ((LAYOUT_CELLS[1], LAYOUT_CELLS[0]),
+                      (1, LAYOUT_CELLS[1])):
+        plane = np.full(n_pad, card, np.int32)  # padding: the cardinality
+        plane[:n] = gid // div % card
+        ids.append(jnp.asarray(plane.reshape(-1, 128)))
+    value = rng.integers(0, 1 << 24, n_pad).astype(np.uint32)
+    filt = rng.integers(0, 11, n_pad).astype(np.uint8)
+    seg = rng.integers(0, 3, n_pad).astype(np.uint8)
+    alive = np.array([True, False, True])
+
+    perm, starts, fullest = ks.key_order(tuple(ids), cards=LAYOUT_CELLS)
+    assert fullest == per_cell.max()
+    assert (np.diff(np.asarray(starts)) == per_cell).all()
+    k = ks.slot_rows(fullest)
+    assert k % 8 == 0 and 0 <= k - fullest < 8
+    if case == "a_cell_of_exactly_k_rows":
+        assert k == fullest
+    shape = (k, ks.slot_lanes(cells))
+    assert shape[1] == 384
+    n_split = -(-24 // bits)
+
+    def channels(v, f, sg, keep):
+        keep = keep & (f >= 1) & (f <= 3) & jnp.asarray(alive)[sg]
+        v = jnp.where(keep, v, jnp.uint32(0))
+        return [keep.astype(jnp.uint32)] + [
+            (v >> (bits * j)) & ((1 << bits) - 1) for j in range(n_split)]
+
+    ordered = [jnp.asarray(x).reshape(-1)[perm].reshape(-1, 128)
+               for x in (value, filt, seg)]
+    at = jnp.arange(n_pad, dtype=jnp.int32).reshape(-1, 128)
+    by_order = np.asarray(ks.segment_sums(
+        starts, channels(*ordered, at < starts[cells])))
+    slotted = [ks.slot_plane(x, starts, k=k) for x in ordered]
+    assert all(x.shape == shape and x.dtype == o.dtype
+               for x, o in zip(slotted, ordered))
+    by_slot = np.asarray(ks.slot_sums(
+        channels(*slotted, ks.slot_mask(starts, shape)), cells))
+
+    keep = (filt[:n] >= 1) & (filt[:n] <= 3) & alive[seg[:n]]
+    want = [np.bincount(gid[keep], minlength=cells)] + [
+        np.bincount(gid[keep], weights=(
+            (value[:n][keep] >> (bits * j)) & ((1 << bits) - 1)).astype(
+                np.float64), minlength=cells).astype(np.int64)
+        for j in range(n_split)]
+    want = np.stack(want)
+    assert by_slot.shape == by_order.shape == want.shape
+    assert (by_slot == by_order).all()
+    # a channel is summed modulo 2^32: a plane as wide as the fullest
+    # cell allows (plane_bits_for) never wraps, a wider one may
+    assert (by_slot == want % (1 << 32)).all()
+    if bits <= ks.plane_bits_for(fullest):
+        assert (by_slot == want).all()
+        total = sum(by_slot[1 + j].astype(np.int64) << (bits * j)
+                    for j in range(n_split))
+        assert (total == np.bincount(
+            gid[keep], weights=value[:n][keep].astype(np.float64),
+            minlength=cells).astype(np.int64)).all()
+    assert want[0].sum() > 0
+    assert (want[0] == 0).any() or case == "uniform"
+
+
+SLOT_A, SLOT_B = 225, 200        # 45,000 cells, c % 9 rows in cell c
+SLOT_SQL = ("SET numGroupsLimit=4194304; SELECT a, b, SUM(v), COUNT(*) "
+            "FROM {} WHERE f BETWEEN 1 AND 3 GROUP BY a, b "
+            "ORDER BY SUM(v) DESC LIMIT 50")
+
+
+def _slot_columns(fat=0):
+    """Cell c of a 225 x 200 key space holds c % 9 rows - a ninth of the
+    cells are empty, a ninth hold exactly the 8 rows that are K - and,
+    with ``fat``, one cell that many more; shuffled over two segments."""
+    rng = np.random.default_rng(37 + fat)
+    cell = np.repeat(np.arange(SLOT_A * SLOT_B), np.arange(SLOT_A * SLOT_B) % 9)
+    cell = rng.permutation(np.concatenate([cell, np.full(fat, 4_321)]))
+    n = len(cell)
+    return {"a": (cell // SLOT_B).astype(np.int32),
+            "b": (cell % SLOT_B).astype(np.int32),
+            "f": rng.integers(0, 11, n).astype(np.int32),
+            "v": rng.integers(0, 1 << 20, n).astype(np.int32)}
+
+
+@pytest.fixture(scope="module")
+def slot_engines(tmp_path_factory):
+    base = tmp_path_factory.mktemp("fullkeys_slots")
+    dims = [(c, DataType.INT) for c in ("a", "b", "f")]
+    cols = {"u": _slot_columns(), "sk": _slot_columns(fat=6_000)}
+    tables = {name: _build(base, name, c, dims, ["v"])
+              for name, c in cols.items()}
+    return {"device": _engine(tables, mm_mode="interpret"),
+            "host": _engine(tables)}, tables, cols
+
+
+def _ctx_of(engine, table_segments):
+    """The device batch that holds ``table_segments``."""
+    names = {s.name for s in table_segments}
+    return next(ctx for ctx in engine.device._batches.values()
+                if {s.name for s in ctx.segments} == names)
+
+
+@pytest.mark.parametrize("table,layout,slot_rows", [
+    ("u", "slotted", 8),
+    # one cell of 6,000 rows among cells of 0-8: 6,000 slots a cell would
+    # be 1,400 times the batch's rows, and the planes stay in key order
+    ("sk", "ordered", None)])
+def test_the_layout_follows_the_fullest_cell(slot_engines, table, layout,
+                                             slot_rows):
+    """Uniform cells are laid out cell by slot, a skewed key past the
+    padding bound keeps the ordered form, each says so, and both are the
+    host's answer: no option, variable or argument chose."""
+    from pinot_tpu.engine import device as device_mod
+    from pinot_tpu.ops import keysorted as ks
+
+    by, tables, _cols = slot_engines
+    sql = SLOT_SQL.format(table)
+    before = by["device"].device.hbm_stats()
+    for _ in range(2):
+        got, resp, spans = _traced(by["device"], sql)
+        assert got == _rows(by["host"], sql)[0] and len(got) == 50
+        for phase in ("executor.dispatch", "executor.device_wait"):
+            attrs = spans[phase]
+            assert attrs["groupbyKeySpace"] == "full"
+            assert attrs["groupbyKeyLayout"] == layout, attrs
+            assert attrs.get("slotRows") == slot_rows
+        assert resp.get("numSegmentsOnHost", 0) == 0
+    after = by["device"].device.hbm_stats()
+    assert after["groupby_full_launches"] \
+        == before["groupby_full_launches"] + 2
+    assert after["groupby_slotted_launches"] \
+        == before["groupby_slotted_launches"] + 2 * (layout == "slotted")
+    ctx = _ctx_of(by["device"], tables[table])
+    fullest = ctx.key_order_rows(("a", "b"))
+    assert fullest == (8 if table == "u" else 6_001)
+    slots = ks.slot_rows(fullest) * ks.slot_lanes(SLOT_A * SLOT_B)
+    assert (slots <= device_mod.FULL_SLOT_PADDING * ctx.lane_rows()) \
+        == (layout == "slotted")
+    built = [k for k in ctx._gb_operands if k.startswith("gp::")]
+    assert built and all(("::slot::" in k) == (layout == "slotted")
+                         for k in built), built
+    assert "slot" not in sql.lower() and "order" not in sql.lower()[:40]
+
+
+def test_a_dead_segment_has_no_row_in_a_slot(slot_engines):
+    """``ps_alive`` against the slotted segment plane: with the second
+    segment dead the table is the first segment's rows alone (numpy), the
+    launch slotted as before."""
+    from test_subrtt import _compiled
+
+    by, tables, cols = slot_engines
+    segs, c = tables["u"], cols["u"]
+    _rows(by["device"], SLOT_SQL.format("u"))
+    dev = by["device"].device
+    before = dev.hbm_stats()["groupby_slotted_launches"]
+    q = _compiled(by["device"], segs, SLOT_SQL.format("u"))
+    half = len(c["a"]) // 2
+    for alive, rows in (([True, False], slice(0, half)),
+                        ([False, True], slice(half, None)),
+                        ([True, True], slice(None))):
+        got = dev.launch(q, list(segs), alive=alive).fetch()
+        keep = (c["f"][rows] >= 1) & (c["f"][rows] <= 3)
+        cell = (c["a"][rows].astype(np.int64) * SLOT_B + c["b"][rows])[keep]
+        count = np.bincount(cell, minlength=SLOT_A * SLOT_B)
+        total = np.bincount(cell, weights=c["v"][rows][keep].astype(
+            np.float64), minlength=SLOT_A * SLOT_B)
+        live = np.nonzero(count)[0]
+        keys = np.asarray(got.group_keys[0]).astype(np.int64) * SLOT_B \
+            + np.asarray(got.group_keys[1])
+        at = np.argsort(keys)
+        assert (keys[at] == live).all(), alive
+        assert (np.asarray(got.agg_partials[1]["count"])[at]
+                == count[live]).all(), alive
+        assert (np.asarray(got.agg_partials[0]["sum"])[at]
+                == total[live]).all(), alive
+    assert dev.hbm_stats()["groupby_slotted_launches"] == before + 3
+
+
+def test_the_byte_budget_reckons_a_slotted_plane_at_its_slots(slot_engines):
+    """``groupby_operand_cost`` of a slotted ``gp::`` key is the bytes its
+    build puts on the device, K x cells (to a lane tile) at the plane's
+    width and not the batch's rows; an ordered key's is the rows."""
+    from pinot_tpu.ops import keysorted as ks
+
+    by, tables, _cols = slot_engines
+    for table, slotted in (("u", True), ("sk", False)):
+        _rows(by["device"], SLOT_SQL.format(table))
+        ctx = _ctx_of(by["device"], tables[table])
+        keys = [k for k in ctx._gb_operands if k.startswith("gp::")]
+        assert len(keys) == 3  # the segment, the filter's column, the value
+        for key in keys:
+            arr = ctx._gb_operands.pop(key)
+            try:
+                assert ctx.groupby_operand_cost([key]) == arr.nbytes, key
+            finally:
+                ctx._gb_operands[key] = arr
+            assert ctx.groupby_operand_cost([key]) == 0  # built: no more
+            slots = 8 * ks.slot_lanes(SLOT_A * SLOT_B) if slotted \
+                else ctx.lane_rows()
+            assert arr.size == slots and ("::slot::" in key) == slotted
+        # a twin in the other layout is a key of its own, at its own size
+        if slotted:
+            twin = keys[0].replace("::slot::", "::")
+            assert ctx.groupby_operand_cost([twin]) \
+                == ctx.lane_rows() * ctx._gb_operands[keys[0]].dtype.itemsize
+            assert ctx.slotted_key(twin) == keys[0]
+
+
+def test_slotted_planes_past_the_byte_budget_keep_the_order(slot_engines):
+    """Where the slotted planes would pass the batch's byte budget and the
+    ordered ones fit, the launch keeps the ordered form - and says so -
+    before it gives the preparation up."""
+    by, tables, _cols = slot_engines
+    sql = SLOT_SQL.format("u")
+    _rows(by["device"], sql)
+    slotted = [k for k in _ctx_of(by["device"], tables["u"])._gb_operands]
+    engine = _engine({"u": tables["u"]}, mm_mode="interpret")
+    _rows(engine, "SELECT COUNT(*) FROM u WHERE f < 3 AND v >= 0 "
+                  "GROUP BY a LIMIT 1")  # the batch and its columns
+    ctx = _ctx_of(engine, tables["u"])
+    assert not any(k.startswith("gp::") for k in ctx._gb_operands)
+    ordered = ctx.groupby_operand_cost(
+        [k.replace("::slot::", "::") for k in slotted])
+    planes = ctx.lane_rows() * (1 + 1 + 4)  # segment, filter, value
+    padding = 8 * ((SLOT_A * SLOT_B + 127) // 128 * 128) * (1 + 1 + 4) \
+        - planes
+    assert padding > 0 and ordered > planes
+    dev = engine.device
+    dev.MAX_CACHED_BYTES = ctx.device_bytes() + ordered + padding // 2
+    got, _resp, spans = _traced(engine, sql)
+    assert got == _rows(by["host"], sql)[0]
+    assert spans["executor.dispatch"]["groupbyKeyLayout"] == "ordered"
+    assert spans["executor.dispatch"]["groupbyOperands"] == "built"
+    assert dev.hbm_stats()["groupby_slotted_launches"] == 0
+    assert ctx.device_bytes() <= dev.MAX_CACHED_BYTES
 
 
 # ---- the selection alone, and the planes' width ---------------------------

@@ -575,16 +575,14 @@ FULL_TRIM = (8192, (("agg", 0, "sum", False),), "select")
 FULL_COMPILE_LIMIT_S = 90
 
 
-@pytest.mark.parametrize("plane_bits", [24, 8])
-@pytest.mark.parametrize("name", list(FULL))
-def test_pipeline_full_groupby_real_batch(spec, name, plane_bits):
-    """The executor's own program for a full key space: the filter over the
-    projected planes, the channels' cumulative sums read at the cells'
-    boundaries, the table over the key space, the selection, the pack. No
-    kernel call, no sort, no scatter at the rows' length, and a compile
-    inside the limit. ``plane_bits`` 24: the mix's own (no cell holds 256
-    rows); 8: a batch whose fullest cell holds 65,536 or more."""
+def _full_pipeline(spec, name, plane_bits, slot_rows):
+    """(compiled program, its HLO text, seconds to build, cells) of the
+    executor's own pipeline for FULL[name] over the flat batch, its
+    projected planes ordered (``slot_rows`` 0) or laid out cell by slot."""
     import time
+
+    from pinot_tpu.engine.params import BatchContext
+    from pinot_tpu.ops import keysorted as ks
 
     case = FULL[name]
     template, widths = case["template"], case["widths"]
@@ -592,16 +590,20 @@ def test_pipeline_full_groupby_real_batch(spec, name, plane_bits):
     for c in template[3]:
         cells *= c
     keys = ",".join(template[2])
-    fcols = tuple((c, f"gp::{keys}::{c}") for c in case["fcols"])
-    planes = ((0, f"gp::{keys}::" + case["value"], 3),)
+    as_built = BatchContext.slotted_key if slot_rows else (lambda k: k)
+    plane = (slot_rows, ks.slot_lanes(cells)) if slot_rows \
+        else (N_FLAT_PAD // 128, 128)
+    fcols = tuple((c, as_built(f"gp::{keys}::{c}")) for c in case["fcols"])
+    planes = ((0, as_built(f"gp::{keys}::" + case["value"]), 3),)
+    seg_key = as_built(f"gp::{keys}::seg")
     prepared = ("keysorted", (), (), (
-        keys, "gs::" + keys, f"gp::{keys}::seg", fcols, planes, plane_bits))
+        keys, "gs::" + keys, seg_key, fcols, planes, plane_bits, slot_rows))
     cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
     cols["gs::" + keys] = spec((cells + 1,), "int32")
-    cols[f"gp::{keys}::seg"] = spec((N_FLAT_PAD // 128, 128), "uint8")
+    cols[seg_key] = spec(plane, "uint8")
     for c, key in fcols:
-        cols[key] = spec((N_FLAT_PAD // 128, 128), widths[c][0])
-    cols[planes[0][1]] = spec((N_FLAT_PAD // 128, 128), "uint32")
+        cols[key] = spec(plane, widths[c][0])
+    cols[planes[0][1]] = spec(plane, "uint32")
     executor = dev.DeviceExecutor(mm_mode="tpu", pallas_mode="tpu")
     entry = executor._pipeline_entry(
         template, template[4], False, False, widths,
@@ -621,6 +623,50 @@ def test_pipeline_full_groupby_real_batch(spec, name, plane_bits):
     assert not _big_results(text, "scatter", rows=N_FLAT)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 << 30, mem
     assert seconds < FULL_COMPILE_LIMIT_S, seconds
+    return compiled, text, seconds, cells
+
+
+@pytest.mark.parametrize("plane_bits", [24, 8])
+@pytest.mark.parametrize("name", list(FULL))
+def test_pipeline_full_groupby_real_batch(spec, name, plane_bits):
+    """The executor's own program for a full key space whose planes stay
+    in key order (a skewed key): the filter over the projected planes, the
+    channels' cumulative sums read at the cells' boundaries, the table
+    over the key space, the selection, the pack. No kernel call, no sort,
+    no scatter at the rows' length, and a compile inside the limit.
+    ``plane_bits`` 24: the mix's own (no cell holds 256 rows); 8: a batch
+    whose fullest cell holds 65,536 or more."""
+    _compiled, text, _seconds, cells = _full_pipeline(
+        spec, name, plane_bits, 0)
+    assert _big_results(text, "reduce-window", rows=N_FLAT)  # the sums
+    assert _big_results(text, "gather", rows=cells)
+
+
+# the fullest cell's rows of the mix's two largest statements at 37.5M
+# uniform rows, to the sublane tile (PERF.md, PR 37: 56 of a mean 27 at
+# 1.4M cells, 48 of 21 at 1.75M): 78M and 84M slots, x2.1 and x2.2
+FULL_SLOT_ROWS = {"supp_shipmode_disc": 56, "year_city_brand_profit": 48}
+
+
+@pytest.mark.parametrize("name", list(FULL))
+def test_pipeline_full_groupby_slotted_real_batch(spec, name):
+    """The same two statements as the mix's batch lays them out, cell by
+    slot: the filter over the (K, cells) planes, the channels summed down
+    the slot axis. No cumulative sum at the rows' length and no gather at the
+    cells', beside what the ordered program has not either; built in
+    5 to 10 s here (5.5 and 5.3 s, this sandbox, PR 37), as the ordered
+    program is."""
+    from pinot_tpu.ops import keysorted as ks
+
+    k = FULL_SLOT_ROWS[name]
+    compiled, text, seconds, cells = _full_pipeline(spec, name, 24, k)
+    assert k * ks.slot_lanes(cells) <= dev.FULL_SLOT_PADDING * N_FLAT_PAD
+    # (the selection's compaction keeps its cumulative sum over the cells)
+    assert not _big_results(text, "reduce-window", rows=N_FLAT)
+    assert not _big_results(text, "gather", rows=cells)
+    # the planes are read once: no copy of one is made at its length
+    assert not _big_results(text, "copy", rows=k * cells)
+    print(f"slotted {name}: built in {seconds:.1f} s")
 
 
 def test_key_order_projections_real_batch(spec):
@@ -642,7 +688,15 @@ def test_key_order_projections_real_batch(spec):
              spec((N_FLAT_PAD,), "int32"))
     _compile(lambda a, b: ks._cartesian((a, b), cards=(200_000, 7)),
              spec(lanes, "int32"), spec(lanes, "uint8"))
-    assert time.perf_counter() - t0 < 60
+    # and a projected plane laid out cell by slot (1.75M cells x 48): one
+    # gather more, its index made in the program and kept nowhere
+    for dtype, width in (("uint32", 4), ("uint8", 1)):
+        _compiled, mem = _compile(
+            lambda o, st: ks.slot_plane(o, st, k=48),
+            spec(lanes, dtype), spec((1_750_001,), "int32"))
+        assert mem.output_size_in_bytes \
+            >= 48 * ks.slot_lanes(1_750_000) * width
+    assert time.perf_counter() - t0 < 90
 
 
 def test_live_block_count_real_batch(spec):
