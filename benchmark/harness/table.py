@@ -12,17 +12,62 @@ earlier ones where its kind derives it (the first three kinds are a copy of
 instead of waiting for the parent to draw through the segments before
 theirs. Nothing here touches JAX; the children pin it to the CPU before the
 package (whose ``__init__`` imports jax) loads.
+
+The build reads the machine it runs on (``harness/machine.py``) and never
+asks it for more than is free: at most ``cores - 1`` children at once, and
+the next one only while what is free now covers its reckoned peak, what the
+running ones have yet to take, and a reserve (``admit``). Where all fit
+they all start at once. What the machine charges a build is more than its
+processes hold (the chip's machine returns freed memory tens of seconds
+late: PERF.md s.7), so the reckoning is not enough: while less than the
+reserve is free every child but the oldest stands still (``SIGSTOP``), and
+they go on one by one as memory comes back. The oldest never stands still.
 """
 
 from __future__ import annotations
 
+import ctypes
 import multiprocessing
 import os
+import signal
+import threading
 import time
+import traceback
 
 import numpy as np
 
+from . import machine
 from . import spec as spec_mod
+
+# What a building child holds at its peak, reckoned from the configuration
+# alone: rows a segment x (working bytes a row + FACTOR x the generated
+# columns' bytes a row as numpy holds their values, ``row_bytes``). Fitted to
+# the children of 12,500,000 rows on the chip's machine (the `build` line's
+# `child_max_gb`: PERF.md s.6, PR 39):
+#   a child of the 9-column table (120 B a row) peaks at 2.97 GB, in the
+#     star-tree pass (2.68 GB on ISSUE 39's machine): 135 + 0.87 x 120 =
+#     239 B a row, 2.99 GB;
+#   a child of the 30-column table (668 B a row) at 8.84-8.87 GB, holding
+#     its columns (7.6 GB at PR 32): 135 + 0.87 x 668 = 716 B a row, 8.95 GB.
+# One factor alone cannot hold both children (2.97 / 1.50 GB = 1.98, 8.87 /
+# 8.35 GB = 1.06): the narrow table's peak is its star-tree pass, which does
+# not grow with the columns' widths: two constants through two readings, so
+# read a third table shape's `child_max_gb` before trusting them for it. A
+# reckoning that is too low is the brake's to catch; one that is too high
+# costs a wait, and only where less is free than all the children's peaks.
+# It is the one number admission reckons with: this process's fold of the
+# reference's columns (0.9-11.5 GB, the `build` line's `fold_max_gb`) is the
+# reserve's and the brake's to cover.
+FACTOR = 0.87
+CHILD_ROW_BYTES = 135
+# kept free beside the reckoned peaks: a sixth of the machine's memory (the
+# chip's machine has 48.3 GB and its keeper, which no file shows, ends a run
+# at 40 GiB = 42.9 GB used, 5.4 GB short of all of it; a build has come
+# within 4.3 GB of that end), and never under 2 GB
+RESERVE_SHARE = 6
+RESERVE_FLOOR_BYTES = 2_000_000_000
+# what is free falls by up to 1.5 GB a second while eight children grow
+POLL_S = 0.25
 
 
 def column_spec(config: dict, column: str) -> dict:
@@ -178,6 +223,192 @@ class _HandOver(dict):
         return self.pop(name)
 
 
+def row_bytes(config: dict) -> int:
+    """Bytes a row of the generated columns as numpy holds their values:
+    8 B a whole number, 4 B a character of a string column's longest value.
+    Read off each kind's ``value_of``."""
+    total = 0
+    for spec in config["generator"]:
+        dtype = kind_of(spec).value_of(spec, np.arange(1)).dtype
+        total += 8 if dtype.kind in "iu" else dtype.itemsize
+    return total
+
+
+def child_peak_bytes(config: dict) -> int:
+    """What one building child holds at its peak (the constants above)."""
+    return int(config["rows_per_segment"]
+               * (CHILD_ROW_BYTES + FACTOR * row_bytes(config)))
+
+
+def admit(waiting: int, running_rss, free: int, child: int, reserve: int,
+          workers: int) -> int:
+    """How many of the ``waiting`` children to start now. ``running_rss`` is
+    what each running child holds, ``free`` what the machine has free at this
+    moment, ``child`` a child's reckoned peak, ``reserve`` what has to stay
+    free beside the children. A running child is owed what it has not yet
+    grown to; the next one starts only while the rest covers its whole
+    peak. One child always runs, however little is free: a build that does
+    not fit goes one by one, it does not die."""
+    owed = sum(max(0, child - held) for held in running_rss)
+    fit = (free - reserve - owed) // child
+    n = max(0, min(waiting, workers - len(running_rss), fit))
+    return 1 if waiting and not n and not running_rss else n
+
+
+def _die_with_parent(parent: int) -> None:
+    """A child whose parent is gone (``kill -9`` reaches no ``finally``)
+    has nobody to build for: without this it works on for half a minute,
+    holding its gigabytes and writing its files into a directory nobody
+    removes. Linux ends it with the parent, even where it stands still; the
+    loop is for a parent that was gone before that was asked for."""
+    try:
+        prctl = ctypes.CDLL(None, use_errno=True).prctl
+        prctl.argtypes = [ctypes.c_int] + [ctypes.c_ulong] * 4
+        prctl.restype = ctypes.c_int
+        prctl(1, signal.SIGKILL, 0, 0, 0)      # PR_SET_PDEATHSIG
+    except (OSError, AttributeError):
+        pass
+    while os.getppid() == parent:
+        time.sleep(1.0)
+    os._exit(1)
+
+
+def _child_main(conn, parent: int, job) -> None:
+    threading.Thread(target=_die_with_parent, args=(parent,),
+                     daemon=True).start()
+    try:
+        conn.send(("ok", _build_segment_job(job)))
+    except BaseException:  # noqa: BLE001 - the parent raises it
+        conn.send(("error", traceback.format_exc()))
+        raise
+
+
+class _Crew(threading.Thread):
+    """The building children, run by a thread of the parent while its main
+    thread folds the reference: admits them as memory allows, polls what
+    the tree of processes holds, reaps them."""
+
+    def __init__(self, jobs, workers: int, child: int):
+        super().__init__(daemon=True)
+        self.ctx = multiprocessing.get_context("spawn")
+        self.waiting = list(jobs)          # (k, job), in segment order
+        self.running = {}                  # k -> (process, connection)
+        self.seconds = {}                  # k -> the child's own seconds
+        self.workers, self.child = workers, child
+        self.free_start, self.limit = machine.free_memory()
+        self.reserve = max(RESERVE_FLOOR_BYTES, self.limit // RESERVE_SHARE)
+        self.free_min = self.free_start
+        self.peak_tree = self.peak_child = self.peak_fold = 0
+        self.held = set()                  # the children that stand still
+        self.waited_s = 0.0
+        self.error = None
+        self._halt = threading.Event()
+        # the first children start here, from the caller's thread and before
+        # its fold takes the interpreter: all of them, where all fit
+        self._poll(0.0)
+
+    def _reap(self) -> None:
+        for k, (proc, conn) in list(self.running.items()):
+            if not conn.poll() and proc.is_alive():
+                continue
+            try:  # a pipe whose far end died is ready too, and empty
+                what, value = conn.recv()
+            except EOFError:
+                what, value = "died", None
+            proc.join()
+            if what == "ok":
+                self.seconds[k] = value
+            elif what == "error":
+                self.error = f"segment {k}'s child raised:\n{value}"
+            else:
+                self.error = (f"segment {k}'s child died with exit code "
+                              f"{proc.exitcode} and said nothing")
+            conn.close()
+            del self.running[k]
+            self.held.discard(k)
+
+    def _stand(self, k: int, still: bool) -> None:
+        os.kill(self.running[k][0].pid,
+                signal.SIGSTOP if still else signal.SIGCONT)
+        if still:
+            self.held.add(k)
+        else:
+            self.held.discard(k)
+
+    def _brake(self, free: int) -> None:
+        """Less than the reserve is free: every child but the oldest stands
+        still. The oldest living child never does, whatever is free: when it
+        ends the next oldest goes on in its place, so a build on a machine
+        that stays short goes one by one and ends. More than the reserve and
+        a child's peak free: the oldest of those that stand goes on too."""
+        alive = sorted(self.running)
+        if free < self.reserve:
+            for k in alive[1:]:
+                if k not in self.held:
+                    self._stand(k, True)
+        if alive and alive[0] in self.held:
+            self._stand(alive[0], False)
+        elif self.held and free > self.reserve + self.child:
+            self._stand(min(self.held), False)
+
+    def _poll(self, dt: float) -> None:
+        me = os.getpid()
+        tree = machine.tree_rss(me)
+        held_by = [tree.get(p.pid, 0) for p, _ in self.running.values()]
+        self.peak_tree = max(self.peak_tree, sum(tree.values()))
+        self.peak_child = max([self.peak_child] + held_by)
+        self.peak_fold = max(self.peak_fold, tree.get(me, 0))
+        free, _ = machine.free_memory()
+        self.free_min = min(self.free_min, free)
+        self._brake(free)
+        n = 0 if self.held else admit(
+            len(self.waiting), held_by, free, self.child, self.reserve,
+            self.workers)
+        if self.held or n < min(len(self.waiting),
+                                self.workers - len(self.running)):
+            self.waited_s += dt      # a core stood free and memory did not
+        for k, job in self.waiting[:n]:
+            recv, send = self.ctx.Pipe(duplex=False)
+            proc = self.ctx.Process(target=_child_main,
+                                    args=(send, me, job), daemon=True)
+            proc.start()
+            send.close()
+            self.running[k] = (proc, recv)
+        del self.waiting[:n]
+
+    def run(self) -> None:
+        last = time.time()
+        try:
+            while (self.waiting or self.running) and not self.error \
+                    and not self._halt.is_set():
+                self._reap()
+                now = time.time()
+                self._poll(now - last)
+                last = now
+                self._halt.wait(POLL_S)
+        except Exception:  # noqa: BLE001 - the main thread raises it
+            self.error = traceback.format_exc()
+
+    def result(self, timeout: float) -> list:
+        """Every child's seconds, in segment order, once all have ended."""
+        self.join(timeout)
+        if self.is_alive():
+            self.error = f"not done {timeout:.0f} s after the fold's end"
+        if self.error:
+            raise RuntimeError("build_table: " + self.error)
+        return [self.seconds[k] for k in sorted(self.seconds)]
+
+    def stop(self) -> None:
+        """Leave nothing running: every child that lives is killed."""
+        self._halt.set()
+        self.join()
+        for proc, conn in self.running.values():
+            proc.kill()
+            proc.join()
+            conn.close()
+        self.running.clear()
+
+
 def _build_segment_job(job):
     """Runs in a spawned child: draw segment ``k`` (the child is handed the
     seed, not 1 GB of columns), lay it out and build its files."""
@@ -206,18 +437,27 @@ def build_table(config: dict, seed: int, out_root: str, reference, say):
     dirs = [os.path.join(out_root, f"s{k}") for k in range(n_seg)]
     workers = min(n_seg, max(1, (os.cpu_count() or 2) - 1))
     t0 = time.time()
-    ctx = multiprocessing.get_context("spawn")
-    with ctx.Pool(workers) as pool:
-        pending = [pool.apply_async(_build_segment_job,
-                                    ((config, seed, k, out),))
-                   for k, out in enumerate(dirs)]
+    crew = _Crew([(k, (config, seed, k, out)) for k, out in enumerate(dirs)],
+                 workers, child_peak_bytes(config))
+    crew.start()
+    try:
         for cols in reference_segments(config, seed, reference.columns):
             reference.add(cols)
         ref_s = time.time() - t0
-        per_seg = [p.get(timeout=1100) for p in pending]
+        per_seg = crew.result(timeout=1100)
+    finally:
+        crew.stop()
     say(f"build segments={n_seg} rows_per_segment="
         f"{config['rows_per_segment']} layout={config['layout']['kind']} "
         f"seed={seed} workers={workers} seconds={time.time() - t0:.1f} "
         f"reference_seconds={ref_s:.1f} "
-        f"slowest_segment_seconds={max(per_seg):.1f}")
+        f"slowest_segment_seconds={max(per_seg):.1f} "
+        f"free_gb={crew.free_start / machine.GB:.2f} "
+        f"child_gb={crew.child / machine.GB:.2f} "
+        f"peak_tree_gb={crew.peak_tree / machine.GB:.2f} "
+        f"limit_gb={crew.limit / machine.GB:.2f} "
+        f"waited_s={crew.waited_s:.1f} "
+        f"free_min_gb={crew.free_min / machine.GB:.2f} "
+        f"child_max_gb={crew.peak_child / machine.GB:.2f} "
+        f"fold_max_gb={crew.peak_fold / machine.GB:.2f}")
     return dirs

@@ -29,6 +29,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import signal  # noqa: E402
 import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
@@ -41,6 +42,7 @@ sys.path.insert(0, BENCH_DIR)                    # harness
 sys.path.insert(1, os.path.dirname(BENCH_DIR))   # the program, pinot_tpu
 
 TRACE_SLICE_S = 5.0
+WORK_PREFIX = "pinot_tpu_benchmark_"
 
 
 from harness.cluster import require_tpu  # noqa: E402 — imports no jax
@@ -171,6 +173,7 @@ def trace_middle(trace_dir: str, seconds: float) -> tuple:
 
 
 def run(args, look_for_chip) -> dict:
+    from harness import machine
     from harness import reference as reference_mod
     from harness import spec, table
 
@@ -189,14 +192,19 @@ def run(args, look_for_chip) -> dict:
               "no number is taken off the chip", file=sys.stderr)
         raise SystemExit(2)
 
-    work = tempfile.mkdtemp(prefix="pinot_tpu_benchmark_")
+    # a run that was killed reached no `finally`: its 4 GB of segment
+    # files are still there, and nobody else removes them
+    for gone in machine.sweep_stale(tempfile.gettempdir(), WORK_PREFIX):
+        say(f"swept {gone}: its run is dead")
+    work = tempfile.mkdtemp(prefix=WORK_PREFIX)
     loadgen = cluster = None
     try:
+        machine.claim(work)
         loadgen = LoadGenerator(cell.traffic_file, args.seed, trace)
         ref = reference_mod.Reference(config, traffic["statements"])
         # The table is built BEFORE this process touches JAX: the TPU
-        # runtime takes 14 GB of host memory when it starts, and with it
-        # beside eight building children the 40 GiB machine runs out.
+        # runtime maps 14 GB into this process when it starts, and the
+        # children are spawned from a process that has no JAX threads yet.
         # Built where the server serves in place: no deep-store copy, no
         # local copy — 4 GB written per run instead of 12.
         dirs = table.build_table(
@@ -345,7 +353,14 @@ def parse(argv=None):
     return ap.parse_args(argv)
 
 
+def _terminated(signum, frame):
+    """SIGTERM leaves by the ``finally`` paths: children stopped, the work
+    directory removed."""
+    raise SystemExit(128 + signum)
+
+
 def main(argv=None) -> int:
+    signal.signal(signal.SIGTERM, _terminated)
     result = run(parse(argv), require_tpu)
     for name, n in result["compared"].items():
         print(f"compared {name}={n['value']} "
