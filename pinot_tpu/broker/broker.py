@@ -3129,6 +3129,8 @@ class Broker:
                     # server partials; the per-flight detail rides "roofline"
                     "deviceBytesMoved": stats.device_bytes_moved,
                     "deviceKernelMs": round(stats.device_kernel_ms, 3),
+                    "deviceQueueMs": round(stats.device_queue_ms, 3),
+                    "deviceRunMs": round(stats.device_run_ms, 3),
                     "deviceLinkMs": round(stats.device_link_ms, 3),
                     "requestId": request_id,
                 }

@@ -185,8 +185,10 @@ class QueryLogger:
                     "tenant", "priorityClass", "sheddingReason",
                     "servedStale", "staleAgeMs",
                     # kernel roofline accounting (ISSUE 11): HBM bytes
-                    # the device pipelines moved vs their kernel wall
-                    "deviceBytesMoved", "deviceKernelMs", "deviceLinkMs",
+                    # the device pipelines moved vs their kernel wall, and
+                    # that wait split into queue and run on the device
+                    "deviceBytesMoved", "deviceKernelMs", "deviceQueueMs",
+                    "deviceRunMs", "deviceLinkMs",
                     # distributed stage-2 exchange (ISSUE 16): effective
                     # strategy (demotion included — the plan is mutated
                     # before logging), partition fan-out, wire volume,

@@ -83,6 +83,15 @@ def _annotate(name: str, **ids):
     return _annotation("pinot." + name, **ids)
 
 
+def mark(name: str, **ids) -> None:
+    """A zero-length ``pinot.<name>`` in the profiler's trace: an instant
+    of a traced request that the device trace can be lined up against.
+    Nothing is kept."""
+    ann = _annotate(name, **ids)
+    ann.__enter__()
+    ann.__exit__(None, None, None)
+
+
 class Span:
     """One span, and the context manager that records it. With
     ``tracer`` None every method is a no-op."""

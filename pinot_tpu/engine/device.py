@@ -39,7 +39,11 @@ from pinot_tpu.common.options import bool_option
 from pinot_tpu.common.trace import span as trace_span
 from pinot_tpu.engine import aggspec
 from pinot_tpu.engine.advisor import PlanAdvisor, advisor_enabled
-from pinot_tpu.engine.inflight import InflightLaunch, LaunchCoalescer
+from pinot_tpu.engine.inflight import (
+    DeviceTimeline,
+    InflightLaunch,
+    LaunchCoalescer,
+)
 from pinot_tpu.engine.params import (
     BatchContext,
     DeviceUnsupported,
@@ -1786,6 +1790,9 @@ class DeviceExecutor:
         self.inflight = 0            # launches between dispatch and fetch
         self._launch_ids = itertools.count(1)  # one per device launch
         self.coalescer = LaunchCoalescer()
+        # the served launches in dispatch order and each one's end on the
+        # device: a fetch's wait split into queue and run
+        self.device_timeline = DeviceTimeline()
         # cumulative host-link observability
         self.fetch_bytes_total = 0
         self.fetch_leaves_total = 0
@@ -1880,11 +1887,10 @@ class DeviceExecutor:
         # kernel roofline accounting (ISSUE 11): per-pipeline-label
         # aggregates of the static bytes-moved cost model (ColPlan-width
         # column planes, block-skip gather ratio, trimmed fetch bytes)
-        # against the measured kernel/link wall — achieved GB/s, surfaced
+        # against the device's time on the launch — achieved GB/s, surfaced
         # through hbm_stats()["roofline"], the deviceKernelGbps histogram,
         # and per-query IntermediateResult.roofline records
         self._roofline: dict = {}
-        self.last_get_wait_s = None
         # device launch/fetch latency histograms ride the server registry
         # (ISSUE 7: the hot timers share ONE histogram-backed truth)
         self.metrics = get_metrics("server")
@@ -2321,16 +2327,22 @@ class DeviceExecutor:
         bits.extend(g.name for g in (q.group_by or ()) if g.is_identifier)
         return ":".join(bits)
 
-    def _make_resolve(self, bufs_dev, layout, flight=None, attrs=None):
+    def _make_resolve(self, bufs_dev, layout, flight=None, attrs=None,
+                      launch=None):
         """fetch-phase closure shared by solo and cohort launches: ONE
         blocking device_get of the dispatched packed buffer, observability
         accounting under the lock, unpack by the precomputed layout.
 
         The blocking wait always splits into a KERNEL wait
-        (block_until_ready — remaining device compute since dispatch) and
-        a LINK wait (device_get — the host transfer): the split feeds the
-        ALWAYS-ON roofline accounting (ISSUE 11 — achieved GB/s needs
-        kernel-ms without tracing armed). Whoever runs the one fetch —
+        (block_until_ready — remaining device compute since dispatch:
+        ``kernelMs``, the fetch's wait) and a LINK wait (device_get — the
+        host transfer). ``launch``, the launch on the device's timeline
+        (None where nothing was launched), splits the device's part
+        further: ``deviceQueueMs`` behind the launches dispatched before
+        it, ``deviceRunMs`` on the device, ``launchesAhead``. They feed
+        the ALWAYS-ON accounting — the wait span, the flight record, the
+        response's stats and ``/metrics`` — and the achieved GB/s divides
+        by the run. Whoever runs the one fetch —
         the launching query, or the first member of a cohort to get here
         — also records the pair, and the unpack, as spans on ITS trace
         (the thread's active tracer: InflightLaunch._traced_resolve). The
@@ -2359,6 +2371,10 @@ class DeviceExecutor:
             with wait_span:
                 jax.block_until_ready(bufs_dev)
             _t_kernel = _time.perf_counter()
+            if launch is not None:
+                # the fetch saw its launch ready: stamped now, if the
+                # device timeline's waiter has not stamped it already
+                self.device_timeline.seen(launch, _t_kernel)
             with trace_span("executor.link"):
                 bufs = jax.device_get(bufs_dev)
             # blocking wait = link round trip + kernel
@@ -2368,21 +2384,26 @@ class DeviceExecutor:
                 bufs = {k: np.asarray(v) for k, v in bufs.items()}
                 fetched = sum(v.nbytes for v in bufs.values())
                 with self._lock:
-                    self.last_get_wait_s = wait
                     # observability: what actually crossed the host link
                     self.fetch_bytes_total += fetched
                     self.fetch_leaves_total += len(bufs)
                 self.metrics.time_ms("deviceFetchMs", wait * 1e3)
                 outs = _unpack_outs(bufs, layout)
-                # what only the result can say of the key space
+                # what only the result can say of the key space, and the
+                # launch's queue and run on the device
                 read = _key_space_outcome(
                     outs, stamp["attrs"].get("groupbyKeySpace"))
+                if launch is not None:
+                    read.update(launch.on_device())
+                    self.metrics.time_ms("deviceQueueMs", launch.queue_s * 1e3)
+                    self.metrics.time_ms("deviceRunMs", launch.run_s * 1e3)
+                    self.metrics.observe("deviceLaunchesAhead", launch.ahead)
                 stamp["attrs"].update(read)
                 wait_span.set(**read)
                 if flight is not None:
                     self._note_flight(flight, outs, fetched,
                                       _t_kernel - _t_get,
-                                      _t_link - _t_kernel)
+                                      _t_link - _t_kernel, launch)
             return outs
 
         resolve.stamp = stamp
@@ -2422,13 +2443,14 @@ class DeviceExecutor:
                 "data_bytes": 0, "zone_bytes": 0, "record": None}
 
     def _note_flight(self, flight: dict, outs: dict, fetched_bytes: int,
-                     kernel_s: float, link_s: float) -> None:
+                     kernel_s: float, link_s: float, launch=None) -> None:
         """Fold one resolved flight into the roofline accounting: the
         modeled bytes (column planes at their ColPlan widths, data planes
         scaled by the block-skip gather ratio the kernel reported, plus
-        the packed fetch buffer) over the measured kernel wall → achieved
-        GB/s. Cache hits (no kernel ran) count separately and never feed
-        the GB/s histogram."""
+        the packed fetch buffer) over the device's time on the launch
+        (``launch.run_s``: not the fetch's wait, which holds the queue
+        behind other launches) → achieved GB/s. Cache hits (no kernel
+        ran) count separately and never feed the GB/s histogram."""
         try:
             cache_hit = bool(flight.get("cache_hit"))
             ratio = 1.0
@@ -2459,20 +2481,26 @@ class DeviceExecutor:
                    "kernelMs": round(kernel_ms, 3),
                    "linkMs": round(link_ms, 3),
                    "cacheHit": cache_hit}
+            queue_ms = run_ms = 0.0
+            if launch is not None:
+                queue_ms, run_ms = launch.queue_s * 1e3, launch.run_s * 1e3
+                rec["queueMs"] = round(queue_ms, 3)
+                rec["runMs"] = round(run_ms, 3)
             if gather_bytes:
                 rec["gatherBytes"] = gather_bytes
             rec.update(flight.get("origin") or {})
             rec.update(_key_space_outcome(outs, rec.get("groupbyKeySpace")))
             gbps = None
-            if not cache_hit and kernel_s > 1e-9:
-                gbps = bytes_moved / kernel_s / 1e9
+            if not cache_hit and run_ms > 1e-6:
+                gbps = bytes_moved / run_ms / 1e6
                 rec["gbps"] = round(gbps, 3)
             flight["record"] = rec
             with self._lock:
                 agg = self._roofline.setdefault(
                     flight["label"],
                     {"queries": 0, "cache_hits": 0, "bytes_moved": 0,
-                     "kernel_ms": 0.0, "link_ms": 0.0})
+                     "kernel_ms": 0.0, "queue_ms": 0.0, "run_ms": 0.0,
+                     "link_ms": 0.0})
                 agg["queries"] += 1
                 agg["link_ms"] += link_ms
                 if cache_hit:
@@ -2480,6 +2508,8 @@ class DeviceExecutor:
                 else:
                     agg["bytes_moved"] += bytes_moved
                     agg["kernel_ms"] += kernel_ms
+                    agg["queue_ms"] += queue_ms
+                    agg["run_ms"] += run_ms
             if gbps is not None:
                 self.metrics.observe("deviceKernelGbps", gbps)
             # plan-advisor feedback: measured skip selectivity (only the
@@ -2495,17 +2525,19 @@ class DeviceExecutor:
             log.exception("roofline flight accounting failed")
 
     def roofline_stats(self) -> dict:
-        """Per-pipeline roofline snapshot: modeled bytes / kernel wall →
-        achieved GB/s per label."""
+        """Per-pipeline roofline snapshot: modeled bytes / the device's
+        time on the launches (``run_ms``) → achieved GB/s per label.
+        ``kernel_ms`` sums the fetches' waits, ``queue_ms`` the part of
+        them spent behind other launches."""
         with self._lock:
             aggs = {k: dict(v) for k, v in self._roofline.items()}
         kernels = {}
         for label, agg in aggs.items():
             entry = dict(agg)
-            entry["kernel_ms"] = round(entry["kernel_ms"], 3)
-            entry["link_ms"] = round(entry["link_ms"], 3)
-            if agg["kernel_ms"] > 0:
-                gbps = agg["bytes_moved"] / (agg["kernel_ms"] / 1e3) / 1e9
+            for k in ("kernel_ms", "queue_ms", "run_ms", "link_ms"):
+                entry[k] = round(entry[k], 3)
+            if agg["run_ms"] > 0:
+                gbps = agg["bytes_moved"] / (agg["run_ms"] / 1e3) / 1e9
                 entry["gbps"] = round(gbps, 3)
             kernels[label] = entry
         return {"kernels": kernels}
@@ -3545,6 +3577,8 @@ class DeviceExecutor:
         dispatch.set(launchId=launch_id, **origin)
         with dispatch:
             bufs_dev = pipeline(cols, n_docs, params)  # async dispatch
+        launch = self.device_timeline.dispatched(
+            launch_id, bufs_dev, traced=dispatch.tracer is not None)
         if cache_key is not None:
             # cache the dispatched buffer itself (immutable): the repeat
             # query fetches it again without gather/dispatch/kernel.
@@ -3554,7 +3588,7 @@ class DeviceExecutor:
         return self._make_resolve(
             bufs_dev, layout, flight,
             attrs={"launchId": launch_id, "cohortSize": 1,
-                   "cohortPadded": 1, **origin})
+                   "cohortPadded": 1, **origin}, launch=launch)
 
     def _cohort_launch(self, entry, cols, n_docs, members, lkey, tracer=None,
                        flight=None, origin=None):
@@ -3611,10 +3645,12 @@ class DeviceExecutor:
         dispatch.set(launchId=launch_id, **origin)
         with dispatch:
             bufs_dev = pipeline_v(cols, n_docs, pstack)  # async dispatch
+        launch = self.device_timeline.dispatched(
+            launch_id, bufs_dev, traced=dispatch.tracer is not None)
         return self._make_resolve(
             bufs_dev, layout, flight,
             attrs={"launchId": launch_id, "cohortSize": n_real,
-                   "cohortPadded": n_pad, **origin})
+                   "cohortPadded": n_pad, **origin}, launch=launch)
 
     def _cohort_pipeline(self, entry):
         """(jitted packed pipeline, inner fn) over params carrying a

@@ -251,6 +251,8 @@ class QueryEngine:
                 # kernel roofline accounting (ISSUE 11)
                 "deviceBytesMoved": stats.device_bytes_moved,
                 "deviceKernelMs": round(stats.device_kernel_ms, 3),
+                "deviceQueueMs": round(stats.device_queue_ms, 3),
+                "deviceRunMs": round(stats.device_run_ms, 3),
                 "deviceLinkMs": round(stats.device_link_ms, 3),
                 "timeUsedMs": round((time.time() - t0) * 1000, 3),
             }

@@ -180,7 +180,8 @@ def _kernel_line(rec: dict) -> str:
         if rec.get(k))
     return (f"    KERNEL({label}{where}: {perf}, "
             f"bytes={rec.get('bytesMoved')}, "
-            f"kernelMs={rec.get('kernelMs')}, linkMs={rec.get('linkMs')}"
+            f"kernelMs={rec.get('kernelMs')}, queueMs={rec.get('queueMs')}, "
+            f"runMs={rec.get('runMs')}, linkMs={rec.get('linkMs')}"
             f"{operands})")
 
 

@@ -25,15 +25,150 @@ result comes back as ONE packed buffer. The leader holds a fixed
 micro-batch window for its members. Nothing the load can set opens a
 window. If a cell ever shows a device with a backlog of short launches,
 the hold to write keys off that backlog (ROADMAP D7).
+
+``DeviceTimeline`` is the device's side of a launch: the executor's
+launches in the order they were dispatched, and the instant each ended on
+the device, so that a fetch's wait splits into the time the launch queued
+behind the launches before it and the time the device spent on it.
 """
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
+from collections import deque
 
+from pinot_tpu.common import trace
 from pinot_tpu.common.trace import span as trace_span
 from pinot_tpu.engine.params import KeySpaceFull
+
+
+class DeviceLaunch:
+    """One launch on the device's timeline (``perf_counter`` seconds):
+    enqueued at ``t_dispatched`` behind ``ahead`` launches that had not
+    ended; ``t_end``, the first instant anyone saw it ready; ``prev_end``,
+    the end of the launch dispatched before it. ``traced``: a traced
+    request's, whose end is written to the profiler's trace."""
+
+    __slots__ = ("launch_id", "t_dispatched", "ahead", "t_end", "prev_end",
+                 "bufs", "traced")
+
+    def __init__(self, launch_id, t_dispatched: float, ahead: int, bufs,
+                 traced: bool = False):
+        self.launch_id, self.t_dispatched, self.ahead = \
+            launch_id, t_dispatched, ahead
+        self.traced = traced
+        self.t_end = None
+        self.prev_end = float("-inf")
+        self.bufs = bufs  # what the waiter blocks on; dropped at the end
+
+    @property
+    def queue_s(self) -> float:
+        """Dispatch to the end of the launch before it: one device runs
+        its launches in the order they were enqueued."""
+        return max(0.0, min(self.prev_end, self.t_end) - self.t_dispatched)
+
+    @property
+    def run_s(self) -> float:
+        """The device's time on this launch: from the later of its
+        dispatch and the previous launch's end, to its own end. Holds the
+        device's idle time between enqueue and first operation besides."""
+        return self.t_end - max(self.t_dispatched, self.prev_end)
+
+    def on_device(self) -> dict:
+        """What its wait span and flight record say of it."""
+        return {"deviceQueueMs": round(self.queue_s * 1e3, 3),
+                "deviceRunMs": round(self.run_s * 1e3, 3),
+                "launchesAhead": self.ahead}
+
+
+class DeviceTimeline:
+    """The served launches of one device (a mesh is one), in the order
+    they were dispatched, each stamped with its end on the device.
+
+    The ends are stamped in dispatch order by whoever first sees one: a
+    waiter thread that blocks on the oldest launch not yet ended, or the
+    launch's fetch once its own wait returns (``seen``) — the device ran
+    every launch before it first, so those end no later. A launch fetched
+    long after its end still gets the instant the waiter saw; a fetch
+    never waits for the waiter. The waiter's blocking is written nowhere:
+    a traced launch's end is a zero-length ``pinot.executor.device_end``
+    in the profiler's trace, carrying ``launch_id``. Device work outside a
+    served launch (an operand build, a key-space probe) is not on the
+    timeline: it counts toward the next launch's run. A launch is
+    registered once its program is enqueued; two callers whose enqueues
+    cross before they register are stamped in the order they registered,
+    which keeps the runs apart and their sum whole but gives the earlier
+    one's run to the later."""
+
+    # the waiter leaves after this long with nothing dispatched and is
+    # started again by the next launch
+    IDLE_EXIT_S = 1.0
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._open: deque = deque()  # dispatched, end not yet seen
+        # the same launches, handed to the waiter: a queue of C, so that a
+        # launch costs the interpreter two short wakes of the waiter and
+        # no Python condition (an interpreter-bound server pays for each)
+        self._to_wait: queue.SimpleQueue = queue.SimpleQueue()
+        self._last_end = float("-inf")
+        self._waiter = None
+        self.ended = 0  # launches stamped so far
+
+    def dispatched(self, launch_id, bufs, traced: bool = False
+                   ) -> DeviceLaunch:
+        """Register a launch whose program was just enqueued; ``bufs``:
+        its output buffers."""
+        with self._lock:
+            launch = DeviceLaunch(launch_id, time.perf_counter(),
+                                  len(self._open), bufs, traced)
+            self._open.append(launch)
+            self._to_wait.put(launch)
+            if self._waiter is None:
+                self._waiter = threading.Thread(
+                    target=self._wait, daemon=True, name="pinot-device-end")
+                self._waiter.start()
+        return launch
+
+    def seen(self, launch: DeviceLaunch, t: float) -> None:
+        """``launch`` was seen ready at ``t``: stamp it and the open
+        launches dispatched before it, unless someone saw it first."""
+        ended = []
+        with self._lock:
+            while launch.t_end is None and self._open:
+                head = self._open.popleft()
+                head.prev_end = self._last_end
+                head.t_end = self._last_end = max(t, self._last_end,
+                                                  head.t_dispatched)
+                head.bufs = None
+                ended.append(head)
+            self.ended += len(ended)
+        for e in ended:
+            if e.traced:
+                trace.mark("executor.device_end", launch_id=e.launch_id)
+
+    def _wait(self) -> None:
+        import jax
+
+        while True:
+            try:
+                launch = self._to_wait.get(timeout=self.IDLE_EXIT_S)
+            except queue.Empty:
+                with self._lock:
+                    if self._to_wait.empty():
+                        self._waiter = None
+                        return
+                continue
+            bufs = launch.bufs
+            if bufs is None:
+                continue  # its fetch, or a later launch's, saw it end
+            try:
+                jax.block_until_ready(bufs)
+            except Exception:  # noqa: BLE001 — a failed launch ended too;
+                pass           # its fetch reports the failure
+            self.seen(launch, time.perf_counter())
 
 
 class InflightLaunch:
@@ -149,6 +284,8 @@ class InflightLaunch:
                 st = result.stats
                 st.device_bytes_moved += int(rec.get("bytesMoved") or 0)
                 st.device_kernel_ms += float(rec.get("kernelMs") or 0.0)
+                st.device_queue_ms += float(rec.get("queueMs") or 0.0)
+                st.device_run_ms += float(rec.get("runMs") or 0.0)
                 st.device_link_ms += float(rec.get("linkMs") or 0.0)
             return result
         finally:

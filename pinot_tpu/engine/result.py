@@ -64,11 +64,16 @@ class ExecutionStats:
     # kernel roofline accounting (ISSUE 11): modeled HBM bytes the device
     # pipeline moved (ColPlan-width column planes scaled by the block-skip
     # gather ratio, plus the trimmed fetch buffer) and the measured
-    # kernel/link wall — achieved GB/s = bytes / kernel time, computed at
-    # export. Summed across partials on merge; per-flight detail rides
+    # kernel/link wall (kernel: the fetch's wait for the device) — and
+    # that wait's two parts on the device: queued behind the launches
+    # dispatched before (device_queue_ms) and the device's time on the
+    # launch (device_run_ms; achieved GB/s = bytes / run, computed at
+    # export). Summed across partials on merge; per-flight detail rides
     # IntermediateResult.roofline.
     device_bytes_moved: int = 0
     device_kernel_ms: float = 0.0
+    device_queue_ms: float = 0.0
+    device_run_ms: float = 0.0
     device_link_ms: float = 0.0
     # distributed stage-2 exchange accounting (ISSUE 16,
     # query2/exchange.py): partitions/bytes this worker SHIPPED to peers
@@ -113,6 +118,8 @@ class ExecutionStats:
         self.table_epoch = max(self.table_epoch, other.table_epoch)
         self.device_bytes_moved += other.device_bytes_moved
         self.device_kernel_ms += other.device_kernel_ms
+        self.device_queue_ms += other.device_queue_ms
+        self.device_run_ms += other.device_run_ms
         self.device_link_ms += other.device_link_ms
         self.exchange_partitions_shipped += other.exchange_partitions_shipped
         self.exchange_bytes_shipped += other.exchange_bytes_shipped
@@ -144,7 +151,8 @@ class IntermediateResult:
     trace: Optional[list] = None  # phase spans when SET trace = true
     # per-flight roofline records (ISSUE 11): one dict per device launch
     # this partial folded in ({kernel, bytesMoved, bytesFetched, kernelMs,
-    # linkMs, gbps, cacheHit}) — concatenated across partials, shipped in
+    # queueMs, runMs, linkMs, gbps, cacheHit}) — concatenated across
+    # partials, shipped in
     # DataTable metadata like ``trace``
     roofline: Optional[list] = None
 

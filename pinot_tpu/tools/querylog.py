@@ -4,8 +4,11 @@ Reads the broker's structured JSONL query log (broker/querylog.py) and
 prints the operator's five-minute view: volume + error/timeout/partial
 counts, latency percentiles overall and per table/template, the
 per-phase p50 breakdown reconstructed from the attached traces (queue /
-compile / gather / kernel / link / reduce — the waterfall that tells
-kernel-ms from link-ms from queue-ms), and the top-N slowest queries.
+compile / gather / run / link / reduce — the waterfall that tells the
+device's run from link-ms from queue-ms; a launch's wait on the device is
+its ``deviceQueueMs`` under queue and its ``deviceRunMs`` under run, and
+a log written before they were stamped shows the whole wait as kernel),
+and the top-N slowest queries.
 
 Accepts MULTIPLE log paths (ISSUE 18): a broker fleet writes one JSONL
 per broker, each entry stamped with its ``brokerId`` — passing them all
@@ -88,6 +91,13 @@ def phase_breakdown(entry: dict) -> dict:
             return
         for s in spans_or_nested or ():
             if not isinstance(s, dict):
+                continue
+            attrs = s.get("attrs") or {}
+            if "deviceRunMs" in attrs:
+                # the launch's wait, split where the device spent it
+                for bucket, k in (("queue", "deviceQueueMs"),
+                                  ("run", "deviceRunMs")):
+                    out[bucket] = out.get(bucket, 0.0) + attrs[k]
                 continue
             bucket = _phase_bucket(s.get("phase", ""))
             if bucket is not None:

@@ -41,8 +41,8 @@ def canonical(resp: dict) -> dict:
     # roofline accounting (ISSUE 11) is measurement, not results: kernel
     # wall and modeled bytes differ run to run (cohort members attribute
     # the shared kernel to the leader; cache hits move zero bytes)
-    for k in ("deviceBytesMoved", "deviceKernelMs", "deviceLinkMs",
-              "roofline"):
+    for k in ("deviceBytesMoved", "deviceKernelMs", "deviceQueueMs",
+              "deviceRunMs", "deviceLinkMs", "roofline"):
         out.pop(k, None)
     return out
 
@@ -428,10 +428,12 @@ class TestFetchTimeFallbackGate:
 
 class TestObservabilityCounters:
     def test_counters_consistent_under_parallel_executes(self, tables):
-        """CI guard: fetch_bytes_total / fetch_leaves_total / last_get_wait_s
-        stay consistent under parallel executes — every request its own
-        launch, K device queries of one shape account exactly K× the
-        solo deltas."""
+        """CI guard: fetch_bytes_total / fetch_leaves_total and the device
+        timeline's stamps stay consistent under parallel executes — every
+        request its own launch, K device queries of one shape account
+        exactly K× the solo deltas (repeats of one statement: the partials
+        cache answers them), and with the cache off each of K launches has
+        its end stamped once, in dispatch order."""
         eng = make_engine(*tables)
         dev = eng.device
         sql = "SELECT dim1, COUNT(*), SUM(ivalue) FROM t GROUP BY dim1"
@@ -446,7 +448,32 @@ class TestObservabilityCounters:
         run_threads(4, lambda i: [eng.execute(sql) for _ in range(5)])
         assert dev.fetch_bytes_total - b1 == 20 * per_bytes
         assert dev.fetch_leaves_total - l1 == 20 * per_leaves
-        assert dev.last_get_wait_s is not None and dev.last_get_wait_s >= 0
+
+        timeline = dev.device_timeline
+        e1 = timeline.ended
+        launches = []
+        real = timeline.dispatched
+
+        def dispatched(*a, **kw):
+            launch = real(*a, **kw)
+            launches.append(launch)
+            return launch
+
+        timeline.dispatched = dispatched
+        dev.partials_cache_enabled = False
+        try:
+            run_threads(4, lambda i: [eng.execute(sql) for _ in range(5)])
+        finally:
+            del timeline.dispatched
+            dev.partials_cache_enabled = True
+        assert timeline.ended - e1 == len(launches) == 20
+        for launch in launches:
+            assert launch.t_end >= launch.t_dispatched
+            assert launch.queue_s >= 0 and launch.run_s >= 0
+            assert 0 <= launch.ahead < 20
+        ends = sorted(launch.t_end for launch in launches)
+        assert sum(launch.run_s for launch in launches) \
+            <= ends[-1] - min(x.t_dispatched for x in launches) + 1e-9
 
 
 class TestSchedulerPressure:

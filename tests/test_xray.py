@@ -121,7 +121,10 @@ class TestRooflineAccounting:
                      if k.startswith("groupby"))
         assert entry["queries"] >= 1
         assert entry["kernel_ms"] >= 0
-        assert entry["gbps"] > 0
+        # the achieved GB/s divides by the device's time on the launches
+        assert entry["run_ms"] > 0 and entry["queue_ms"] >= 0
+        assert entry["gbps"] == pytest.approx(
+            entry["bytes_moved"] / entry["run_ms"] / 1e6, rel=0.01, abs=2e-3)
 
     def test_kernel_gbps_histogram_feeds_metrics(self, xray_engine):
         from pinot_tpu.common.metrics import get_metrics
@@ -205,8 +208,11 @@ def test_flight_record_fields(regime_engine, name):
     assert not r.get("exceptions"), r
     assert r.get("numSegmentsOnHost", 0) == 0
     (rec,) = r["roofline"]
-    assert {"kernel", "bytesMoved", "kernelMs", "linkMs", "gbps"} <= set(rec)
-    assert rec["bytesMoved"] > 0 and rec["gbps"] > 0
+    assert {"kernel", "bytesMoved", "kernelMs", "queueMs", "runMs", "linkMs",
+            "gbps"} <= set(rec)
+    assert rec["bytesMoved"] > 0 and rec["gbps"] > 0 and rec["runMs"] > 0
+    assert rec["gbps"] == pytest.approx(
+        rec["bytesMoved"] / rec["runMs"] / 1e6, rel=0.01, abs=2e-3)
     assert not [k for k in rec if "peak" in k.lower()], rec
     assert rec.get("groupbyKeySpace") == space
     kernels = regime_engine.device.hbm_stats()["roofline"]["kernels"]
@@ -216,6 +222,7 @@ def test_flight_record_fields(regime_engine, name):
     (kernel,) = [ln.strip() for ln in lines
                  if ln.strip().startswith("KERNEL(")]
     assert " GB/s, bytes=" in kernel and "kernelMs=" in kernel \
+        and "queueMs=" in kernel and "runMs=" in kernel \
         and "linkMs=" in kernel and "peak" not in kernel.lower(), kernel
     if space is not None:
         assert f"groupbyKeySpace={space}" in kernel, kernel
@@ -600,3 +607,27 @@ class TestQuerylogSummarizer:
         phases = phase_breakdown(entry)
         assert phases.get("scatter") == pytest.approx(7.5)
         assert phases.get("reduce") == pytest.approx(1.0)
+
+    def test_waterfall_splits_the_device_wait_into_queue_and_run(self):
+        """A launch's wait shows as its queue behind other launches and
+        its run on the device; a log from before the split keeps
+        ``kernel``."""
+        from pinot_tpu.tools.querylog import phase_breakdown
+
+        entry = {"traceInfo": {"server_1": [
+            {"phase": "server.queue", "startMs": 0, "durationMs": 0.5},
+            {"phase": "executor.device_wait", "startMs": 1,
+             "durationMs": 9.0,
+             "attrs": {"launchId": 4, "deviceQueueMs": 6.0,
+                       "deviceRunMs": 3.25, "launchesAhead": 2}},
+            {"phase": "executor.link", "startMs": 10, "durationMs": 0.4},
+        ]}}
+        phases = phase_breakdown(entry)
+        assert phases.get("queue") == pytest.approx(6.5)
+        assert phases.get("run") == pytest.approx(3.25)
+        assert "kernel" not in phases
+        assert phases.get("link") == pytest.approx(0.4)
+        old = {"traceInfo": {"server_1": [
+            {"phase": "executor.device_wait", "startMs": 1,
+             "durationMs": 9.0, "attrs": {"launchId": 4}}]}}
+        assert phase_breakdown(old) == {"kernel": 9.0}
