@@ -113,14 +113,28 @@ _COUNT_ONLY = (("count", None, None),)
 # ORDER (ops/keysorted.py): the batch keeps, a set of key columns, the
 # rows' order by cartesian key, each cell's first row in it, and the
 # statement's filter and value columns projected into it. The projected
-# planes are laid out cell by slot (SLOTTED: every cell the fullest cell's
-# K slots, a plane (K, cells) with the cells along the lanes) and a launch
-# masks and sums down the slot axis - no cumulative sum, no gather - where
-# the key order found K x cells within FULL_SLOT_PADDING times the batch's
-# rows and the planes pass the batch's byte budget; a skewed key (one cell
-# many times the mean) keeps them one row a row (ORDERED) and a launch runs
-# one cumulative sum a channel and reads it at the cells' boundaries. What
-# a launch took is its ``groupbyKeyLayout``; no option chooses it.
+# planes take one of three layouts, chosen from the key order's own rows a
+# cell (ops/keysorted.py layout_rows), once a key order:
+# - SLOTTED, where the fullest cell's K slots (to the sublane tile) x the
+#   cells stay within FULL_SLOT_PADDING times the batch's rows: every cell
+#   K slots, a plane (K, cells) with the cells along the lanes, and a
+#   launch masks and sums down the slot axis - no cumulative sum, no
+#   gather. Uniform keys take it.
+# - SPILLED, where they do not (a skewed key: one cell many times the
+#   mean): K a power of two near the mean, the one whose slots plus
+#   FULL_SPILL_FAT_SLOTS a fat cell (over K rows) are fewest among those
+#   within the same bound; each cell's first K rows in its home column, a
+#   fat cell's rows past K in chunk columns of K slots after them. A launch
+#   sums down the slot axis as SLOTTED does; the chunks' sums are combined
+#   by a cumulative sum over the chunk axis read at the F fat cells'
+#   boundaries and added into the table at their ids.
+# - ORDERED, the byte budget's fallback: the planes one row a row, and a
+#   launch runs one cumulative sum a channel down the rows and reads it at
+#   every cell's boundaries.
+# Exact either way: the value planes are as wide as the FULLEST cell allows
+# (plane_bits_for), every partial sum is a uint32 modulo 2^32, and a cell's
+# true sum of a channel is below 2^32, so its residue is the sum. What a
+# launch took is its ``groupbyKeyLayout``; no option chooses it.
 # The table is the key space itself (the dense regime's output form), and
 # the trim's selection (ops/device_reduce.py select_top) takes its top
 # rows without a sort at table length. Which of the two regimes a
@@ -139,6 +153,13 @@ FULL_MAX_PLANES = 4      # a value's projected plane is one uint32 a row
 # uniform keys at 37.5M rows need x1.2 (62,500 cells) to x2.2 (1.75M) and
 # x3.2 at a 3M-cell key (PERF.md, PR 37); past it the key is skewed
 FULL_SLOT_PADDING = 4
+# what a SPILLED layout's fat cell costs a launch, in slots of the sum down
+# the slot axis: the chunks' combine reads two of a fat cell's cumulative
+# sums and scatter-adds their difference into the table, where a slot is
+# streamed once. On the v5e, four channels, 37.5M rows of Zipf z = 1 keys:
+# ~100 ns a fat cell (the scatter-add ~80 of it) against 24-34 ps a slot
+# (PERF.md §6, the spilled layout's step 0)
+FULL_SPILL_FAT_SLOTS = 3000
 # what executor.dispatch / device_wait, the flight record and EXPLAIN
 # ANALYZE call a group-by's key space (``groupbyKeySpace``)
 KEY_SPACES = {"groupby": "dense", "groupby_narrow": "narrowed",
@@ -1070,8 +1091,9 @@ def _key_space_outcome(outs: dict, space=None) -> dict:
 # groupby_operand), none of them (S, L): the dense kernel's lane-major ids
 # and byte planes; the full regime's key order ("go::<key columns>": the
 # permutation, "gs::<key columns>": the cells' first rows) and the planes
-# projected into it ("gp::<key columns>::<what>")
-_GB_OPERAND_PREFIXES = ("gk::", "gv::", "go::", "gs::", "gp::")
+# projected into it ("gp::<key columns>::<what>"), and a SPILLED layout's
+# own ("gl::<key columns>::<K>::<part>")
+_GB_OPERAND_PREFIXES = ("gk::", "gv::", "go::", "gs::", "gp::", "gl::")
 
 
 def build_pipeline(template, mm_mode: str = "auto",
@@ -1689,18 +1711,25 @@ def build_pipeline(template, mm_mode: str = "auto",
         key order (KEY_SPACES and ops/keysorted.py have the why). The
         launch's own work: the filter over the projected columns, each
         row's segment against ``ps_alive`` and which positions hold a row
-        (slotted: a cell's first ``rows[cell]`` slots; ordered: up to the
-        number of real rows); a count channel and each value split into
-        planes narrow enough that the fullest cell's sum stays under
-        2^32; slotted, the sum down the slot axis, ordered, one
-        cumulative sum a channel read at the cells' boundaries. The table
-        is the key space, as the dense form's."""
-        _name, starts_key, seg_key, fcols, planes, plane_bits, slotted = \
-            prep[3]  # slotted: a cell's slots K, 0 in the ordered layout
-        starts = cols[starts_key]
+        (slotted: a cell's first ``rows[cell]`` slots; spilled: a
+        column's first ``rows[column]``; ordered: up to the number of real
+        rows); a count channel and each value split into planes narrow
+        enough that the fullest cell's sum stays under 2^32; slotted, the
+        sum down the slot axis, spilled, that and the fat cells' chunks
+        combined apart, ordered, one cumulative sum a channel read at the
+        cells' boundaries. The table is the key space, as the dense
+        form's."""
+        # rows: the cells' first rows (slotted, ordered) or each column's
+        # rows (spilled); slotted: the slots a column K, 0 in the ordered
+        # layout; spill: the spilled layout's chunk and fat-cell operands
+        _name, rows_key, seg_key, fcols, planes, plane_bits, slotted, \
+            spill = prep[3]
+        starts = cols[rows_key]
         seg = cols[seg_key]
         with jax.named_scope("pinot.mask"):
-            if slotted:
+            if spill:
+                keep = ks_ops.column_mask(starts, seg.shape)
+            elif slotted:
                 keep = ks_ops.slot_mask(starts, seg.shape)
             else:
                 at = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) \
@@ -1730,8 +1759,13 @@ def build_pipeline(template, mm_mode: str = "auto",
                         (v >> (plane_bits * k)) & ((1 << plane_bits) - 1)
                         for k in range(n_split))
                 split.append((i, first, n_split))
-            sums = ks_ops.slot_sums(channels, num_groups) if slotted \
-                else ks_ops.segment_sums(starts, channels)
+            if spill:
+                sums = ks_ops.spill_sums(channels, num_groups,
+                                         *(cols[k] for k in spill))
+            elif slotted:
+                sums = ks_ops.slot_sums(channels, num_groups)
+            else:
+                sums = ks_ops.segment_sums(starts, channels)
         with jax.named_scope("pinot.recombine"):
             gcount = sums[0].astype(jnp.int64)
             outs["gcount"] = gcount
@@ -1860,6 +1894,9 @@ class DeviceExecutor:
         # planes in key order (ORDERED), and the most of those
         self.groupby_slotted_launches = 0
         self.groupby_slotted_bytes = 0
+        # those that summed planes laid out SPILLED, and the most of those
+        self.groupby_spilled_launches = 0
+        self.groupby_spilled_bytes = 0
         self.groupby_ordered_launches = 0
         self.groupby_ordered_bytes = 0
         # (filter template, key columns, aggregates, batch) -> is its
@@ -2093,6 +2130,8 @@ class DeviceExecutor:
                 "groupby_full_table_bytes": self.groupby_full_table_bytes,
                 "groupby_slotted_launches": self.groupby_slotted_launches,
                 "groupby_slotted_bytes": self.groupby_slotted_bytes,
+                "groupby_spilled_launches": self.groupby_spilled_launches,
+                "groupby_spilled_bytes": self.groupby_spilled_bytes,
                 "groupby_ordered_launches": self.groupby_ordered_launches,
                 "groupby_ordered_bytes": self.groupby_ordered_bytes,
                 "groupby_key_space_probes": self.groupby_key_space_probes,
@@ -3128,24 +3167,37 @@ class DeviceExecutor:
                     or ctx.device_bytes() + cost <= self.MAX_CACHED_BYTES
 
             plane_bits = slot_rows = 0
+            spill, as_built = (), str
             if fits(sources + ordered):
                 fullest = ctx.key_order_rows(group_cols)
                 plane_bits = ks_ops.plane_bits_for(fullest)
-                slot_rows = ks_ops.slot_rows(fullest)
                 # the slots a row of the batch the slotted planes would
                 # hold: what FULL_SLOT_PADDING bounds
-                padding = slot_rows * ks_ops.slot_lanes(total) \
-                    / ctx.lane_rows()
-                if padding > FULL_SLOT_PADDING or not fits(
-                        sources + tuple(map(ctx.slotted_key, ordered))):
-                    slot_rows = 0  # a skewed key, or the budget: ordered
+                padding = ks_ops.slot_rows(fullest) \
+                    * ks_ops.slot_lanes(total) / ctx.lane_rows()
+                slot_rows = ctx.key_layout_rows(
+                    group_cols, FULL_SLOT_PADDING * ctx.lane_rows(),
+                    FULL_SPILL_FAT_SLOTS)
+                if 0 < slot_rows < ks_ops.slot_rows(fullest):
+                    spill = ctx.spill_keys(name, slot_rows)
+                    as_built = functools.partial(ctx.spilled_key,
+                                                 k=slot_rows)
+                elif slot_rows:
+                    as_built = ctx.slotted_key
+                if slot_rows and not fits(sources + spill + tuple(
+                        map(as_built, ordered))):
+                    # the budget: ordered
+                    slot_rows, spill, as_built = 0, (), str
             if plane_bits:
-                as_built = ctx.slotted_key if slot_rows else str
                 seg_key = as_built(f"gp::{name}::seg")
                 fcols = tuple((c, as_built(k)) for c, k in fcols)
                 vplanes = tuple((i, as_built(k), n) for i, k, n in vplanes)
+                # a launch masks by each column's rows (spilled) or reads
+                # them off the cells' first rows
+                rows_key = spill[0] if spill else "gs::" + name
                 needed.update(
-                    ("gs::" + name, seg_key) + tuple(k for _c, k in fcols)
+                    (rows_key, seg_key) + spill[1:]
+                    + tuple(k for _c, k in fcols)
                     + tuple(k for _i, k, _n in vplanes))
                 for i, key, nplanes in vplanes:
                     argt = agg_tpls[i][1]
@@ -3154,11 +3206,16 @@ class DeviceExecutor:
                             self._build_expr_planes, ctx, argt, widths,
                             params, offsets[i], nplanes)
                 prepared = ("keysorted", (), (), (
-                    name, "gs::" + name, seg_key, fcols, vplanes,
-                    plane_bits, slot_rows))
-                key_layout = {"groupbyKeyLayout": "slotted", "slotRows":
-                              slot_rows} if slot_rows else \
-                    {"groupbyKeyLayout": "ordered"}
+                    name, rows_key, seg_key, fcols, vplanes,
+                    plane_bits, slot_rows, spill[1:]))
+                key_layout = {"groupbyKeyLayout": "spilled" if spill
+                              else "slotted" if slot_rows else "ordered"}
+                if slot_rows:
+                    key_layout["slotRows"] = slot_rows
+                if spill:
+                    laid = ctx.spill_layout(group_cols, slot_rows)
+                    key_layout.update(spillCells=laid.fat_cells,
+                                      spillChunks=laid.n_chunks)
                 # and why: the key order's fullest cell, the padding the
                 # slotted planes would cost, the value planes' width
                 key_layout.update(fullestCellRows=fullest,
@@ -3175,7 +3232,8 @@ class DeviceExecutor:
                      "keySpaceCells": total} if shape in KEY_SPACES else {}
         if shape == "groupby_full" and trim is not None:
             key_space["trimSelect"] = dr_ops.trim_select(trim)
-        # a full launch's layout of the projected planes: slotted | ordered
+        # a full launch's layout of the projected planes: slotted |
+        # spilled | ordered
         key_space.update(key_layout)
 
         pkey = self._pipeline_key(template, use_bs, wsig, trim, pmode,
@@ -3293,6 +3351,10 @@ class DeviceExecutor:
                     self.groupby_slotted_launches += 1
                     self.groupby_slotted_bytes = max(
                         self.groupby_slotted_bytes, plane_bytes)
+                elif key_layout.get("groupbyKeyLayout") == "spilled":
+                    self.groupby_spilled_launches += 1
+                    self.groupby_spilled_bytes = max(
+                        self.groupby_spilled_bytes, plane_bytes)
                 elif key_layout.get("groupbyKeyLayout") == "ordered":
                     self.groupby_ordered_launches += 1
                     self.groupby_ordered_bytes = max(

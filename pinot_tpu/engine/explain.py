@@ -175,6 +175,7 @@ def _kernel_line(rec: dict) -> str:
         f", {k}={rec[k]}" for k in ("groupbyOperands", "groupbyKeySpace",
                                     "keySpaceCells", "keySpaceLive",
                                     "groupbyKeyLayout", "slotRows",
+                                    "spillCells", "spillChunks",
                                     "fullestCellRows", "slotPadding",
                                     "planeBits")
         if rec.get(k))

@@ -64,6 +64,9 @@ class KeySpaceFull(DeviceUnsupported):
 
 
 _NUMERIC_KINDS = ("i", "u", "f")
+# a SPILLED layout's operands (``gl::<cols>::<K>::<part>``) -> the field of
+# ops/keysorted.py SpillLayout each holds
+_SPILL_PARTS = {"rows": "col_rows", "chunks": "chunks", "fat": "fat"}
 
 # ---------------------------------------------------------------------------
 # cardinality-aware column width planning
@@ -188,8 +191,13 @@ class BatchContext:
         # live and die with this batch
         self._gb_operands: dict = {}
         self._gb_operand_bytes = 0
-        # the full regime's key orders: "<cols>" -> rows of the fullest cell
+        # the full regime's key orders: "<cols>" -> rows of the fullest
+        # cell, and rows a cell (host numpy); what the layout's choice
+        # made of them: (cols, bounds) -> K, (cols, K) -> SPILLED layout
         self._key_order_rows: dict = {}
+        self._key_cell_rows: dict = {}
+        self._key_layout_rows: dict = {}
+        self._spill_layouts: dict = {}
         # col key -> ((S, NB) lo, (S, NB) hi) device zone maps in the
         # column's device value space (global ids / decoded / raw); built
         # eagerly alongside the column block (the host data is in hand
@@ -684,7 +692,7 @@ class BatchContext:
                 return arr, False
             from pinot_tpu.ops import groupby_mm as mm
 
-            if key.startswith(("go::", "gs::", "gp::")):
+            if key.startswith(("go::", "gs::", "gp::", "gl::")):
                 arr = self._key_ordered_locked(key, build)
             elif build is not None:
                 arr = build()
@@ -716,7 +724,10 @@ class BatchContext:
         offset as one uint32 a row (from the byte planes, ``build`` making
         an expression's), any other cols key the stored plane itself;
         ``gp::<cols>::slot::<what>`` (``slotted_key``) the same plane laid
-        out cell by slot, the ordered one a temporary of its build."""
+        out cell by slot and ``gp::<cols>::spill<K>::<what>``
+        (``spilled_key``) laid out SPILLED at K, the ordered one a
+        temporary of their build; ``gl::<cols>::<K>::<part>``
+        (``spill_keys``) the SPILLED layout's own operands."""
         from pinot_tpu.ops import keysorted as ks
 
         kind, rest = key[:2], key[4:]
@@ -726,12 +737,17 @@ class BatchContext:
             perm, starts, fullest = ks.key_order(ids, cards=tuple(
                 len(self._global_dict_locked(c)) for c in names))
             self._key_order_rows[rest] = int(fullest)
+            self._key_cell_rows[rest] = np.diff(np.asarray(starts))
             other = ("gs::" if kind == "go" else "go::") + rest
             self._gb_operands[other] = starts if kind == "go" else perm
             self._gb_operand_bytes += int(self._gb_operands[other].nbytes)
             self._note_resident(self._gb_operands[other])
             return perm if kind == "go" else starts
-        names, what, slotted = self._projected(key)
+        if kind == "gl":
+            names, k, part = rest.split("::")
+            layout = self.spill_layout(names.split(","), int(k))
+            return jnp.asarray(getattr(layout, _SPILL_PARTS[part]))
+        names, what, form = self._projected(key)
         perm = self.groupby_operand("go::" + names)[0]
         if what.startswith("gv::"):
             plane = ks.project_value(self.groupby_operand(what, build)[0],
@@ -745,10 +761,13 @@ class BatchContext:
                 stored = self._decoded_column_locked(what[4:]) \
                     if what.startswith("dv::") else self._column_locked(what)
             plane = ks.project_plane(stored, perm)
-        if slotted:
+        if form == "slot":
             plane = ks.slot_plane(
                 plane, self.groupby_operand("gs::" + names)[0],
-                k=ks.slot_rows(self._key_order_rows[names]))
+                k=ks.slot_rows(self.key_order_rows(names.split(","))))
+        elif form:
+            plane = ks.spill_plane(plane, jnp.asarray(self.spill_layout(
+                names.split(","), form).first), k=form)
         return plane
 
     @staticmethod
@@ -758,11 +777,36 @@ class BatchContext:
         return f"gp::{names}::slot::{what}"
 
     @staticmethod
-    def _projected(key: str):
-        """A ``gp::`` key's (key columns, what it projects, slotted?)."""
+    def spilled_key(key: str, k: int) -> str:
+        """A ``gp::<cols>::<what>`` key's twin in the SPILLED layout at
+        ``k`` slots a column."""
         names, what = key[4:].split("::", 1)
-        slotted = what.startswith("slot::")
-        return names, what[6:] if slotted else what, slotted
+        return f"gp::{names}::spill{k}::{what}"
+
+    @staticmethod
+    def spill_keys(names: str, k: int) -> tuple:
+        """The SPILLED layout's operands at ``k`` over the key columns
+        ``names`` (comma-joined): each column's rows, the fat cells' first
+        chunks, the fat cells' ids (``_SPILL_PARTS``)."""
+        return tuple(f"gl::{names}::{k}::{part}" for part in _SPILL_PARTS)
+
+    @staticmethod
+    def _projected(key: str):
+        """A ``gp::`` key's (key columns, what it projects, layout): the
+        layout None (in key order), ``"slot"`` or the SPILLED K."""
+        names, what = key[4:].split("::", 1)
+        form, _sep, rest = what.partition("::")
+        if form == "slot":
+            return names, rest, "slot"
+        if form.startswith("spill") and form[5:].isdigit():
+            return names, rest, int(form[5:])
+        return names, what, None
+
+    def _cell_rows(self, names: str):
+        """Rows a cell of the key order over ``names`` (host numpy),
+        building the order where it is not built yet."""
+        self.groupby_operand("go::" + names)
+        return self._key_cell_rows[names]
 
     def key_order_rows(self, group_cols) -> int:
         """Rows of the fullest cell of the key order over ``group_cols``,
@@ -770,6 +814,31 @@ class BatchContext:
         name = ",".join(group_cols)
         self.groupby_operand("go::" + name)
         return self._key_order_rows[name]
+
+    def key_layout_rows(self, group_cols, max_slots: int,
+                        fat_slots: int) -> int:
+        """``ops/keysorted.py layout_rows`` of the key order over
+        ``group_cols`` (the SLOTTED K, a SPILLED K, or 0), once a batch."""
+        from pinot_tpu.ops import keysorted as ks
+
+        key = (",".join(group_cols), max_slots, fat_slots)
+        k = self._key_layout_rows.get(key)
+        if k is None:
+            k = self._key_layout_rows[key] = ks.layout_rows(
+                self._cell_rows(key[0]), max_slots, fat_slots)
+        return k
+
+    def spill_layout(self, group_cols, k: int):
+        """``ops/keysorted.py spill_layout`` of the key order over
+        ``group_cols`` at ``k``, once a batch."""
+        from pinot_tpu.ops import keysorted as ks
+
+        names = ",".join(group_cols)
+        layout = self._spill_layouts.get((names, k))
+        if layout is None:
+            layout = self._spill_layouts[(names, k)] = ks.spill_layout(
+                self._cell_rows(names), k)
+        return layout
 
     def lane_rows(self) -> int:
         """The batch's rows as the lane-major operands hold them: padded
@@ -781,7 +850,7 @@ class BatchContext:
     def groupby_operand_cost(self, keys) -> int:
         """HBM bytes that building the not-yet-built operands among
         ``keys`` would add (lock-free: the byte budget's check). A slotted
-        plane is reckoned at its own K x cells slots, which the built key
+        or spilled plane is reckoned at its own slots, which the built key
         order says: ask after ``key_order_rows``."""
         from pinot_tpu.ops import keysorted as ks
 
@@ -797,11 +866,21 @@ class BatchContext:
                 cost += n_pad * 4
             elif key.startswith("gs::"):
                 cost += 4 * (self._key_cells(key[4:]) + 1)
+            elif key.startswith("gl::"):
+                names, k, part = key[4:].split("::")
+                cost += 4 * getattr(self.spill_layout(
+                    names.split(","), int(k)), _SPILL_PARTS[part]).size
             elif key.startswith("gp::"):
-                names, what, slotted = self._projected(key)
-                slots = n_pad if not slotted else ks.slot_rows(
-                    self._key_order_rows[names]) * ks.slot_lanes(
-                        self._key_cells(names))
+                names, what, form = self._projected(key)
+                if form is None:
+                    slots = n_pad
+                elif form == "slot":
+                    slots = ks.slot_rows(self.key_order_rows(
+                        names.split(","))) * ks.slot_lanes(
+                            self._key_cells(names))
+                else:
+                    slots = form * self.spill_layout(
+                        names.split(","), form).first.size
                 cost += slots * (
                     1 if what == "seg" else 4 if what.startswith("gv::")
                     else np.dtype(self.width_plan(what).dtype).itemsize)

@@ -11,7 +11,8 @@ row in that order (``starts``); a column a statement reads (a filter's, a
 value's) is projected into that order once (``project_plane``,
 ``project_value``), like the ``gk::`` and ``gv::`` operands of the dense
 kernel. The cells are ragged in that order, and the batch pays for that
-once too, in one of two layouts of the projected planes:
+once too, in one of three layouts, chosen from the key order's own row
+counts (``layout_rows``; engine/device.py has the bound and the cost):
 
 SLOTTED (``slot_plane``, ``slot_sums``): every cell gets the same number
 of slots K - the fullest cell's rows, rounded up to the sublane tile - and
@@ -24,26 +25,43 @@ scatter and no one-hot at launch time. Uniform keys pad little (dbgen's at
 where K x cells stays within engine/device.py FULL_SLOT_PADDING times
 the batch's rows.
 
+SPILLED (``spill_layout``, ``spill_sums``): a skewed key, where a few fat
+cells would set K for every cell. K is a power of two near the mean
+(``layout_rows``); each cell keeps its first K rows in its home column, as
+SLOTTED, and a fat cell's rows past K go into chunk columns of K slots of
+their own, after the home columns' lane tiles, in cell order then chunk
+order. A launch masks each column by its own row count and sums down the
+slot axis as SLOTTED does; the home sums are the table, and the chunk sums
+are combined per fat cell by one cumulative sum over the CHUNK axis read at
+the fat cells' boundaries, then added at the fat cells' ids: a cumulative
+sum over S chunks, a gather and a scatter of F fat cells, where ORDERED
+reads every row and every cell.
+
 ORDERED (``segment_sums``): the planes stay one row a row, in key order,
 and a launch runs one cumulative sum a channel down the rows and reads
 each cell's sum as the difference at its two boundaries (a gather of
 cells + 1 elements a channel: 54% of a launch at 1.75M cells, the
-cumulative sums 31%: PERF.md, PR 36). It stays for a skewed key - one
-cell many times the mean, where K x cells would be many times the rows -
-and where the slotted planes do not fit the batch's byte budget.
+cumulative sums 31%: PERF.md §6). It stays where neither slotted
+layout fits the batch's byte budget.
 
-Either way the table is the key space itself: the dense regime's output.
+Either way the table is the key space itself, in cell order: the dense
+regime's output.
 
 Sums are exact integers. A channel is summed modulo 2^32, and a cell's
-sum (ordered: a difference of two such sums) is its own as long as that
-stays below 2^32: the executor splits a value into planes of
-``plane_bits`` so that the fullest cell's rows times a plane's largest
-value does (``plane_bits_for``), and recombines the planes in int64.
+sum is its own as long as that stays below 2^32: the executor splits a
+value into planes of ``plane_bits`` so that the fullest cell's rows times
+a plane's largest value does (``plane_bits_for`` of the fullest cell, not
+of K), and recombines the planes in int64. Every partial sum on the way -
+a home column, a chunk, the chunks' cumulative sum, a difference of two of
+its entries, the add into the table; ordered, the rows' cumulative sum and
+its differences - is a uint32 taken modulo 2^32, so the residue that
+reaches the table is the cell's true sum modulo 2^32, which is the sum.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +69,7 @@ import numpy as np
 
 SLOT_TILE = 8      # sublanes of a 32-bit tile: a cell's slots, K, to it
 LANE_TILE = 128    # the slotted planes' cells, to it
+SCAN_TILE = 1024   # the SPILLED chunks' cumulative sum runs in blocks of it
 
 
 def plane_bits_for(max_cell_rows: int) -> int:
@@ -136,6 +155,89 @@ def slot_lanes(cells: int) -> int:
     return -(-cells // LANE_TILE) * LANE_TILE
 
 
+def layout_rows(rows, max_slots: int, fat_slots: int) -> int:
+    """K, the slots a column, of a key order whose cells hold ``rows``
+    (host numpy, once a key order). SLOTTED's, ``slot_rows`` of the
+    fullest cell, where its slots stay within ``max_slots``. Else
+    SPILLED's: of the candidates ``SLOT_TILE`` x 2^i below that - powers of
+    two, so that batches of one shape meet each other's programs - the one
+    whose slots (home and chunk columns, each to a lane tile) plus
+    ``fat_slots`` a fat cell (what the chunks' combine pays a fat cell, in
+    slots) are fewest, among those within ``max_slots``. 0 where none is
+    (ORDERED)."""
+    rows = np.asarray(rows, dtype=np.int64)
+    home = slot_lanes(rows.size)
+    fullest = slot_rows(int(rows.max(initial=0)))
+    if fullest * home <= max_slots:
+        return fullest
+    best, best_cost = 0, None
+    k = SLOT_TILE
+    while k < fullest and k * home <= max_slots:
+        over = rows[rows > k]
+        slots = k * (home + slot_lanes(int(np.sum(-(-over // k) - 1))))
+        cost = slots + fat_slots * over.size
+        if slots <= max_slots and (best_cost is None or cost < best_cost):
+            best, best_cost = k, cost
+        k *= 2
+    return best
+
+
+class SpillLayout(NamedTuple):
+    """The SPILLED layout of a key order at K (``spill_layout``), host
+    numpy int32. A column a lane: the cells' home columns to a lane tile,
+    then the chunk columns to a lane tile. ``first``: the row (in key
+    order) a column's slot 0 holds; ``col_rows``: how many of its slots
+    hold a row of its cell (0 in a padding column). The ``fat_cells``
+    cells of more than K rows, in cell order: the ``f``-th owns chunks
+    ``chunks[f]`` up to ``chunks[f + 1]`` (chunk ``i`` of a cell holds its
+    rows ``(i + 1) K`` on) and is cell ``fat[f]``. ``fat`` and ``chunks``
+    are padded to a lane tile: ``chunks`` repeats ``n_chunks``, so a
+    padding entry's total is 0, and ``fat`` goes on from the home columns'
+    lanes, so that its ids stay distinct and sorted and a padding one
+    lands past the table."""
+    first: np.ndarray
+    col_rows: np.ndarray
+    chunks: np.ndarray
+    fat: np.ndarray
+    fat_cells: int
+    n_chunks: int
+
+
+def spill_layout(rows, k: int) -> SpillLayout:
+    """The SPILLED layout at ``k`` of a key order whose cells hold
+    ``rows`` (host numpy, once a batch and set of key columns)."""
+    rows = np.asarray(rows, dtype=np.int64)
+    cells = rows.size
+    starts = np.concatenate([[0], np.cumsum(rows)])
+    fat = np.flatnonzero(rows > k)
+    n_chunks = -(-rows[fat] // k) - 1
+    chunks = np.concatenate([[0], np.cumsum(n_chunks)])
+    n = int(chunks[-1])
+    owner = np.repeat(fat, n_chunks)
+    past = k * (np.arange(n) - np.repeat(chunks[:-1], n_chunks) + 1)
+    home = slot_lanes(cells)
+    first = np.full(home + slot_lanes(n), starts[-1], np.int32)
+    first[:cells] = starts[:-1]
+    first[home:home + n] = starts[owner] + past
+    col_rows = np.zeros(first.size, np.int32)
+    col_rows[:cells] = np.minimum(rows, k)
+    col_rows[home:home + n] = np.minimum(rows[owner] - past, k)
+    at = np.full(slot_lanes(fat.size) + 1, n, np.int32)
+    at[:fat.size + 1] = chunks
+    # the padding entries' ids run on past the home columns' lanes: every
+    # id is distinct and in order, and a padding one lands off the table
+    ids = np.arange(slot_lanes(fat.size), dtype=np.int32) + home - fat.size
+    ids[:fat.size] = fat
+    return SpillLayout(first, col_rows, at, ids, int(fat.size), n)
+
+
+def _gather_slots(ordered, first, k):
+    at = first[None, :] + jax.lax.broadcasted_iota(
+        jnp.int32, (k, first.shape[0]), 0)
+    flat = ordered.reshape(-1)
+    return flat[jnp.minimum(at, flat.shape[0] - 1)]
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def slot_plane(ordered, starts, *, k):
     """A plane in key order (``project_plane`` / ``project_value``) laid
@@ -149,17 +251,28 @@ def slot_plane(ordered, starts, *, k):
     lanes = slot_lanes(cells)
     first = jnp.concatenate(
         [starts[:-1], jnp.full(lanes - cells, starts[-1], starts.dtype)])
-    at = first[None, :] + jax.lax.broadcasted_iota(jnp.int32, (k, lanes), 0)
-    flat = ordered.reshape(-1)
-    return flat[jnp.minimum(at, flat.shape[0] - 1)]
+    return _gather_slots(ordered, first, k)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def spill_plane(ordered, first, *, k):
+    """A plane in key order laid out SPILLED (``SpillLayout.first``):
+    ``(k, columns)`` at the plane's own width, slot ``(j, col)`` the row at
+    ``first[col] + j`` - ``slot_plane``'s one gather over more columns."""
+    return _gather_slots(ordered, first, k)
+
+
+def column_mask(rows, shape):
+    """``(k, lanes)`` bool: slot ``j`` of column ``c`` holds a row of its
+    cell where ``j < rows[c]`` (``rows`` zero-padded to the lanes)."""
+    rows = jnp.concatenate(
+        [rows, jnp.zeros(shape[1] - rows.shape[0], rows.dtype)])
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) < rows[None, :]
 
 
 def slot_mask(starts, shape):
     """``(k, lanes)`` bool: the slots that hold a row of their cell."""
-    rows = starts[1:] - starts[:-1]
-    rows = jnp.concatenate(
-        [rows, jnp.zeros(shape[1] - rows.shape[0], rows.dtype)])
-    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) < rows[None, :]
+    return column_mask(starts[1:] - starts[:-1], shape)
 
 
 def slot_sums(channels, cells: int):
@@ -168,6 +281,47 @@ def slot_sums(channels, cells: int):
     the slot axis."""
     return jnp.stack([jnp.sum(ch, axis=0, dtype=jnp.uint32)
                       for ch in channels])[:, :cells]
+
+
+def _scan_chunks(x):
+    """Cumulative sums modulo 2^32 of a 1-D uint32: within blocks of
+    ``SCAN_TILE``, then over the blocks' totals. The TPU's compiler builds
+    one cumulative sum of 10^4 to 10^6 elements in 30-45 s, in blocks in
+    1-2 s (PERF.md §6)."""
+    n = x.shape[0]
+    x = jnp.concatenate([x, jnp.zeros(-n % SCAN_TILE, x.dtype)])
+    inner = jnp.cumsum(x.reshape(-1, SCAN_TILE), axis=1, dtype=jnp.uint32)
+    total = inner[:, -1]
+    below = jnp.cumsum(total, dtype=jnp.uint32) - total
+    return (inner + below[:, None]).reshape(-1)[:n]
+
+
+def spill_sums(channels, cells: int, chunks, fat):
+    """Per-cell sums modulo 2^32 of ``channels`` - uint32 planes laid out
+    SPILLED, a masked slot 0 - as ``(len(channels), cells)`` uint32: the
+    sum down the slot axis, whose home columns are the table; the chunk
+    columns' sums run cumulatively over the chunk axis, read at the fat
+    cells' boundaries (``chunks``) and added at their ids (``fat``).
+
+    Channel by channel, every array one-dimensional, as ``segment_sums``
+    does: over the channels stacked, the TPU's windowed scatter moved one
+    channel's totals between fat cells in a served program (PERF.md
+    §6). ``fat``'s ids are distinct and sorted, a padding one past the
+    home lanes (``spill_layout``), so the scatter is told so."""
+    home = slot_lanes(cells)
+    out = []
+    for ch in channels:
+        col = jnp.sum(ch, axis=0, dtype=jnp.uint32)
+        with jax.named_scope("pinot.full_spill"):
+            run = jnp.concatenate(
+                [jnp.zeros(1, jnp.uint32), _scan_chunks(col[home:])])
+            at = run[chunks]
+            table = jnp.concatenate(
+                [col[:home], jnp.zeros(LANE_TILE, jnp.uint32)])
+            out.append(table.at[fat].add(
+                at[1:] - at[:-1], indices_are_sorted=True,
+                unique_indices=True)[:cells])
+    return jnp.stack(out)
 
 
 def segment_sums(starts, channels):

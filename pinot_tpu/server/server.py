@@ -262,13 +262,14 @@ class ServerInstance:
             # whose live keys did not fit it and the HOST answered (one
             # launched again in the full regime is not among them), the
             # full regime's launches, and those of them that summed planes
-            # laid out cell by slot, or in key order
+            # laid out cell by slot, spilled, or in key order
             for gname, attr in (
                     ("deviceGroupbyNarrowed", "groupby_narrowed_launches"),
                     ("deviceGroupbyNarrowOverflow",
                      "groupby_narrow_overflows"),
                     ("deviceGroupbyFull", "groupby_full_launches"),
                     ("deviceGroupbySlotted", "groupby_slotted_launches"),
+                    ("deviceGroupbySpilled", "groupby_spilled_launches"),
                     ("deviceGroupbyOrdered", "groupby_ordered_launches")):
                 self._register_gauge(
                     gname, (lambda _a=attr, _d=dev: getattr(_d, _a)))

@@ -39,9 +39,12 @@ CELLS = {"citypair_1997": 62_500, "city_brand": 250_000,
 # lanes hold them) fill 62,500 and 35,000 cells 38, 39 and 36 deep: 40
 # slots a cell are x3.8 and x2.1 of the rows, within FULL_SLOT_PADDING.
 # Its larger key spaces are mostly EMPTY here (at 37.5M rows they pad
-# x1.4 to x2.2: PERF.md, PR 37): 24 x 250,000 slots are x9.2, and those
-# three statements keep the ordered planes and the cumulative sums.
+# x1.4 to x2.2: PERF.md §6): 24 x 250,000 slots are x9.2. At 250,000
+# cells 8 slots a cell and the cells past 8 rows in chunks of 8 (SPILLED)
+# fit the bound; at 601,500 and 1.75M cells not even 8 slots a cell do,
+# and those two statements keep the ordered planes and the cumulative sums.
 SLOT_ROWS = {"citypair_1997": 40, "supp_year": 40, "supp_shipmode_disc": 40}
+SPILL_ROWS = {"city_brand": 8}
 
 
 def _bench(*parts):
@@ -178,6 +181,7 @@ def test_full_is_the_host_and_says_so(engines, built, name):
             assert attrs["groupbyOperands"] in ("prepared", "built")
             assert (attrs["groupbyKeyLayout"], attrs.get("slotRows")) == (
                 ("slotted", SLOT_ROWS[name]) if name in SLOT_ROWS
+                else ("spilled", SPILL_ROWS[name]) if name in SPILL_ROWS
                 else ("ordered", None)), (name, attrs)
         assert 4096 < wait["keySpaceLive"] <= CELLS[name]
         rec = resp["roofline"][0]
@@ -185,7 +189,8 @@ def test_full_is_the_host_and_says_so(engines, built, name):
         assert (rec["groupbyKeySpace"], rec["keySpaceCells"]) \
             == ("full", CELLS[name])
         assert rec["groupbyKeyLayout"] == dispatch["groupbyKeyLayout"]
-        assert rec.get("slotRows") == SLOT_ROWS.get(name)
+        assert rec.get("slotRows") == SLOT_ROWS.get(
+            name, SPILL_ROWS.get(name))
         assert rec["keySpaceLive"] == wait["keySpaceLive"]
         assert rec["trimSelect"].startswith("select:")
 
@@ -513,13 +518,15 @@ def _ctx_of(engine, table_segments):
 @pytest.mark.parametrize("table,layout,slot_rows", [
     ("u", "slotted", 8),
     # one cell of 6,000 rows among cells of 0-8: 6,000 slots a cell would
-    # be 1,400 times the batch's rows, and the planes stay in key order
-    ("sk", "ordered", None)])
+    # be 1,400 times the batch's rows; 8 slots a cell and the fat cell's
+    # 5,993 more rows in 750 chunks of 8 (six lane tiles) are x1.9
+    ("sk", "spilled", 8)])
 def test_the_layout_follows_the_fullest_cell(slot_engines, table, layout,
                                              slot_rows):
     """Uniform cells are laid out cell by slot, a skewed key past the
-    padding bound keeps the ordered form, each says so, and both are the
-    host's answer: no option, variable or argument chose."""
+    padding bound is slotted at a K near the mean with its fat cell's
+    excess rows in chunks, each says so, and both are the host's answer:
+    no option, variable or argument chose."""
     from pinot_tpu.engine import device as device_mod
     from pinot_tpu.ops import keysorted as ks
 
@@ -538,17 +545,21 @@ def test_the_layout_follows_the_fullest_cell(slot_engines, table, layout,
     after = by["device"].device.hbm_stats()
     assert after["groupby_full_launches"] \
         == before["groupby_full_launches"] + 2
-    assert after["groupby_slotted_launches"] \
-        == before["groupby_slotted_launches"] + 2 * (layout == "slotted")
+    for form in ("slotted", "spilled"):
+        assert after[f"groupby_{form}_launches"] \
+            == before[f"groupby_{form}_launches"] + 2 * (layout == form)
     ctx = _ctx_of(by["device"], tables[table])
     fullest = ctx.key_order_rows(("a", "b"))
     assert fullest == (8 if table == "u" else 6_001)
     slots = ks.slot_rows(fullest) * ks.slot_lanes(SLOT_A * SLOT_B)
     assert (slots <= device_mod.FULL_SLOT_PADDING * ctx.lane_rows()) \
         == (layout == "slotted")
+    if layout == "spilled":
+        assert (attrs["spillCells"], attrs["spillChunks"]) == (1, 750)
     built = [k for k in ctx._gb_operands if k.startswith("gp::")]
-    assert built and all(("::slot::" in k) == (layout == "slotted")
-                         for k in built), built
+    assert built and all(
+        ("::slot::" if layout == "slotted" else "::spill8::") in k
+        for k in built), built
     assert "slot" not in sql.lower() and "order" not in sql.lower()[:40]
 
 
@@ -589,7 +600,9 @@ def test_a_dead_segment_has_no_row_in_a_slot(slot_engines):
 def test_the_byte_budget_reckons_a_slotted_plane_at_its_slots(slot_engines):
     """``groupby_operand_cost`` of a slotted ``gp::`` key is the bytes its
     build puts on the device, K x cells (to a lane tile) at the plane's
-    width and not the batch's rows; an ordered key's is the rows."""
+    width and not the batch's rows; a spilled key's K x its home and chunk
+    columns, and its layout operands' a column or a fat cell each; an
+    ordered key's is the rows."""
     from pinot_tpu.ops import keysorted as ks
 
     by, tables, _cols = slot_engines
@@ -605,15 +618,24 @@ def test_the_byte_budget_reckons_a_slotted_plane_at_its_slots(slot_engines):
             finally:
                 ctx._gb_operands[key] = arr
             assert ctx.groupby_operand_cost([key]) == 0  # built: no more
+            # the skewed table spills its one fat cell into 750 chunks
             slots = 8 * ks.slot_lanes(SLOT_A * SLOT_B) if slotted \
-                else ctx.lane_rows()
+                else 8 * (ks.slot_lanes(SLOT_A * SLOT_B) + 768)
             assert arr.size == slots and ("::slot::" in key) == slotted
+            assert ("::spill8::" in key) != slotted
         # a twin in the other layout is a key of its own, at its own size
         if slotted:
             twin = keys[0].replace("::slot::", "::")
             assert ctx.groupby_operand_cost([twin]) \
                 == ctx.lane_rows() * ctx._gb_operands[keys[0]].dtype.itemsize
             assert ctx.slotted_key(twin) == keys[0]
+        else:
+            for key in ctx.spill_keys("a,b", 8):
+                arr = ctx._gb_operands.pop(key)
+                try:
+                    assert ctx.groupby_operand_cost([key]) == arr.nbytes
+                finally:
+                    ctx._gb_operands[key] = arr
 
 
 def test_slotted_planes_past_the_byte_budget_keep_the_order(slot_engines):

@@ -1,15 +1,17 @@
-"""Skewed entity keys (ISSUE 40): configuration ``ssb_sf100_skewkeys``, whose
-three foreign keys are drawn Zipf with z = 1 (benchmark/generators/zipf.py),
-and its mix ``ssb_skewkeys_6q_c4``, the first cell on the ORDERED side of
-the full key space's layout choice (engine/device.py FULL_SLOT_PADDING).
+"""Skewed entity keys: configuration ``ssb_sf100_skewkeys``, whose three
+foreign keys are drawn Zipf with z = 1 (benchmark/generators/zipf.py), and
+its mix ``ssb_skewkeys_6q_c4``, the cell on the skewed side of the full key
+space's layout choice (engine/device.py FULL_SLOT_PADDING).
 
 The kind alone; the configuration held to ``ssb_sf100_fullkeys`` key by
 key; the mix on the tiny skewed table (benchmark/harness/testdata/
-ssb_skewkeys_tiny.json) served full, ordered and prepared, every row equal
-to benchmark/harness/reference.py's, each launch saying why it went ordered
+ssb_skewkeys_tiny.json) served full, SPILLED and prepared, every row equal
+to benchmark/harness/reference.py's, each launch saying its layout
+(``slotRows``, ``spillCells``, ``spillChunks``) and why it is not slotted
 (``fullestCellRows``, ``slotPadding``, ``planeBits``) on its spans, in its
-flight record, in EXPLAIN ANALYZE and in the counters; the same table with
-uniform keys laid out cell by slot; and ``groupby_ordered_share``'s reader.
+flight record, in EXPLAIN ANALYZE and in the counters; the byte budget's
+fallback to the ORDERED planes; the same table with uniform keys laid out
+by the same rule; and ``groupby_ordered_share``'s reader.
 """
 
 import copy
@@ -293,10 +295,24 @@ def _why(ctx, group_cols, cells):
             "planeBits": ks.plane_bits_for(fullest)}
 
 
+def _spilled(ctx, group_cols):
+    """What a SPILLED launch should say of its layout, from the key order's
+    rows a cell (numpy): K by the rule, the cells past K, their chunks."""
+    starts = np.asarray(ctx.groupby_operand("gs::" + ",".join(group_cols))[0])
+    rows = np.diff(starts)
+    k = ks.layout_rows(rows, device_mod.FULL_SLOT_PADDING * ctx.lane_rows(),
+                       device_mod.FULL_SPILL_FAT_SLOTS)
+    assert 0 < k < ks.slot_rows(rows.max()) and k & (k - 1) == 0
+    over = rows[rows > k]
+    return {"slotRows": k, "spillCells": int(over.size),
+            "spillChunks": int(np.sum(-(-over // k) - 1))}
+
+
 @pytest.mark.parametrize("name", NAMES)
 def test_statement_served_is_the_reference_and_ordered(served, name):
     """Twice through HTTP -> broker -> server: exact, on the device,
-    complete, every launch full and ordered, the slotted counters still."""
+    complete, every launch full and spilled, the slotted and ordered
+    counters still."""
     from pinot_tpu import client
 
     cluster, traffic, want, reference_mod = served
@@ -314,28 +330,34 @@ def test_statement_served_is_the_reference_and_ordered(served, name):
         assert not cur.stats.get("numGroupsLimitReached"), name
     after = cluster.executor.hbm_stats()
     for counter, grew in (("groupby_full_launches", 2),
-                          ("groupby_ordered_launches", 2),
+                          ("groupby_spilled_launches", 2),
+                          ("groupby_ordered_launches", 0),
                           ("groupby_slotted_launches", 0),
                           ("groupby_narrow_overflows", 0)):
         assert after[counter] == before[counter] + grew, counter
     assert after["groupby_operand_launches"]["perLaunch"] \
         == before["groupby_operand_launches"]["perLaunch"]
-    # a value, a segment and maybe a filter plane, a row each of the batch
-    assert after["groupby_ordered_bytes"] >= 5 * 600_000
-    assert after["groupby_slotted_bytes"] == 0
+    # a value, a segment and maybe a filter plane, a slot each, and the
+    # slots hold at least the batch's rows
+    assert after["groupby_spilled_bytes"] >= 5 * 600_000
+    assert after["groupby_ordered_bytes"] == after["groupby_slotted_bytes"] \
+        == 0
     gauges = cluster.server.metrics.snapshot()["gauges"]
-    assert gauges["server.deviceGroupbyOrdered.server_0"] \
-        == after["groupby_ordered_launches"]
-    assert gauges["server.deviceGroupbySlotted.server_0"] == 0
+    assert gauges["server.deviceGroupbySpilled.server_0"] \
+        == after["groupby_spilled_launches"]
+    assert gauges["server.deviceGroupbySlotted.server_0"] \
+        == gauges["server.deviceGroupbyOrdered.server_0"] == 0
     assert not any(cluster.failure_counters().values())
 
 
 @pytest.mark.parametrize("name", NAMES)
 def test_the_ordered_launch_says_why(engines, name):
     """device == host == reference; both spans, the flight record and
-    EXPLAIN ANALYZE say full, ordered and what the key order holds: a
-    fullest cell thousands of times the mean, a padding past
-    FULL_SLOT_PADDING, the planes' width for that cell."""
+    EXPLAIN ANALYZE say full, spilled, the layout (K a power of two below
+    the fullest cell, the fat cells and their chunks, as numpy reckons them
+    from the key order) and what the key order holds: a fullest cell
+    thousands of times the mean, a padding past FULL_SLOT_PADDING, the
+    planes' width for that cell and not for K."""
     by, sqls, tables, want, reference_mod = engines
     traffic = _bench("traffic", "ssb_skewkeys_6q_c4.json")
     host, _ = _rows(by["host"], sqls[name])
@@ -349,20 +371,22 @@ def test_the_ordered_launch_says_why(engines, name):
         why = _why(ctx, _group_by(traffic, name), cells)
         assert why["slotPadding"] > device_mod.FULL_SLOT_PADDING
         assert why["fullestCellRows"] > 100 * 600_000 // cells
+        why.update(_spilled(ctx, _group_by(traffic, name)))
+        assert why["spillCells"] and why["spillChunks"] >= why["spillCells"]
+        assert why["planeBits"] < ks.plane_bits_for(why["slotRows"])
         for phase in ("executor.dispatch", "executor.device_wait"):
             attrs = spans[phase]
             assert (attrs["groupbyKeySpace"], attrs["groupbyKeyLayout"],
                     attrs["groupbyOperands"]) \
-                == ("full", "ordered", operands), (phase, attrs)
-            assert "slotRows" not in attrs
+                == ("full", "spilled", operands), (phase, attrs)
             assert {k: attrs[k] for k in why} == why, (phase, attrs)
         rec = resp["roofline"][0]
-        assert rec["groupbyKeyLayout"] == "ordered"
+        assert rec["groupbyKeyLayout"] == "spilled"
         assert {k: rec[k] for k in why} == why
     _got, resp = _rows(by["device"], sqls[name].replace(
         "SELECT ", "EXPLAIN ANALYZE SELECT ", 1))
     text = json.dumps(resp)
-    for k, v in dict(why, groupbyKeyLayout="ordered").items():
+    for k, v in dict(why, groupbyKeyLayout="spilled").items():
         assert f"{k}={v}" in text, k
 
 
@@ -371,7 +395,9 @@ def test_the_same_table_with_uniform_keys_goes_slotted(uniform, built, name):
     """The choice from the other side: the twin's one-key statements and
     its city pairs fit within the padding bound and are laid out cell by
     slot; a statement's layout is slotted exactly where its padding says
-    so; the answers are the twin's reference."""
+    so, and where it does not (``supp_year`` and ``city_brand``, whose key
+    spaces are mostly empty at this size) spilled at the rule's K; the
+    answers are the twin's reference."""
     engine, tables, want = uniform
     traffic, reference_mod = built[1], built[3]
     sql = traffic["set_prefix"] + next(
@@ -384,14 +410,63 @@ def test_the_same_table_with_uniform_keys_goes_slotted(uniform, built, name):
     why = _why(ctx, _group_by(traffic, name), attrs["keySpaceCells"])
     assert {k: attrs[k] for k in why} == why
     slotted = why["slotPadding"] <= device_mod.FULL_SLOT_PADDING
-    assert attrs["groupbyKeyLayout"] == ("slotted" if slotted else "ordered")
+    assert attrs["groupbyKeyLayout"] == ("slotted" if slotted else "spilled")
     assert slotted or name in ("supp_year", "city_brand")
-    assert ("slotRows" in attrs) == slotted
+    if slotted:
+        assert attrs["slotRows"] == ks.slot_rows(why["fullestCellRows"])
+        assert "spillCells" not in attrs and "spillChunks" not in attrs
+    else:
+        spilled = _spilled(ctx, _group_by(traffic, name))
+        assert {k: attrs[k] for k in spilled} == spilled
     after = engine.device.hbm_stats()
     assert after["groupby_slotted_launches"] \
         == before["groupby_slotted_launches"] + slotted
+    assert after["groupby_spilled_launches"] \
+        == before["groupby_spilled_launches"] + (not slotted)
     assert after["groupby_ordered_launches"] \
-        == before["groupby_ordered_launches"] + (not slotted)
+        == before["groupby_ordered_launches"]
+
+
+def test_spilled_planes_past_the_byte_budget_keep_the_order(built, engines):
+    """Where the spilled planes would pass the batch's byte budget and the
+    ordered ones fit, the launch keeps the ORDERED planes - the budget's
+    fallback - says so, and is the reference's answer."""
+    _config, traffic, _want, _ref, _dirs, _work = built
+    by, sqls, tables, want, reference_mod = engines
+    name = "top_parts"
+    names = ",".join(_group_by(traffic, name))
+    _rows(by["device"], sqls[name])  # the spilled launch's operands
+    k = _spilled(_ctx_of(by["device"], tables["lineorder"]),
+                 names.split(","))["slotRows"]
+    spill = f"::spill{k}::"
+    built_keys = [key for key in _ctx_of(
+        by["device"], tables["lineorder"])._gb_operands
+        if key.startswith(f"gp::{names}{spill}")]
+    assert len(built_keys) == 3  # the segment, the filter's column, the value
+    engine = _engine(tables, mm_mode="interpret")
+    _rows(engine, "SELECT COUNT(*) FROM lineorder WHERE lo_discount < 3 "
+                  "AND lo_revenue >= 0 GROUP BY lo_quantity LIMIT 1")
+    ctx = _ctx_of(engine, tables["lineorder"])
+    ctx.key_order_rows(names.split(","))
+    # what either layout is built from: the value's byte planes
+    sources = [key.split("::", 2)[2] for key in built_keys if "gv::" in key]
+    ordered = ctx.groupby_operand_cost(
+        sources + [key.replace(spill, "::") for key in built_keys])
+    spilled = ctx.groupby_operand_cost(
+        sources + built_keys + list(ctx.spill_keys(names, k)))
+    assert spilled > ordered
+    dev = engine.device
+    dev.MAX_CACHED_BYTES = ctx.device_bytes() + (ordered + spilled) // 2
+    got, _resp, spans = _traced(engine, sqls[name])
+    assert reference_mod.answer_error(got, want[name]) == 0.0
+    attrs = spans["executor.dispatch"]
+    assert (attrs["groupbyKeyLayout"], attrs["groupbyOperands"]) \
+        == ("ordered", "built")
+    assert "slotRows" not in attrs and "spillCells" not in attrs
+    stats = dev.hbm_stats()
+    assert stats["groupby_ordered_launches"] == 1
+    assert stats["groupby_spilled_launches"] == 0
+    assert ctx.device_bytes() <= dev.MAX_CACHED_BYTES
 
 
 @pytest.mark.parametrize("mode", ["f32_partials", "drop_segment"])

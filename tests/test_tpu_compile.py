@@ -575,10 +575,12 @@ FULL_TRIM = (8192, (("agg", 0, "sum", False),), "select")
 FULL_COMPILE_LIMIT_S = 90
 
 
-def _full_pipeline(spec, name, plane_bits, slot_rows):
+def _full_pipeline(spec, name, plane_bits, slot_rows, spill=None):
     """(compiled program, its HLO text, seconds to build, cells) of the
     executor's own pipeline for FULL[name] over the flat batch, its
-    projected planes ordered (``slot_rows`` 0) or laid out cell by slot."""
+    projected planes ordered (``slot_rows`` 0), laid out cell by slot, or
+    SPILLED (``spill``: the fat cells and their chunks)."""
+    import functools
     import time
 
     from pinot_tpu.engine.params import BatchContext
@@ -590,16 +592,24 @@ def _full_pipeline(spec, name, plane_bits, slot_rows):
     for c in template[3]:
         cells *= c
     keys = ",".join(template[2])
-    as_built = BatchContext.slotted_key if slot_rows else (lambda k: k)
-    plane = (slot_rows, ks.slot_lanes(cells)) if slot_rows \
-        else (N_FLAT_PAD // 128, 128)
+    as_built = functools.partial(BatchContext.spilled_key, k=slot_rows) \
+        if spill else BatchContext.slotted_key if slot_rows \
+        else (lambda k: k)
+    lanes = ks.slot_lanes(cells) + (ks.slot_lanes(spill[1]) if spill else 0)
+    plane = (slot_rows, lanes) if slot_rows else (N_FLAT_PAD // 128, 128)
     fcols = tuple((c, as_built(f"gp::{keys}::{c}")) for c in case["fcols"])
     planes = ((0, as_built(f"gp::{keys}::" + case["value"]), 3),)
     seg_key = as_built(f"gp::{keys}::seg")
+    spill_keys = BatchContext.spill_keys(keys, slot_rows) if spill else ()
+    rows_key = spill_keys[0] if spill else "gs::" + keys
     prepared = ("keysorted", (), (), (
-        keys, "gs::" + keys, seg_key, fcols, planes, plane_bits, slot_rows))
+        keys, rows_key, seg_key, fcols, planes, plane_bits, slot_rows,
+        spill_keys[1:]))
     cols = {k: spec(FLAT_BATCH, w[0]) for k, w in widths.items()}
-    cols["gs::" + keys] = spec((cells + 1,), "int32")
+    cols[rows_key] = spec((lanes,) if spill else (cells + 1,), "int32")
+    if spill:
+        cols[spill_keys[1]] = spec((ks.slot_lanes(spill[0]) + 1,), "int32")
+        cols[spill_keys[2]] = spec((ks.slot_lanes(spill[0]),), "int32")
     cols[seg_key] = spec(plane, "uint8")
     for c, key in fcols:
         cols[key] = spec(plane, widths[c][0])
@@ -669,6 +679,40 @@ def test_pipeline_full_groupby_slotted_real_batch(spec, name):
     print(f"slotted {name}: built in {seconds:.1f} s")
 
 
+# a skewed key's SPILLED layout: supp_year's at 37.5M rows whose keys are
+# Zipf z = 1 (ssb_sf100_skewkeys), by the rule (ops/keysorted.py
+# layout_rows): K, the fat cells past it, their chunks (PERF.md §6)
+FULL_SPILL = (32, 91_564, 883_189)
+
+
+@pytest.mark.parametrize("name", list(FULL))
+def test_pipeline_full_groupby_spilled_real_batch(spec, name):
+    """The same two statements over a skewed key, laid out SPILLED: the
+    sum down the slot axis over home and chunk columns, the chunks'
+    cumulative sum over the chunk axis and a gather and a scatter of the
+    fat cells - none at the rows' length, no gather at the cells', no
+    sort, inside the compile limit, at the value planes' 8 bits."""
+    from pinot_tpu.ops import keysorted as ks
+
+    k, fat, chunks = FULL_SPILL
+    compiled, text, seconds, cells = _full_pipeline(
+        spec, name, 8, k, (fat, chunks))
+    assert k * (ks.slot_lanes(cells) + ks.slot_lanes(chunks)) \
+        <= dev.FULL_SLOT_PADDING * N_FLAT_PAD
+    assert not _big_results(text, "reduce-window", rows=N_FLAT)
+    assert not _big_results(text, "gather", rows=cells)
+    # the fat cells' totals are added into the table in place, channel by
+    # channel: one-dimensional, at ids sorted and distinct
+    scatters = [line for line in text.splitlines()
+                if " scatter(" in line and "pinot.full_spill" in line]
+    assert len(scatters) == 4   # the count and three value planes
+    assert all(
+        "update_window_dims={}" in line and "indices_are_sorted=true" in line
+        and "unique_indices=true" in line for line in scatters)
+    assert not _big_results(text, "copy", rows=k * cells)
+    print(f"spilled {name}: built in {seconds:.1f} s")
+
+
 def test_key_order_projections_real_batch(spec):
     """The once-a-batch projections of the full regime's operands into the
     key order: one relayout (ops/groupby_mm.py _to_lanes's split of L:
@@ -696,6 +740,13 @@ def test_key_order_projections_real_batch(spec):
             spec(lanes, dtype), spec((1_750_001,), "int32"))
         assert mem.output_size_in_bytes \
             >= 48 * ks.slot_lanes(1_750_000) * width
+    # and one laid out SPILLED: the same gather over the home and chunk
+    # columns (3M cells and a million chunks x 32)
+    lanes_spilled = ks.slot_lanes(3_000_000) + ks.slot_lanes(1_000_000)
+    _compiled, mem = _compile(
+        lambda o, first: ks.spill_plane(o, first, k=32),
+        spec(lanes, "uint32"), spec((lanes_spilled,), "int32"))
+    assert mem.output_size_in_bytes >= 32 * lanes_spilled * 4
     assert time.perf_counter() - t0 < 90
 
 
